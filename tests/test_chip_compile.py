@@ -1,0 +1,132 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+chip that is described and not attached (guide on-chip-measurement §2).
+
+Interpret-mode tests cannot see what Mosaic refuses — a slice not aligned
+to the tiling, too much VMEM, a kernel GSPMD cannot partition. These
+compiles can, at no chip time: each lowers one kernel at a real width for
+a described ``v5e:2x2`` and requires ``tpu_custom_call`` in the program.
+head_dim 64 is the small published Llamas' head size (Llama-3.2-1B); 128
+is the flagship's.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and every xdist worker imports this file.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+HEAD_DIMS = (128, 64)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _qkv(d, sharding, b=2, h=8, l=2048):
+    return [jax.ShapeDtypeStruct((b, h, l, d), jnp.bfloat16,
+                                 sharding=sharding)] * 3
+
+
+@pytest.mark.parametrize("window", (None, 512))
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_forward_compiles_for_v5e(one_chip, d, window):
+    from tony_tpu.ops.attention import _flash_fwd
+
+    fwd = functools.partial(_flash_fwd, causal=True, scale=None,
+                            interpret=False, window=window)
+    _compiled_text(jax.jit(fwd), *_qkv(d, one_chip))
+
+
+@pytest.mark.parametrize("window", (None, 512))
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_backward_compiles_for_v5e(one_chip, d, window):
+    from tony_tpu.ops.attention import _flash_bwd
+
+    b, h, l = 2, 8, 2048
+    q, k, v = _qkv(d, one_chip, b, h, l)
+    lse = jax.ShapeDtypeStruct((b, h, l), jnp.float32, sharding=one_chip)
+    bwd = functools.partial(_flash_bwd, causal=True, scale=None,
+                            interpret=False, window=window)
+    # (q, k, v, out, lse, g_out)
+    _compiled_text(jax.jit(bwd), q, k, v, q, lse, q)
+
+
+@pytest.mark.parametrize("variant", ("bf16", "int8", "layer_window"))
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_decode_compiles_for_v5e(one_chip, d, variant):
+    from tony_tpu.ops.decode_attention import flash_decode
+
+    b, kvh, rep, m, layers = 2, 8, 4, 4096, 2
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = sds((b, kvh, rep, d), jnp.bfloat16)
+    length = sds((), jnp.int32)
+    if variant == "int8":
+        cache = sds((b, kvh, m, d), jnp.int8)
+        scale = sds((b, kvh, m), jnp.bfloat16)
+        _compiled_text(flash_decode, q, cache, cache, length, scale, scale)
+    elif variant == "layer_window":
+        cache = sds((layers, b, kvh, m, d), jnp.bfloat16)
+        fn = jax.jit(functools.partial(flash_decode, layer=1, window=512))
+        _compiled_text(fn, q, cache, cache, length)
+    else:
+        cache = sds((b, kvh, m, d), jnp.bfloat16)
+        _compiled_text(flash_decode, q, cache, cache, length)
+
+
+def test_flash_under_a_four_chip_mesh_compiles_for_v5e(topo):
+    """GSPMD refuses to partition a Mosaic kernel; the model's flash path
+    wraps it in a shard_map over batch and heads (models/transformer.py).
+    Llama-3.2-1B's attention shape on a data x tensor mesh of the 2x2."""
+    from tony_tpu.models.transformer import _partition_over_batch_and_heads
+    from tony_tpu.ops.attention import _flash_fwd
+
+    mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                ("fsdp", "tensor"))
+    sharding = NamedSharding(mesh, P("fsdp", None, "tensor", None))
+    q = jax.ShapeDtypeStruct((4, 2048, 32, 64), jnp.bfloat16,
+                             sharding=sharding)
+
+    def attn(q, k, v):                   # [B, L, H, D], as the model calls
+        t = lambda x: x.transpose(0, 2, 1, 3)
+        return t(_flash_fwd(t(q), t(k), t(v), True, None,
+                            interpret=False)[0])
+
+    fn = jax.jit(_partition_over_batch_and_heads(attn, mesh, q.shape))
+    _compiled_text(fn, q, q, q)

@@ -186,6 +186,35 @@ def test_lm_train_example_consumes_token_file(tmp_path):
     metrics = json.loads(out.read_text())
     assert np.isfinite(metrics["final_loss"])
     assert metrics["mesh"]["data"] == 2 and metrics["mesh"]["fsdp"] == 4
+    # every result names the device that produced it
+    assert metrics["device"]["platform"] == "cpu"
+    assert metrics["device"]["count"] == 8 and metrics["device"]["kind"]
+
+
+def test_lm_train_resumes_onto_the_step_shardings(tmp_path, capsys):
+    """A second run with the same --checkpoint-dir resumes: the saved state
+    restores from shapes alone straight onto the mesh shardings the step
+    expects (the fresh initialisation is dropped first — one chip does not
+    hold two copies at a realistic size), and training goes on from the
+    step after the save."""
+    import json
+
+    from tony_tpu.examples import lm_train
+
+    args = ["--batch-size", "8", "--seq-len", "16", "--vocab", "64",
+            "--d-model", "32", "--n-layers", "1", "--n-heads", "2",
+            "--d-ff", "64", "--dtype", "float32", "--mesh", "data=2,fsdp=4",
+            "--checkpoint-dir", str(tmp_path / "ck"),
+            "--checkpoint-every", "100"]
+    out = tmp_path / "m.json"
+    assert lm_train.main(["--steps", "3", *args]) == 0
+    capsys.readouterr()
+    assert lm_train.main(["--steps", "18", "--metrics-out", str(out),
+                          *args]) == 0
+    printed = capsys.readouterr().out
+    assert "resumed from checkpoint step 2" in printed
+    assert "step 20: loss" in printed        # steps 3..20, not 0..17
+    assert np.isfinite(json.loads(out.read_text())["final_loss"])
 
 
 def test_append_uses_file_header_dtype(tmp_path):

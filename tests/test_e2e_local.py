@@ -451,28 +451,31 @@ def test_history_events_written(tmp_job_dirs, fixture_script):
     assert types[-1] == "APPLICATION_FINISHED"
 
 
-def test_tpu_metrics_flow_into_task_finished(tmp_job_dirs, fixture_script,
-                                             tmp_path, monkeypatch):
-    """Full observability chain for accelerator metrics: the executor's
-    TaskMonitor samples the TPU channel (a fake libtpu.sdk injected via
-    PYTHONPATH — the same import surface the real chip serves), pushes over
-    the metrics RPC, and the driver stamps them into the TASK_FINISHED
-    history event (reference: GPU metrics via GpuDiscoverer ->
-    TaskMonitor -> jhist, TaskMonitor.java:101-170)."""
+def test_tpu_metrics_flow_into_task_finished(tmp_job_dirs, tmp_path,
+                                             monkeypatch):
+    """Full observability chain for accelerator metrics, with the chip's
+    ownership respected: the CHILD (the process that owns the chip) writes
+    its TPU sample into the step log (train.profiling.StepTimer does),
+    the executor's TaskMonitor reads it from there and pushes it over the
+    metrics RPC, and the driver stamps it into the TASK_FINISHED history
+    event (reference: GPU metrics via GpuDiscoverer -> TaskMonitor ->
+    jhist, TaskMonitor.java:101-170). The executor itself must never load
+    libtpu — a fake one on PYTHONPATH records every import."""
+    marker = tmp_path / "libtpu-imported-by"
     pkg = tmp_path / "fakelibs" / "libtpu"
     pkg.mkdir(parents=True)
-    (pkg / "__init__.py").write_text("")
-    (pkg / "sdk.py").write_text(
-        "class _Metric:\n"
-        "    def __init__(self, data): self._d = data\n"
-        "    def data(self): return self._d\n"
-        "class tpumonitoring:\n"
-        "    _DATA = {'duty_cycle_pct': ['62.5'],\n"
-        "             'hbm_capacity_usage': ['3000000']}\n"
-        "    @staticmethod\n"
-        "    def get_metric(name):\n"
-        "        return _Metric(tpumonitoring._DATA[name])\n"
-    )
+    (pkg / "__init__.py").write_text(
+        "import os, sys\n"
+        f"open({str(marker)!r}, 'a').write(' '.join(sys.argv) + '\\n')\n")
+    (pkg / "sdk.py").write_text("tpumonitoring = None\n")
+    child = tmp_path / "child.py"
+    child.write_text(
+        "import json, os, time\n"
+        "rec = {'step': 50, 'train_step': 49, 'tpu_duty_cycle_pct': 62.5,\n"
+        "       'tpu_hbm_used_mb': 3.0, 'tpu_hbm_peak_mb': 4.5}\n"
+        "with open(os.environ['TONY_STEP_LOG'], 'a') as f:\n"
+        "    f.write(json.dumps(rec) + '\\n')\n"
+        "time.sleep(1.0)\n")
     existing = os.environ.get("PYTHONPATH", "")
     monkeypatch.setenv(
         "PYTHONPATH",
@@ -481,7 +484,7 @@ def test_tpu_metrics_flow_into_task_finished(tmp_job_dirs, fixture_script,
     status, client = run_job(
         tmp_job_dirs,
         **{"tony.worker.instances": 1,
-           "tony.worker.command": f"{PY} {fixture_script('exit_0.py')}",
+           "tony.worker.command": f"{PY} {child}",
            "tony.task.metrics-interval-ms": 200},
     )
     assert status == JobStatus.SUCCEEDED, dump_logs(client)
@@ -494,7 +497,11 @@ def test_tpu_metrics_flow_into_task_finished(tmp_job_dirs, fixture_script,
                for m in finished[0]["payload"]["metrics"]}
     assert metrics["max_tpu_duty_cycle_pct"] == 62.5
     assert metrics["max_tpu_hbm_used_mb"] == 3.0
+    assert metrics["max_tpu_hbm_peak_mb"] == 4.5
+    assert metrics["max_train_step"] == 49
     assert "max_memory_rss_mb" in metrics and metrics["max_memory_rss_mb"] > 0
+    assert not marker.exists(), (
+        "a process of the job loaded libtpu: " + marker.read_text())
 
 
 def test_task_traces_and_driver_metrics_e2e(tmp_job_dirs):

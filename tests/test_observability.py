@@ -442,6 +442,9 @@ def test_metrics_endpoint_matches_stats(params):
         assert "serving_xla_recompiles_post_warm_total" in text
 
         samples = _parse_samples(text)
+        # /stats names the device beside the dispatch attribution
+        assert stats["device"]["platform"] == "cpu"
+        assert stats["device"]["count"] >= 1 and stats["device"]["kind"]
         assert samples["serving_inflight_dispatches"] == 0
         assert samples["serving_dispatches_tracked_total"] == (
             stats["device"]["tracked"]) > 0

@@ -393,3 +393,28 @@ def test_flash_decode_layer_indexed_stack():
             np.asarray(out),
             np.asarray(_decode_ref(q, ck[i], cv[i], 100)),
             rtol=2e-5, atol=2e-5)
+
+
+def test_flash_under_a_mesh_runs_in_a_shard_map_and_matches_reference():
+    """GSPMD cannot partition a Mosaic kernel, so on a mesh of several
+    devices the model wraps the flash call in a shard_map over batch and
+    heads (models/transformer._attention). Same numbers as the reference,
+    and the error for a batch that does not divide is explicit."""
+    from tony_tpu.models import transformer
+    from tony_tpu.parallel import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, d_model=64, n_layers=1, n_heads=4, n_kv_heads=4,
+        d_ff=64, dtype=jnp.float32, attn_impl="flash")
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(kk, (4, 128, 4, 16), jnp.float32)
+               for kk in ks)
+    got = jax.jit(lambda q, k, v: transformer._attention(q, k, v, cfg, mesh)
+                  )(q, k, v)
+    want = reference_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="must divide"):
+        transformer._attention(q[:3], k[:3], v[:3], cfg, mesh)
+

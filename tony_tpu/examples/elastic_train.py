@@ -49,7 +49,6 @@ def main(argv=None) -> int:
     parser.add_argument("--dim", type=int, default=64)
     args = parser.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import jax.numpy as jnp
 

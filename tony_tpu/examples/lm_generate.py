@@ -83,6 +83,9 @@ def main(argv=None) -> int:
     parser.add_argument("--metrics-out", default="")
     args = parser.parse_args(argv)
 
+    from tony_tpu.utils.jaxenv import device_report, place_compile_cache
+
+    place_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -264,6 +267,7 @@ def main(argv=None) -> int:
         "decode_tokens_per_sec": n_generated / wall,
         "generated_tokens": n_generated,
         "backend": jax.default_backend(),
+        "device": device_report(),
         "kv_dtype": args.kv_dtype,
         "weight_dtype": args.weight_dtype,
         "tensor_parallel": args.tensor_parallel,

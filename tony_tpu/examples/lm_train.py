@@ -58,6 +58,9 @@ def main(argv=None) -> int:
     parser.add_argument("--eval-batches", type=int, default=8)
     args = parser.parse_args(argv)
 
+    from tony_tpu.utils.jaxenv import device_report, place_compile_cache
+
+    place_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -99,13 +102,21 @@ def main(argv=None) -> int:
         mgr = CheckpointManager(args.checkpoint_dir, save_interval=args.checkpoint_every)
         latest = mgr.latest_step()
         if latest is not None:
-            template = {"params": params, "opt_state": opt_state}
+            from tony_tpu.train.checkpoint import sharded_restore_template
+
+            # every shard restores straight onto the sharding the train
+            # step expects, from shapes alone: the fresh initialisation is
+            # dropped first, because a chip that holds one copy of
+            # parameters + optimizer state at a realistic size does not
+            # hold two (the step then fails to load: RESOURCE_EXHAUSTED)
+            template = {
+                "params": sharded_restore_template(
+                    params, bundle.param_shardings),
+                "opt_state": sharded_restore_template(
+                    opt_state, bundle.opt_shardings),
+            }
+            params = opt_state = bundle.params = bundle.opt_state = None
             restored = mgr.restore(template=template)
-            # restore may land leaves on a single device; re-place onto the
-            # mesh shardings the train step expects
-            restored = jax.device_put(
-                restored, jax.tree.map(lambda x: x.sharding, template)
-            )
             params, opt_state = restored["params"], restored["opt_state"]
             start_step = latest + 1
             print(f"resumed from checkpoint step {latest}")
@@ -280,6 +291,7 @@ def main(argv=None) -> int:
         "tokens_per_sec": args.steps * tokens_per_step / wall,
         "n_params": n_params,
         "mesh": {k: int(v) for k, v in dict(mesh.shape).items()},
+        "device": device_report(),
     }
     if last_eval is not None:
         import math

@@ -29,6 +29,7 @@ def warmup() -> None:
 
     from tony_tpu.models.mnist import init_mlp, synthetic_mnist
     from tony_tpu.parallel import MeshSpec, build_mesh
+    from tony_tpu.utils.jaxenv import place_compile_cache
 
     # the same shapes mnist_jax stages (n is its hardcoded dataset size;
     # batch overridable to match the job's --batch-size): the RNG/
@@ -49,11 +50,9 @@ def warmup() -> None:
         lr = float(os.environ.get("TONY_WARMUP_MNIST_LR", "1e-3"))
     except ValueError:
         lr = 1e-3
-    cache = os.environ.get("TONY_WARMUP_MNIST_CACHE", "")
-    if cache:
-        # prepaid compiles land in the job's shared persistent cache
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # prepaid compiles land in the persistent cache the job's child reads
+    # (utils/jaxenv.py: JAX_COMPILATION_CACHE_DIR, or the checkout's own)
+    place_compile_cache()
     x, y = synthetic_mnist(jax.random.PRNGKey(0), n=n)
     mesh = build_mesh(MeshSpec(data=-1, fsdp=1))
     P = jax.sharding.PartitionSpec

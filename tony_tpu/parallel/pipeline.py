@@ -19,9 +19,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 StageFn = Callable[[Any, jax.Array], jax.Array]  # (stage_params, x) -> y
 

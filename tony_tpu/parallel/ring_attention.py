@@ -19,9 +19,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .compat import shard_map
 
 NEG_INF = -1e30
 

@@ -25,9 +25,8 @@ import functools
 from typing import Callable
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 from .ring_attention import reference_attention
 

@@ -15,6 +15,8 @@ import logging
 import time
 from pathlib import Path
 
+from ..metrics import sample_tpu_metrics
+
 log = logging.getLogger(__name__)
 
 
@@ -145,6 +147,10 @@ class StepTimer:
             if self._ckpt_step is not None:
                 rec["last_ckpt_step"] = self._ckpt_step
                 rec["last_ckpt_ts"] = self._ckpt_ts
+            # this process owns the chip, so it is the one that may ask
+            # libtpu about it; the executor's TaskMonitor reads the
+            # numbers from this record ({} off the TPU)
+            rec.update(sample_tpu_metrics())
             # best-effort, like the rest of the telemetry path: a missing
             # log dir (remote executor, no logs/ in the unpacked archive)
             # or a full disk must not kill the training loop

@@ -687,6 +687,9 @@ def _default_warmup() -> dict:
     """The prepaid bill: import jax, initialize the default backend, and
     push one tiny jitted dispatch through it so the client, compiler
     plumbing, and transfer path are all live before adoption."""
+    from .utils.jaxenv import place_compile_cache
+
+    place_compile_cache()
     import jax
     import jax.numpy as jnp
 
