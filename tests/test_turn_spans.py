@@ -1,0 +1,321 @@
+"""The serving turn's phases in the profiler's own trace
+(``observability.phase``), and the benchmark's readers of them.
+
+One profiler session for the whole module, on the CPU: a tiny
+``SlotServer`` drained on the test's own thread (EOS mode, then the paged
+engine), then a predictive one behind ``ServeApp`` with a thread a
+submitter. The tests read the one trace back through the benchmark's
+``trace/host_spans.py``: what the program writes and what the benchmark
+reads are held to each other here. Also pinned here: the program names that
+the device-trace readers match.
+"""
+
+import json
+import shutil
+import sys
+import threading
+import time
+import types
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tony_tpu import observability as obs
+from tony_tpu.models import transformer
+from tony_tpu.models.serving import Request, SlotServer
+
+REPO = Path(__file__).resolve().parent.parent
+BENCH = REPO / "benchmark"
+if str(BENCH) not in sys.path:
+    sys.path.insert(0, str(BENCH))
+
+import lib  # noqa: E402  (benchmark/lib.py: the readers import it by this name)
+
+host_spans = lib.load("trace/host_spans.py")
+
+TINY = transformer.TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+    d_ff=128, max_seq_len=128, dtype=jnp.float32,
+)
+STEP_PHASES = (obs.PHASE_ADMIT, obs.PHASE_DISPATCH, obs.PHASE_SYNC,
+               obs.PHASE_BOOKKEEP)
+NEW_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
+               ["per_layer"] if m["source"] == "program_span"]
+
+
+def _requests(n, seed, max_new=9):
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=rng.integers(0, TINY.vocab_size,
+                                        int(rng.integers(2, 14)),
+                                        dtype=np.int32),
+                    max_new_tokens=max_new + i % 4) for i in range(n)]
+
+
+def _drain(server, reqs):
+    for r in reqs:
+        server.submit(r)
+    return server.run_until_drained()
+
+
+@pytest.fixture(scope="module")
+def params():
+    return transformer.init(jax.random.PRNGKey(0), TINY)
+
+
+@pytest.fixture(scope="module")
+def run(params, tmp_path_factory):
+    """The one session: -> what ran in it and where its trace lies."""
+    from tony_tpu.cli.serve import ServeApp
+
+    trace_dir = tmp_path_factory.mktemp("turn_spans")
+    kw = dict(slots=3, max_len=64, block_size=4, prefill_chunk=8)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        # (a) on this thread: more requests than slots, so turns admit
+        # into freed slots while blocks are in flight
+        eos = _drain(SlotServer(params, TINY, stop_tokens=(65,), pad_id=255,
+                                **kw), _requests(7, seed=1))
+        paged = _drain(SlotServer(params, TINY, paged=True, kv_block=8,
+                                  **kw), _requests(4, seed=2))
+        # (c) a predictive engine behind ServeApp: its drain syncs inside
+        # the engine, the case that would nest under serve.loop.drain
+        app = ServeApp(SlotServer(params, TINY, **kw))
+        app.start()
+        sent = {}
+
+        def send(i, req):
+            sent[i] = app.submit_async(req.prompt, req.max_new_tokens)
+
+        senders = [threading.Thread(target=send, args=(i, r))
+                   for i, r in enumerate(_requests(5, seed=3))]
+        for t in senders:
+            t.start()
+        for t in senders:
+            t.join(timeout=60)
+        served = {rid: ev.wait(60) and app.take_result(rid)
+                  for rid, ev in sent.values()}
+        time.sleep(0.1)                 # a few idle turns of the loop
+        app.shutdown()
+    finally:
+        jax.profiler.stop_trace()
+    return types.SimpleNamespace(
+        dir=trace_dir, engine=[eos, paged], served=served,
+        spans=host_spans.spans("serve.", trace_dir))
+
+
+def _by_thread(spans):
+    out = {}
+    for span in spans:
+        out.setdefault(span[1], []).append(span)
+    return out
+
+
+def _loop_thread(run):
+    """The thread line of ServeApp's loop: the one with the idle spans."""
+    return next(s[1] for s in run.spans if s[0] == obs.PHASE_IDLE)
+
+
+def _test_thread(run):
+    """The thread line of the session's own thread: step phases, and no
+    phase of ServeApp's loop."""
+    loop = _loop_thread(run)
+    threads = {s[1] for s in run.spans
+               if s[0] == obs.PHASE_DISPATCH and s[1] != loop}
+    assert len(threads) == 1
+    return threads.pop()
+
+
+def test_every_span_is_a_phase_and_every_phase_was_entered(run):
+    assert {s[0] for s in run.spans} == set(obs.PHASES)
+
+
+def test_step_phases_carry_their_counts(run):
+    mine = [s for s in run.spans if s[1] == _test_thread(run)]
+    assert {s[0] for s in mine} == set(STEP_PHASES)
+    by_name = {name: [s[4] for s in mine if s[0] == name]
+               for name in STEP_PHASES}
+    for counts in by_name[obs.PHASE_ADMIT]:
+        assert set(counts) == {"queued", "admitted", "prefill_tokens"}
+        assert 0 <= counts["admitted"] <= counts["queued"]
+    for counts in by_name[obs.PHASE_DISPATCH]:
+        assert set(counts) == {"live", "slots"}
+        assert 1 <= counts["live"] <= counts["slots"] == 3
+    for counts in by_name[obs.PHASE_SYNC]:
+        assert set(counts) == {"blocks"} and counts["blocks"] >= 1
+    for counts in by_name[obs.PHASE_BOOKKEEP]:
+        assert set(counts) == {"tokens", "completions"}
+    done = {**run.engine[0], **run.engine[1]}
+    assert len(done) == 11
+    assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 11
+    assert sum(c["prefill_tokens"] for c in by_name[obs.PHASE_ADMIT]) > 0
+    assert sum(c["tokens"] for c in by_name[obs.PHASE_BOOKKEEP]) == sum(
+        len(comp.tokens) for comp in done.values())
+    assert sum(c["completions"] for c in by_name[obs.PHASE_BOOKKEEP]) == 11
+    assert sum(c["blocks"] for c in by_name[obs.PHASE_SYNC]) == len(
+        by_name[obs.PHASE_DISPATCH])
+    assert any(comp.finish_reason == "stop" for comp in done.values())
+
+
+def test_phases_are_leaves(run):
+    """On one thread no phase begins before the last one has ended."""
+    threads = _by_thread(run.spans)
+    assert len(threads) >= 3        # the test's, the loop's, a submitter's
+    for thread, spans in threads.items():
+        end = 0.0
+        for name, _, start, dur, _ in sorted(spans, key=lambda s: s[2]):
+            assert start >= end, f"{name} starts inside a phase on {thread}"
+            end = start + dur
+
+
+def test_serve_app_phases_and_the_submitters_rid(run):
+    assert len(run.served) == 5 and all(run.served.values())
+    loop = [s for s in run.spans if s[1] == _loop_thread(run)]
+    assert {s[0] for s in loop} == set(obs.PHASES) - {
+        obs.PHASE_SUBMIT_LOCK_WAIT}
+    waits = [s for s in run.spans if s[0] == obs.PHASE_SUBMIT_LOCK_WAIT]
+    assert sorted(s[4]["rid"] for s in waits) == sorted(run.served)
+    assert all(s[1] != _loop_thread(run) for s in waits)
+    delivered = sum(s[4]["completions"] for s in loop
+                    if s[0] == obs.PHASE_DELIVER)
+    drained = sum(s[4]["completions"] for s in loop
+                  if s[0] == obs.PHASE_DRAIN)
+    assert delivered == drained == 5
+    tokens = sum(s[4]["tokens"] for s in loop if s[0] == obs.PHASE_BOOKKEEP)
+    assert tokens == sum(len(c.tokens) for c in run.served.values())
+
+
+def test_no_session_no_difference(params, run):
+    """With no session open the same requests give the same tokens, and a
+    submit is taken as before."""
+    from tony_tpu.cli.serve import ServeApp
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    kw = dict(slots=3, max_len=64, block_size=4, prefill_chunk=8)
+    again = _drain(SlotServer(params, TINY, stop_tokens=(65,), pad_id=255,
+                              **kw), _requests(7, seed=1))
+    assert ([c.tokens for c in again.values()]
+            == [c.tokens for c in run.engine[0].values()])
+    app = ServeApp(SlotServer(params, TINY, **kw))
+    app.start()
+    try:
+        req = _requests(1, seed=3)[0]
+        rid, ev = app.submit_async(req.prompt, req.max_new_tokens)
+        assert ev.wait(60)
+        assert (app.take_result(rid).tokens
+                == next(iter(run.served.values())).tokens)
+    finally:
+        app.shutdown()
+
+
+def test_phase_without_jax_is_the_shared_null_context(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)  # import fails
+    obs._trace_annotation.cache_clear()
+    try:
+        span = obs.phase(obs.PHASE_IDLE, completions=1)
+        assert span is obs.phase(obs.PHASE_ADMIT)
+        with span as entered:
+            assert entered is None
+    finally:
+        obs._trace_annotation.cache_clear()
+    monkeypatch.undo()
+    assert isinstance(obs.phase(obs.PHASE_IDLE), jax.profiler.TraceAnnotation)
+
+
+def test_step_raises_what_it_raised_before(params):
+    """A failing dispatch leaves step() as itself, through the phase."""
+    server = SlotServer(params, TINY, slots=2, max_len=64, block_size=4)
+    server.submit(_requests(1, seed=4)[0])
+    server._chaos_crash_blocks = {1}
+    with pytest.raises(RuntimeError, match="chaos: injected mid-decode"):
+        server.run_until_drained()
+
+
+# ------------------------------------------------ the benchmark's readers
+
+def test_host_spans_reads_the_recorded_chip_trace(tmp_path):
+    shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
+    found = host_spans.spans("bench_", tmp_path)
+    assert [s[0] for s in found] == ["bench_host_span"] * 3
+    assert all(0.015 < s[3] * 1e-9 < 0.03 and s[4] == {} for s in found)
+    assert len({s[1] for s in found}) == 1
+    assert host_spans.spans("serve.", tmp_path) == []
+    assert host_spans.spans("serve.", tmp_path / "nothing_here") == []
+
+
+def _read(entry, trace_root, monkeypatch):
+    monkeypatch.setattr(host_spans, "TRACE_ROOT", trace_root)
+    stem, _, suffix = entry["name"].partition(".")
+    reader = lib.load(f"layer_metrics/{stem}.py")
+    return reader.read({"trace_window_s": 5.0}, suffix)
+
+
+@pytest.mark.parametrize("entry", NEW_ENTRIES, ids=lambda m: m["name"])
+def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
+    stem = entry["name"].partition(".")[0]
+    assert (BENCH / "layer_metrics" / f"{stem}.py").is_file()
+    assert entry["source"] == "program_span"
+    assert entry["workloads"] == ["mistral7b-v01.chat-open"]
+    # a program without the spans (the chip fixture; the parent commit)
+    shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
+    assert _read(entry, tmp_path, monkeypatch) is None
+    assert _read(entry, tmp_path / "no_trace", monkeypatch) is None
+    value = _read(entry, run.dir, monkeypatch)
+    assert isinstance(value, float) and value >= 0.0
+    if entry["unit"] == "%" and "occupancy" in entry["name"]:
+        assert value <= 100.0
+
+
+def test_new_entries_are_the_eight_and_the_shares_are_disjoint(
+        run, monkeypatch):
+    assert len(NEW_ENTRIES) == 8
+    monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
+    pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
+    named = [name for names in pct.PHASES.values() for name in names]
+    assert len(named) == len(set(named)) and set(named) <= set(obs.PHASES)
+    assert {m["name"].partition(".")[2] for m in NEW_ENTRIES
+            if m["name"].startswith("serve_loop_phase_pct.")} == set(pct.PHASES)
+
+
+# ------------------------------- names that the device-trace readers match
+
+def _train_step_name():
+    from jax.sharding import Mesh
+
+    from tony_tpu.train.step import create_train_step
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1),
+                ("data", "fsdp", "tensor", "seq"))
+    bundle = create_train_step(TINY, mesh)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    lowered = bundle.step_fn.lower(bundle.params, bundle.opt_state,
+                                   tokens, tokens)
+    return lowered.as_text().split("module @", 1)[1].split()[0]
+
+
+def _jit_name(module, attr):
+    import importlib
+
+    fn = getattr(importlib.import_module(module), attr)
+    assert hasattr(fn, "lower"), f"{module}.{attr} is not jitted any more"
+    return f"jit_{fn.__name__}"
+
+
+@pytest.mark.parametrize("needle, reader, name_of", [
+    ("_decode_block", "drivers/serve.py",
+     lambda: _jit_name("tony_tpu.models.serving", "_decode_block")),
+    ("jit_step", "drivers/train.py", _train_step_name),
+    ("_flash_fwd", "layer_metrics/flash_fwd_roofline.py",
+     lambda: _jit_name("tony_tpu.ops.attention", "_flash_fwd")),
+    ("_flash_bwd", "layer_metrics/flash_bwd_roofline.py",
+     lambda: _jit_name("tony_tpu.ops.attention", "_flash_bwd")),
+], ids=["decode_block", "train_step", "flash_fwd", "flash_bwd"])
+def test_program_names_the_trace_readers_match(needle, reader, name_of):
+    """A rename on either side fails here, and does not silence
+    ``decode_block_device_ms``, ``train_step_device_ms`` or a roofline."""
+    assert f'"{needle}"' in (BENCH / reader).read_text()
+    assert needle in name_of()

@@ -46,6 +46,7 @@ chip's HBM serves live traffic with this same single-controller loop
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import select
 import socket
@@ -54,6 +55,7 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from .. import metrics as _metrics
+from .. import observability as _obs
 
 
 def build_argparser() -> argparse.ArgumentParser:
@@ -541,6 +543,18 @@ class ServeApp:
                 fail_stream(rid, f"serving loop failed: {exc!r}")
             ev.set()
 
+    @contextlib.contextmanager
+    def _locked(self, wait_phase: str, **counts):
+        """``with self.lock``, the wait for it written into the
+        profiler's trace as ``wait_phase``: the span closes where the lock
+        is held, so what runs under the lock is not inside it."""
+        with _obs.phase(wait_phase, **counts):
+            self.lock.acquire()
+        try:
+            yield
+        finally:
+            self.lock.release()
+
     def _loop(self):
         while not self.stop.is_set():
             try:
@@ -570,8 +584,16 @@ class ServeApp:
                  getattr(e, "blocks_dispatched", 0))
                 for e in self.engines.values())
 
+        def drain(eng, done):
+            # the engine call first: a predictive engine syncs inside it,
+            # under serve.step.* phases of its own, and those must not
+            # nest under this one
+            got = eng.drain_completed()
+            with _obs.phase(_obs.PHASE_DRAIN, completions=len(got)):
+                done.update(got)
+
         while not self.stop.is_set():
-            with self.lock:
+            with self._locked(_obs.PHASE_LOOP_LOCK_WAIT):
                 busy = False
                 attests = False
                 pre = dispatch_ctrs()
@@ -612,7 +634,7 @@ class ServeApp:
                         # called every tick would serialize compute
                         # with the host round trip
                         if eng.completions_ready:
-                            done.update(eng.drain_completed())
+                            drain(eng, done)
                         elif ckpt_due:
                             # durability checkpoint (bounded cadence):
                             # keep the journal's emitted prefixes fresh
@@ -624,7 +646,7 @@ class ServeApp:
                             if callable(ckpt):
                                 ckpt()
                                 if eng.completions_ready:
-                                    done.update(eng.drain_completed())
+                                    drain(eng, done)
                     except Exception as e:
                         if step_exc is None:
                             step_exc, failed_eng = e, eng
@@ -633,7 +655,8 @@ class ServeApp:
                     if busy and ckpt_due:
                         self._last_checkpoint = now
                     if busy:
-                        self._observe_load()
+                        with _obs.phase(_obs.PHASE_OBSERVE):
+                            self._observe_load()
                     if has_ctrs:
                         attests = dispatch_ctrs() != pre
                     if busy and attests and self.status == "degraded":
@@ -652,7 +675,8 @@ class ServeApp:
                 # idle: the next busy turn must not record this gap as a
                 # giant scheduling turn in loop_turn_s
                 self._turn_timer.reset_interval()
-                self.wake.wait(0.02)
+                with _obs.phase(_obs.PHASE_IDLE):
+                    self.wake.wait(0.02)
                 self.wake.clear()
 
     def _deliver(self, done: dict) -> None:
@@ -666,7 +690,8 @@ class ServeApp:
         # leaks) — atomically: either the waiter cleaned up first
         # (ev is None, completion dropped) or the store+set land
         # before the waiter's cleanup pops both
-        with self.lock:
+        with self._locked(_obs.PHASE_LOOP_LOCK_WAIT), \
+                _obs.phase(_obs.PHASE_DELIVER, completions=len(done)):
             for rid, comp in done.items():
                 ev = self._events.pop(rid, None)
                 self._rid_engine.pop(rid, None)
@@ -802,7 +827,7 @@ class ServeApp:
             # health check + event registration + submit are ONE atomic
             # step vs the loop's failure handler (which flips the status
             # and fails registered events under this same lock)
-            with self.lock:
+            with self._locked(_obs.PHASE_SUBMIT_LOCK_WAIT, rid=req.id):
                 if self.status == "down":
                     raise ServingLoopError(
                         f"serving loop is down: {self.error}")

@@ -150,12 +150,17 @@ from jax import lax
 from .. import constants as c
 from ..events.journal import RequestJournal
 from ..observability import (
+    PHASE_ADMIT,
+    PHASE_BOOKKEEP,
+    PHASE_DISPATCH,
+    PHASE_SYNC,
     DispatchTracker,
     Histogram,
     RequestTrace,
     ServiceRateEstimator,
     ServingTelemetry,
     TraceContext,
+    phase,
 )
 from .registry import ModelEntry, ModelRegistry
 
@@ -3103,6 +3108,22 @@ class SlotServer:
         return not self._host_busy[slot]
 
     def _admit(self) -> None:
+        """One admission pass (ring or paged, prefill dispatches
+        included) as the turn's ``serve.step.admit`` phase."""
+        if self.pause_admission:
+            return
+        with phase(PHASE_ADMIT, queued=len(self._queue)) as span:
+            inflight = len(self._inflight)
+            computed = self.prefill_tokens_computed
+            if self._paged:
+                self._admit_paged()
+            else:
+                self._admit_ring()
+            span.set_metadata(
+                admitted=len(self._inflight) - inflight,
+                prefill_tokens=self.prefill_tokens_computed - computed)
+
+    def _admit_ring(self) -> None:
         """Admit queued requests into free slots. Prefill + slot-state
         pokes are dispatched NOW (after every block dispatched so far) and
         logged against the newest in-flight block so the bookkeeping
@@ -3121,11 +3142,6 @@ class SlotServer:
         burst start — a same-burst template twin prefills too (its copy
         would otherwise be dispatched before the twin's insert) — so
         sharing begins one burst after a template first appears."""
-        if self.pause_admission:
-            return
-        if self._paged:
-            self._admit_paged()
-            return
         self._sweep_expired()
         C = self.prefill_chunk
         admissions: list[_Admission] = []
@@ -4331,9 +4347,10 @@ class SlotServer:
         (same tables, per-pool length vectors), run the unchanged
         `_spec_block`, scatter each slot's round window — the gamma+1
         positions starting at its pre-round length — back into both
-        pools, and process the round IMMEDIATELY (forced sync, like
-        spec's sync mode generally: the scatter window is computed from
-        host lengths, which only stay exact with an empty pipeline).
+        pools; ``_dispatch`` then processes the round IMMEDIATELY (forced
+        sync, like spec's sync mode generally: the scatter window is
+        computed from host lengths, which only stay exact with an empty
+        pipeline).
         Committing all gamma+1 rows is safe even when the verify
         rolled tokens back: rolled-back rows sit ABOVE the slot's new
         length in exclusively-owned tail blocks — the mask never reads
@@ -4373,8 +4390,21 @@ class SlotServer:
         self._pipeline.append({"packed": packed, "events": [], "seq": seq,
                                "w": gamma + 4, "spec_gamma": gamma})
         self._post_dispatch_chaos()
-        if self._pipeline:          # forced sync (see docstring); the
-            self._process(1)        # chaos hook may have emptied it
+
+    def _dispatch(self) -> None:
+        """One decode dispatch (a block, or a speculative round) as the
+        turn's ``serve.step.dispatch`` phase; ``live`` counts the slots
+        it decodes for."""
+        with phase(PHASE_DISPATCH, live=self.n_active, slots=self.slots):
+            if self._spec:
+                self._dispatch_spec_round()
+            else:
+                self._dispatch_block()
+        # the paged spec round's forced sync (see its docstring), once
+        # its phase has closed: sync and bookkeep stay leaves. The chaos
+        # hook may have emptied the pipeline.
+        if self._spec and self._paged and self._pipeline:
+            self._process(1)
 
     def _process(self, count: int) -> None:
         """Sync + bookkeep the oldest ``count`` in-flight blocks with ONE
@@ -4383,8 +4413,21 @@ class SlotServer:
         matter the size). Emitted token count per slot is the length delta
         vs the expectation; completions fire where a slot went inactive;
         each block's admissions AND cancellations replay after it, in
-        dispatch order (the order the device applied them)."""
+        dispatch order (the order the device applied them). The two
+        halves are the turn's ``serve.step.sync`` and
+        ``serve.step.bookkeep`` phases."""
         recs = [self._pipeline.popleft() for _ in range(count)]
+        with phase(PHASE_SYNC, blocks=count):
+            flat, lags = self._sync(recs)
+        with phase(PHASE_BOOKKEEP) as span:
+            done = len(self._done)
+            tokens = self._bookkeep(recs, flat, lags)
+            span.set_metadata(tokens=tokens,
+                              completions=len(self._done) - done)
+
+    def _sync(self, recs) -> tuple:
+        """-> (the blocks' packed results on the host, each block's
+        measured device lag or None)."""
         if len(recs) == 1:
             flat = np.asarray(recs[0]["packed"])
         else:
@@ -4406,6 +4449,12 @@ class SlotServer:
             lags.append(lag)
             if lag is not None:
                 self.telemetry.observe("device_lag_s", lag)
+        return flat, lags
+
+    def _bookkeep(self, recs, flat, lags) -> int:
+        """Replay the synced blocks into the host's state; -> the tokens
+        newly emitted (each fed to its request's stream, if it has one)."""
+        fed = 0
         col = 0
         for i, rec in enumerate(recs):
             # records carry their own packed width: plain decode blocks
@@ -4481,6 +4530,7 @@ class SlotServer:
                         new = cand[prev_len:end]
                         stop_hit = True
                 n_new = len(new)
+                fed += n_new
                 self._emitted[slot].extend(new)
                 if (n_new and lp_chosen is not None and req is not None
                         and req.logprobs):
@@ -4556,6 +4606,7 @@ class SlotServer:
                     self._apply_admit(payload)
                 else:
                     self._apply_cancel(payload)
+        return fed
 
     def _complete_slot(self, slot: int, req: Request, reason: str,
                        lag: float | None) -> None:
@@ -4632,7 +4683,7 @@ class SlotServer:
         if self._predictive:
             self._admit()
             if self._device_may_be_active():
-                self._dispatch_block()
+                self._dispatch()
             elif self._pipeline:
                 self._process(len(self._pipeline))
             if len(self._pipeline) >= 64:      # bound host-side backlog
@@ -4642,10 +4693,7 @@ class SlotServer:
             self._admit()
         dispatched = False
         if self._device_may_be_active():
-            if self._spec:
-                self._dispatch_spec_round()
-            else:
-                self._dispatch_block()
+            self._dispatch()
             dispatched = True
         depth = self.pipeline_depth if dispatched else 0
         if len(self._pipeline) > depth:

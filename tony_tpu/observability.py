@@ -56,6 +56,11 @@ shared observability layer every serving component feeds:
   histogram + counter, and a post-warmup recompile-storm warning (a
   serving loop that recompiles after warmup is silently re-paying
   seconds per dispatch — the classic shape-leak bug).
+- **``phase``** — the serving turn's phases (``PHASES``) as spans in
+  the PROFILER's own trace, on the device trace's clock: everything
+  above runs on ``time.monotonic()`` beside the device trace, these
+  lie in it, so a capture shows what the host was doing under each
+  device gap.
 
 See docs/observability.md for metric names, the trace schema, and a
 scrape example.
@@ -65,6 +70,8 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
+import functools
 import hashlib
 import logging
 import math
@@ -710,6 +717,59 @@ class DispatchTracker:
         return out
 
 
+# ------------------------------------------------- profiler-clock phases
+
+# The phases of one serving turn, as they are named in a profiler trace.
+# Every one is a LEAF: no phase is entered inside another on the same
+# thread, so a phase's duration is its self time and a device gap has one
+# owner. serve.loop.* are ServeApp's (cli/serve.py), serve.step.* are
+# SlotServer's (models/serving.py), serve.submit.* lie on the caller's
+# thread. docs/observability.md lists the counts each one carries.
+PHASE_LOOP_LOCK_WAIT = "serve.loop.lock_wait"
+PHASE_ADMIT = "serve.step.admit"
+PHASE_DISPATCH = "serve.step.dispatch"
+PHASE_SYNC = "serve.step.sync"
+PHASE_BOOKKEEP = "serve.step.bookkeep"
+PHASE_DRAIN = "serve.loop.drain"
+PHASE_OBSERVE = "serve.loop.observe"
+PHASE_DELIVER = "serve.loop.deliver"
+PHASE_IDLE = "serve.loop.idle"
+PHASE_SUBMIT_LOCK_WAIT = "serve.submit.lock_wait"
+PHASES = (PHASE_LOOP_LOCK_WAIT, PHASE_ADMIT, PHASE_DISPATCH, PHASE_SYNC,
+          PHASE_BOOKKEEP, PHASE_DRAIN, PHASE_OBSERVE, PHASE_DELIVER,
+          PHASE_IDLE, PHASE_SUBMIT_LOCK_WAIT)
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None in a
+    process without jax (router, portal), which has no profiler either."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def phase(name: str, **counts: int):
+    """A context manager that writes the span ``name`` (one of
+    ``PHASES``), with ``counts`` on it, into the profiler's trace.
+
+    There is no switch: outside a profiler session a ``TraceAnnotation``
+    is a flag test, so "tracing off" is "no session open". The two things
+    that open one are the benchmark's ``--trace 1`` window and serve's
+    on-demand capture (``/debug/profile``). Counts known only once the
+    work is done go on through the entered span's ``set_metadata`` (the
+    sites that do so are in modules that import jax themselves: without
+    jax the shared null context is returned and enters as None)."""
+    annotation = _trace_annotation()
+    if annotation is None:
+        return _NO_SPAN
+    return annotation(name, **counts)
+
+
 # the jax.monitoring event that fires once per actual XLA compilation
 # (cache hits fire nothing); the other /jax/core/compile/* events time
 # tracing/lowering stages of the same compile and would triple-count
@@ -1139,4 +1199,4 @@ __all__ = ["Histogram", "RequestTrace", "TaskTrace", "TraceContext",
            "PromFamily", "parse_prom_text",
            "TELEMETRY_HISTOGRAMS", "TERMINAL_SPANS", "TASK_TERMINAL_SPANS",
            "DispatchTracker", "CompileTelemetry", "COMPILE_TELEMETRY",
-           "install_compile_telemetry"]
+           "install_compile_telemetry", "PHASES", "phase"]
