@@ -395,6 +395,78 @@ def test_flash_decode_layer_indexed_stack():
             rtol=2e-5, atol=2e-5)
 
 
+# (lengths, offsets, active, window, int8, layered) for a 4-row ring of
+# M = 256 read in blocks of 64; each case against the einsum on the same
+# inputs
+_RING_CASES = {
+    "random_offsets_and_lengths":
+        ([17, 100, 201, 63], [5, 200, 130, 250], None, 0, False, False),
+    "range_wraps_across_m":
+        ([90, 130, 40, 255], [250, 192, 255, 1], None, 0, False, False),
+    "length_zero":
+        ([0, 0, 0, 5], [0, 63, 255, 64], None, 0, False, False),
+    "inactive_row":
+        ([37, 100, 0, 200], [9, 250, 3, 77], [True, False, False, True],
+         0, False, False),
+    "row_fills_the_ring":
+        ([255, 255, 254, 255], [0, 100, 64, 255], None, 0, False, False),
+    "window_band_binds":
+        ([200, 255, 30, 129], [100, 7, 250, 192], None, 48, False, False),
+    "int8_with_scales":
+        ([90, 255, 0, 180], [250, 31, 64, 200], [True, True, True, False],
+         0, True, False),
+    "layer_of_the_stack":
+        ([17, 130, 201, 63], [5, 192, 130, 250], [True, True, False, True],
+         96, False, True),
+}
+
+
+@pytest.mark.parametrize("case", _RING_CASES)
+def test_flash_decode_ring_matches_cached_attention_einsum(case):
+    """The kernel's (length, offset, active) contract against the einsum
+    path of _cached_attention — what the serving decode block ran before
+    the kernel took rings, and still runs on the CPU and on a mesh."""
+    from tony_tpu.models.generate import _cached_attention, _quantize_kv
+    from tony_tpu.models.transformer import TransformerConfig
+    from tony_tpu.ops.decode_attention import flash_decode
+
+    lengths, offsets, active, window, int8, layered = _RING_CASES[case]
+    Ly, B, kvH, rep, D, M = 3, 4, 2, 2, 128, 256
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=kvH * rep * D, n_layers=Ly,
+        n_heads=kvH * rep, n_kv_heads=kvH, d_ff=64, max_seq_len=M,
+        dtype=jnp.float32, attn_window=window or None)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    q = jax.random.normal(ks[0], (B, 1, kvH * rep, D), jnp.float32)
+    ck = jax.random.normal(ks[1], (Ly, B, kvH, M, D), jnp.float32)
+    cv = jax.random.normal(ks[2], (Ly, B, kvH, M, D), jnp.float32)
+    k_scale = v_scale = None
+    if int8:
+        ck, k_scale = _quantize_kv(ck)
+        cv, v_scale = _quantize_kv(cv)
+    layer = 1 if layered else None
+    if not layered:
+        ck, cv = ck[2], cv[2]
+        if int8:
+            k_scale, v_scale = k_scale[2], v_scale[2]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    offsets = jnp.asarray(offsets, jnp.int32)
+    ref = _cached_attention(cfg, q, ck, cv, lengths, 1, k_scale, v_scale,
+                            ring_offsets=offsets, layer_idx=layer)
+    out = flash_decode(
+        q.reshape(B, kvH, rep, D), ck, cv, lengths, k_scale, v_scale,
+        ring_offsets=offsets,
+        active=None if active is None else jnp.asarray(active),
+        window=window, layer=layer, block_k=64, interpret=True,
+    ).reshape(ref.shape)
+    live = np.ones(B, bool) if active is None else np.asarray(active)
+    tol = 2e-3 if int8 else 2e-5
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               rtol=tol, atol=tol)
+    # a row that is not active reads nothing: zeros, whatever its ring holds
+    assert not np.asarray(out)[~live].any()
+
+
 def test_flash_under_a_mesh_runs_in_a_shard_map_and_matches_reference():
     """GSPMD cannot partition a Mosaic kernel, so on a mesh of several
     devices the model wraps the flash call in a shard_map over batch and

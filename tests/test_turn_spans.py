@@ -148,7 +148,8 @@ def test_step_phases_carry_their_counts(run):
     for counts in by_name[obs.PHASE_SYNC]:
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
-        assert set(counts) == {"tokens", "completions"}
+        assert set(counts) == {"tokens", "completions", "kv_blocks_read",
+                               "kv_blocks_ring"}
     done = {**run.engine[0], **run.engine[1]}
     assert len(done) == 11
     assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 11
@@ -235,6 +236,66 @@ def test_step_raises_what_it_raised_before(params):
         server.run_until_drained()
 
 
+def test_kv_blocks_read_never_pass_the_ring_and_equal_it_on_the_cpu(run):
+    """The CPU engine's decode step is the einsum over the whole ring
+    (``decode_kernel_engages`` is false here), so every processed block
+    reads as many blocks as its slots' rings hold: one of 64 a slot."""
+    spans = [s[4] for s in run.spans if s[0] == obs.PHASE_BOOKKEEP]
+    assert spans
+    for counts in spans:
+        assert 0 <= counts["kv_blocks_read"] <= counts["kv_blocks_ring"]
+        assert counts["kv_blocks_read"] == counts["kv_blocks_ring"]
+        assert counts["kv_blocks_ring"] % 3 == 0
+    assert sum(c["kv_blocks_ring"] for c in spans) > 0
+
+
+def test_kv_block_count_where_the_kernel_engages(params, monkeypatch):
+    """With the gate forced open the engine counts by the kernel's rule:
+    a few blocks of short rows, not the ring."""
+    from tony_tpu.models import serving
+
+    monkeypatch.setattr(serving, "decode_kernel_engages",
+                        lambda *a: True)
+    monkeypatch.setattr(serving, "kv_block_k", lambda *a: 16)
+    server = SlotServer(params, TINY, slots=3, max_len=64, block_size=4,
+                        prefill_chunk=8)
+    _drain(server, _requests(5, seed=5))
+    ring_a_block = 3 * (64 // 16)
+    blocks = server.kv_blocks_ring // ring_a_block
+    assert blocks >= 3 and server.kv_blocks_ring == blocks * ring_a_block
+    # prompts of 2-13 and up to 12 new tokens: a live row spans at most
+    # ceil(26 / 16) + 1 = 3 of its 4 blocks, and some rows are idle
+    assert blocks <= server.kv_blocks_read < server.kv_blocks_ring * 3 // 4
+
+
+@pytest.mark.parametrize("window", (0, 5, 40))
+@pytest.mark.parametrize("block_k, m_cap", [(16, 64), (16, 56), (64, 64)])
+def test_live_kv_blocks_against_a_brute_force_mask(block_k, m_cap, window):
+    """The block walk that ``flash_decode``'s index maps make, against the
+    set of blocks in which the einsum's mask has a visible position."""
+    from tony_tpu.ops.decode_attention import live_kv_blocks
+
+    rng = np.random.default_rng(block_k + m_cap + window)
+    n = 200
+    offsets = rng.integers(0, m_cap, n).astype(np.int32)
+    lengths = rng.integers(0, m_cap, n).astype(np.int32)
+    lengths[:4] = (0, m_cap - 1, m_cap - 1, 0)
+    offsets[:4] = (0, 0, m_cap - 1, m_cap - 1)
+    active = rng.random(n) < 0.8
+    first, count = live_kv_blocks(offsets, lengths, active, block_k=block_k,
+                                  m_cap=m_cap, window=window)
+    n_blocks = -(-m_cap // block_k)
+    idx = np.arange(m_cap)
+    for i in range(n):
+        logical = (idx - offsets[i]) % m_cap
+        seen = logical <= lengths[i]
+        if window:
+            seen &= logical > lengths[i] - window
+        want = set(idx[seen] // block_k) if active[i] else set()
+        walk = [(first[i] + j) % n_blocks for j in range(count[i])]
+        assert len(walk) == len(set(walk)) and set(walk) == want, i
+
+
 # ------------------------------------------------ the benchmark's readers
 
 def test_host_spans_reads_the_recorded_chip_trace(tmp_path):
@@ -268,11 +329,13 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     assert isinstance(value, float) and value >= 0.0
     if entry["unit"] == "%" and "occupancy" in entry["name"]:
         assert value <= 100.0
+    if entry["name"] == "decode_kv_read_pct":
+        assert value == 100.0       # the CPU engine reads the whole ring
 
 
-def test_new_entries_are_the_eight_and_the_shares_are_disjoint(
+def test_new_entries_are_the_nine_and_the_shares_are_disjoint(
         run, monkeypatch):
-    assert len(NEW_ENTRIES) == 8
+    assert len(NEW_ENTRIES) == 9
     monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
     pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
     named = [name for names in pct.PHASES.values() for name in names]

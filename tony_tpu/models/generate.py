@@ -26,7 +26,9 @@ choices:
   the forward's per-use casts; the f32 MoE router excepted).
   `kv_dtype="int8"` and `weight_dtype="int8"` are the two opt-ins that
   genuinely change numerics vs the full forward (within int8 resolution).
-  The flash-decode kernel (auto-dispatched at M>=4096 on TPU) computes
+  The flash-decode kernel (auto-dispatched at M>=4096 on TPU, for the
+  lockstep path and the serving ring alike; it reads only the cache
+  blocks that hold a visible position) computes
   softmax+PV in f32 like the einsum formulation, but its blockwise online
   softmax accumulates in a different ORDER — greedy tokens across the
   kernel gate agree to float tolerance, not provably bit-for-bit (a logit
@@ -168,9 +170,22 @@ def _quantize_kv(x):
     return q, scale[..., 0].astype(jnp.bfloat16)
 
 
+def decode_kernel_engages(cfg, m_cap: int) -> bool:
+    """Whether a single-token decode step over an ``m_cap``-position cache
+    may run the Pallas kernel (ops/decode_attention.py) instead of the
+    einsum below: the one gate on what the code observes, which the
+    serving engine's ``kv_blocks_read`` count asks too. Below ~4k
+    positions the einsum wins (one kernel launch a layer of fixed cost vs
+    a small cache read: measured crossover between M=2048 and 4096 on
+    v5e); the CPU keeps the einsum. A caller under a mesh does not ask: a
+    GSPMD-sharded decode would need a shard_map around the call."""
+    return (cfg.attn_impl != "ref" and m_cap >= 4096
+            and jax.default_backend() == "tpu")
+
+
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
-                      allow_kernel=True, layer_idx=None):
+                      allow_kernel=True, layer_idx=None, active=None):
     """q: [B, L, H, D] for the L new positions (absolute offsets cache_len..
     cache_len+L-1); ck/cv: [B, kvH, max_len, D] full cache buffers (already
     containing the new keys). Scores run against the whole static buffer;
@@ -195,29 +210,27 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
     whose index m holds logical position (m - offset_b) mod M. Offsets are
     chosen at admission so every active row's next write lands at the same
     global cursor index (see models/serving.py) — the mask maps indices to
-    logical positions per row; nothing else changes."""
+    logical positions per row; nothing else changes.
+
+    A single-token step (L == 1) with ``allow_kernel`` (no mesh, the
+    whole cache stack in hand) where ``decode_kernel_engages`` runs
+    ``flash_decode`` instead, lockstep and ring alike: the same (length,
+    offset) contract, but only the KV blocks that hold a visible position
+    are read, and a row that is not ``active`` ([B] bool, None = all)
+    reads nothing and returns zeros. The einsum ignores ``active`` (an
+    idle row attends over its stale positions; its output is dropped)."""
     b, l, h, d = q.shape
     kvh = ck.shape[1 if layer_idx is None else 2]
     rep = h // kvh
-    if (allow_kernel and l == 1 and jnp.ndim(cache_len) == 0
-            and ring_offsets is None and cfg.attn_impl != "ref"
-            and ck.shape[-2] >= 4096
-            and jax.default_backend() == "tpu"):
-        # long-context single-token lockstep decode on a real chip: the
-        # split-KV Pallas kernel streams the cache at ~1.2x its HBM bound
-        # where this function's einsum graph measured ~4.3x (16k context,
-        # v5e) — ops/decode_attention.py. Below ~4k positions the einsum
-        # wins (12 kernel launches/step of fixed cost vs a small cache
-        # read: measured crossover between M=2048 and 4096). With
-        # layer_idx the kernel indexes the full cache stack itself
-        # (slicing a pallas operand is a real copy). Mesh-sharded (GSPMD)
-        # and serving-ring paths keep the XLA formulation.
+    if allow_kernel and l == 1 and decode_kernel_engages(cfg, ck.shape[-2]):
+        # with layer_idx the kernel indexes the full cache stack itself
+        # (slicing a pallas operand is a real copy)
         from ..ops.decode_attention import flash_decode
 
         out = flash_decode(
             q.reshape(b, kvh, rep, d), ck, cv, cache_len,
-            k_scale, v_scale, window=cfg.attn_window or 0,
-            layer=layer_idx,
+            k_scale, v_scale, ring_offsets=ring_offsets, active=active,
+            window=cfg.attn_window or 0, layer=layer_idx,
         )
         return out.reshape(b, 1, h, d)
     if layer_idx is not None:           # einsum path works on the slice
@@ -395,15 +408,17 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     may be a [B] vector — every row then decodes at its OWN logical
     position (rope positions and attention masks per-row), which is the
     decode step of the continuous-batching slot pool (models/serving.py).
-    Per-row mode requires ``ring=(cursor, offsets)``: each row's buffer is
-    a ring where logical position p lives at index (p + offset_b) mod M,
-    and the offsets are chosen at admission so every row's CURRENT write
-    lands at the same scalar ``cursor`` index — the K/V write is then the
-    same cheap shared-offset dynamic_update_slice as the lockstep path
-    (per-row-offset writes lower to TPU scatters that cost more than the
-    whole step), and only the mask pays the index→logical remap
-    arithmetic. Active rows advance one position per step exactly as the
-    cursor does, so a live row never wraps onto its own data. Scalar
+    Per-row mode requires ``ring=(cursor, offsets, active)``: each row's
+    buffer is a ring where logical position p lives at index
+    (p + offset_b) mod M, and the offsets are chosen at admission so every
+    row's CURRENT write lands at the same scalar ``cursor`` index — the
+    K/V write is then the same cheap shared-offset dynamic_update_slice as
+    the lockstep path (per-row-offset writes lower to TPU scatters that
+    cost more than the whole step), and only the attention pays the
+    index→logical remap arithmetic. Active rows advance one position per
+    step exactly as the cursor does, so a live row never wraps onto its
+    own data; ``active`` [B] tells the decode kernel which rows to read
+    the cache for at all (_cached_attention). Scalar
     length (all rows in lockstep) is the generate() path; l > 1 per-row
     is unsupported (serving prefill has its own program). By default only
     the LAST position is projected through the unembed — generation never
@@ -436,12 +451,13 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     if per_row:
         if ring is None or l != 1:
             raise ValueError(
-                "per-row cache lengths require ring=(cursor, offsets) and "
-                "single-token steps (the serving decode contract)")
-        ring_cursor, ring_offsets = ring
+                "per-row cache lengths require ring=(cursor, offsets, "
+                "active) and single-token steps (the serving decode "
+                "contract)")
+        ring_cursor, ring_offsets, ring_active = ring
         positions = cache.length[:, None] + jnp.arange(l)
     else:
-        ring_cursor = ring_offsets = None
+        ring_cursor = ring_offsets = ring_active = None
         positions = jnp.broadcast_to(cache.length + jnp.arange(l), (b, l))
     x = params["embed"].astype(dt)[tokens]
     if shardings is not None:
@@ -509,7 +525,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
                 # a pallas call inside the GSPMD-sharded decode would need
                 # a shard_map wrapper; the sharded path keeps the einsum
                 allow_kernel=shardings is None,
-                layer_idx=i,
+                layer_idx=i, active=ring_active,
             )
         if w8:
             proj = jnp.einsum(

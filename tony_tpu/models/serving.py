@@ -19,8 +19,8 @@ Design — everything stays one compiled program over static shapes:
   lands at one shared global cursor index. The decode K/V write is then
   the same cheap shared-offset dynamic_update_slice the lockstep
   generate() path uses — per-row-offset writes lower to TPU scatters
-  that cost more than the whole step — and only the attention mask pays
-  the index→logical remap. Active rows advance one position per step
+  that cost more than the whole step — and only the attention pays the
+  index→logical remap. Active rows advance one position per step
   exactly as the cursor does, so a live row never wraps onto its own
   data. No tensor ever changes shape when requests come and go.
 - **One decode step for all slots.** Every block runs ``block_size``
@@ -28,7 +28,11 @@ Design — everything stays one compiled program over static shapes:
   or not. Inactive slots compute garbage that is never read: masking rows
   would need dynamic shapes, and a masked row costs the same HBM stream
   the active rows already pay (decode is weight-bound; the weight read is
-  shared). Per-row EOS/budget masks freeze finished rows' lengths
+  shared). The cache read is NOT shared: on a TPU with a ring of 4096 or
+  more the step's attention is ``flash_decode`` (ops/decode_attention.py),
+  which takes each slot's (length, offset, active) and streams only the KV
+  blocks that hold a position the slot's query may see — nothing for an
+  inactive slot. Per-row EOS/budget masks freeze finished rows' lengths
   in-device so a row that stops mid-block stays exactly where it stopped.
 - **Chunked prefill into one slot, one dispatch per chunk.** A new
   request's prompt (all but its last token) is fed through the
@@ -254,12 +258,14 @@ from .generate import (
     _quantize_kv,
     _rule_size,
     _validate_decode_mesh,
+    decode_kernel_engages,
     init_cache,
     init_prefix_pool,
     moe_dropfree,
     prepare_decode,
     sample_token,
 )
+from ..ops.decode_attention import kv_block_k, live_kv_blocks
 from .transformer import TransformerConfig, rms_norm
 from . import transformer
 
@@ -801,7 +807,8 @@ def _prefill_chunk(params, cache, d_tokens, d_active, d_target, d_offsets,
         else:
             row_ks = row_vs = None
         attn = _cached_attention(cfg, q, row_k, row_v, start, l,
-                                 row_ks, row_vs, ring_offsets=off_vec)
+                                 row_ks, row_vs, ring_offsets=off_vec,
+                                 allow_kernel=False)
         proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
         x = x + proj
         hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -905,7 +912,8 @@ def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
         else:
             row_ks = row_vs = None
         attn = _cached_attention(cfg, q, row_k, row_v, starts, l,
-                                 row_ks, row_vs, ring_offsets=offsets)
+                                 row_ks, row_vs, ring_offsets=offsets,
+                                 allow_kernel=False)
         proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
         x = x + proj
         hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -977,7 +985,7 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         cache, tokens, active, cursor, key = carry
         logits, new_cache = _forward_with_cache(
             params, cfg, tokens[:, None], cache, fused,
-            ring=(cursor, offsets), shardings=shardings)
+            ring=(cursor, offsets, active), shardings=shardings)
         key, sub = jax.random.split(key)
         # per-ROW sampling: each slot decodes at its own request's
         # temperature (0 = greedy) and top_k, so mixed traffic shares one
@@ -1113,7 +1121,10 @@ def _spec_rows_forward(params, cfg, tokens, ck, cv, ks_buf, vs_buf,
             cfg, q, ck[i], cv[i], lens, l,
             ks_buf[i] if int8_cache else None,
             vs_buf[i] if int8_cache else None,
-            ring_offsets=offsets)
+            # the einsum, also for the draft's single-token steps: this
+            # program hands the kernel a slice of the cache, which as a
+            # Pallas operand is a copy (as in the two prefill programs)
+            ring_offsets=offsets, allow_kernel=False)
         proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
         x = x + proj
         hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -1938,6 +1949,10 @@ class SlotServer:
         self.expired_requests = 0       # deadline passed while queued
         self.resets = 0                 # reset() calls (loop recoveries)
         self.blocks_dispatched = 0      # decode blocks sent to the device
+        # how far the decode kernel's live-range reads engage: KV blocks
+        # a processed block's last step streamed / those its rings hold
+        self.kv_blocks_read = 0
+        self.kv_blocks_ring = 0
         self.max_queue = int(max_queue)
         # ---- request durability (events/journal.py) ----
         # the journal records every accepted request's replay state
@@ -2013,6 +2028,13 @@ class SlotServer:
         self.block_size = block_size
         self.prefill_chunk = prefill_chunk
         self.kv_dtype = kv_dtype
+        # the unit of the kv_blocks_read / kv_blocks_ring counts, and
+        # whether the decode block's attention reads by it at all
+        self._kv_block_k = kv_block_k(
+            max_len, self.cfg.n_kv_heads, self.cfg.head_dim,
+            1 if kv_dtype == "int8" else jnp.dtype(self.cfg.dtype).itemsize)
+        self._decode_kernel = (self._shardings is None
+                               and decode_kernel_engages(self.cfg, max_len))
         self.weight_dtype = weight_dtype
         self.temperature = temperature
         self.top_k = top_k
@@ -2123,6 +2145,10 @@ class SlotServer:
         # (p + offset_b) mod max_len; offsets are picked at admission so
         # every active slot's next write is at the shared global cursor
         self._d_offsets = jnp.zeros((slots,), jnp.int32)
+        # its host mirror, current as of the newest dispatch: the paged
+        # engine's gather/scatter authority, and what each decode block's
+        # kv_blocks_read count is taken from (_count_kv_blocks)
+        self._np_offs = np.zeros((slots,), np.int32)
         self._d_temps = jnp.zeros((slots,), jnp.float32)  # per-request
         self._d_topks = jnp.zeros((slots,), jnp.int32)    # per-request
         if self._spec:
@@ -2211,14 +2237,12 @@ class SlotServer:
         self._np_tables = np.full((self.slots, entries), n, np.int32)
         self._d_tables = jnp.asarray(self._np_tables)
         self._tables_dirty = False
-        # per-slot ring offsets + write floors (host mirrors; the device
-        # offsets vector is _d_offsets as in ring mode). floor = the
+        # per-slot write floors (host mirror, beside _np_offs). floor = the
         # lowest logical position the scatter may commit for the slot:
         # max_len (= never) while the slot is idle or mid-prefill,
         # body.size once activated — the decode program writes garbage
         # rows for inactive slots, and those must never land in a block
         # the trie might share.
-        self._np_offs = np.zeros((self.slots,), np.int32)
         self._np_floor = np.full((self.slots,), self.max_len, np.int32)
         # slot -> exclusively-owned block ids (decode tail + cold-filled
         # prefix chunks; refcount-1 holders unless adopted by the trie)
@@ -3191,6 +3215,7 @@ class SlotServer:
             # submit-time prompt+budget <= max_len check.
             offset = (0 if self._spec
                       else (self._cursor - body.size) % self.max_len)
+            self._np_offs[slot] = offset
             # each active step advances length by 1 and emits 1 token, so
             # the remaining emissions end at body + remaining budget —
             # for a fresh request exactly body + max_new (the last
@@ -4150,6 +4175,7 @@ class SlotServer:
         self.telemetry.observe("decode_block_s", time.monotonic() - t0)
         seq = self.dispatch_tracker.track("decode_block", packed)
         self._pipeline.append({"packed": packed, "events": [], "seq": seq,
+                               "offsets": self._np_offs.copy(),
                                "w": self.block_size + 2
                                + (self.block_size * (2 * lp_k + 1)
                                   if lp_k else 0),
@@ -4261,6 +4287,7 @@ class SlotServer:
         # measure the pipeline lag this block's tokens were delivered at
         seq = self.dispatch_tracker.track("decode_block", packed)
         self._pipeline.append({"packed": packed, "events": [], "seq": seq,
+                               "offsets": self._np_offs.copy(),
                                "w": self.block_size + 2
                                + (self.block_size * (2 * lp_k + 1)
                                   if lp_k else 0),
@@ -4421,9 +4448,12 @@ class SlotServer:
             flat, lags = self._sync(recs)
         with phase(PHASE_BOOKKEEP) as span:
             done = len(self._done)
+            read, ring = self.kv_blocks_read, self.kv_blocks_ring
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
-                              completions=len(self._done) - done)
+                              completions=len(self._done) - done,
+                              kv_blocks_read=self.kv_blocks_read - read,
+                              kv_blocks_ring=self.kv_blocks_ring - ring)
 
     def _sync(self, recs) -> tuple:
         """-> (the blocks' packed results on the host, each block's
@@ -4494,6 +4524,7 @@ class SlotServer:
                 toks, n_accs, lengths, active = (
                     packed[:, :-2], None, packed[:, -2],
                     packed[:, -1].astype(bool))
+            self._count_kv_blocks(rec, lengths, active)
             for slot in np.nonzero(self._expect_active)[0]:
                 if slot in self._stop_cancelled:
                     continue
@@ -4607,6 +4638,25 @@ class SlotServer:
                 else:
                     self._apply_cancel(payload)
         return fed
+
+    def _count_kv_blocks(self, rec, lengths, active) -> None:
+        """Add one processed block to ``kv_blocks_read`` / ``_ring``: the
+        KV blocks its last decode step streams, per layer, by the
+        kernel's own rule (``live_kv_blocks`` on the block's final
+        lengths and the offsets it was dispatched with; a row that
+        emitted in the block counts as live for it) against the blocks
+        its slots' rings hold. Where the step runs the einsum (a mesh,
+        the CPU, a short ring, a speculative round) it reads them all."""
+        ring = self.slots * -(-self.max_len // self._kv_block_k)
+        read = ring
+        if self._decode_kernel and "offsets" in rec:
+            live = active | (lengths > self._expect_len)
+            read = int(live_kv_blocks(
+                rec["offsets"], lengths, live, block_k=self._kv_block_k,
+                m_cap=self.max_len, window=self.cfg.attn_window or 0,
+            )[1].sum())
+        self.kv_blocks_read += read
+        self.kv_blocks_ring += ring
 
     def _complete_slot(self, slot: int, req: Request, reason: str,
                        lag: float | None) -> None:
