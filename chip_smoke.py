@@ -92,6 +92,9 @@ REAL = dict(
     train4=dict(layers=16, batch=4, seq=2048, steps=41),
     restore_steps=20,
     kernel_len=1024, decode_len=4096,
+    # rows, width, vocabulary of the blockwise cross-entropy case: the
+    # benchmark's train cell (batch 2 x 4096 at Mistral-7B v0.3 widths)
+    ce_shape=(8192, 4096, 32768),
 )
 TOY = dict(
     llama=TOY_LLAMA, expect="cpu",
@@ -101,6 +104,7 @@ TOY = dict(
     train4=dict(layers=2, batch=4, seq=32, steps=41),
     restore_steps=20,
     kernel_len=256, decode_len=512,
+    ce_shape=(96, 32, 200),
 )
 
 
@@ -314,18 +318,20 @@ def phase_kernels(r: Runner, mode: dict) -> None:
         bad = [c["case"] for c in rep["cases"] if not c["ok"]]
         if bad:
             raise ValueError(f"kernel differs from the reference: {bad}")
+        pallas = [c for c in rep["cases"] if c["pallas"]]
         if mode["expect"] == "tpu":
-            interp = [c["case"] for c in rep["cases"] if not c["compiled"]]
+            interp = [c["case"] for c in pallas if not c["compiled"]]
             if interp:
                 raise ValueError(f"not compiled (no tpu_custom_call): {interp}")
         return {"device": rep["device"], "seconds": rep["seconds"],
                 "cache": rep["cache"],
-                "compiled": all(c["compiled"] for c in rep["cases"]),
+                "compiled": all(c["compiled"] for c in pallas),
                 "max_abs_err": {c["case"]: float(f"{c['max_abs_err']:.3g}")
                                 for c in rep["cases"]}}
     cmd = [PY, __file__, "--child", "kernels",
            "--kernel-len", str(mode["kernel_len"]),
-           "--decode-len", str(mode["decode_len"])]
+           "--decode-len", str(mode["decode_len"]),
+           "--ce-shape", ",".join(map(str, mode["ce_shape"]))]
     cold = r.run("kernels", cmd, timeout=400, check=check)
 
     def check_warm(out):
@@ -822,7 +828,7 @@ def child_generate(spec_path: str) -> int:
     return 0
 
 
-def child_kernels(kernel_len: int, decode_len: int) -> int:
+def child_kernels(kernel_len: int, decode_len: int, ce_shape: tuple) -> int:
     t0 = time.monotonic()
     import jax
     import jax.numpy as jnp
@@ -848,7 +854,7 @@ def child_kernels(kernel_len: int, decode_len: int) -> int:
     f32 = jnp.float32
     cases = []
 
-    def record(case, got, want, text, tol=3e-2):
+    def record(case, got, want, text, tol=3e-2, pallas=True):
         got = np.asarray(jnp.asarray(got, f32))
         want = np.asarray(jnp.asarray(want, f32))
         err = float(np.max(np.abs(got - want)))
@@ -856,7 +862,7 @@ def child_kernels(kernel_len: int, decode_len: int) -> int:
         cases.append({
             "case": case, "max_abs_err": err, "ref_max_abs": scale,
             "finite": bool(np.isfinite(got).all()),
-            "compiled": "tpu_custom_call" in text,
+            "compiled": "tpu_custom_call" in text, "pallas": pallas,
             "ok": bool(np.isfinite(got).all() and err <= tol * scale)})
 
     def reference(q, k, v, window):
@@ -954,6 +960,36 @@ def child_kernels(kernel_len: int, decode_len: int) -> int:
                           v8.astype(f32) * v8s[..., None].astype(f32),
                           length), text)
 
+    # the blockwise cross entropy (scan + matmuls, no Pallas): weighted sum
+    # and both gradients against autodiff of the dense path at f32
+    from tony_tpu.ops.cross_entropy import (blockwise_cross_entropy,
+                                            dense_cross_entropy)
+
+    n, d, v = ce_shape
+    ks = jax.random.split(jax.random.PRNGKey(n), 3)
+    x = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (d, v), f32) * d ** -0.5).astype(
+        jnp.bfloat16)
+    t = jax.random.randint(ks[2], (n,), 0, v)
+    # a masked mean's weights, every seventh row padding
+    valid = (jnp.arange(n) % 7 != 0).astype(f32)
+    rw = valid / valid.sum()
+
+    def ce_ref(x, w):
+        with jax.default_matmul_precision("highest"):
+            return jnp.sum(dense_cross_entropy(x, w, t) * rw)
+
+    ce = jax.jit(jax.value_and_grad(
+        lambda x, w: blockwise_cross_entropy(x, w, t, rw), (0, 1)))
+    text = ce.lower(x, w).as_text()
+    loss, (dx, dw) = ce(x, w)
+    want, (want_dx, want_dw) = jax.jit(jax.value_and_grad(ce_ref, (0, 1)))(
+        x.astype(f32), w.astype(f32))
+    for name, got, ref in (("loss", loss, want), ("dx", dx, want_dx),
+                           ("dw", dw, want_dw)):
+        record(f"blockwise_ce_{name}_{n}x{d}x{v}", got, ref, text,
+               pallas=False)
+
     for c in cases:
         print(json.dumps(c), flush=True)
     say({"device": device_report(), "cases": cases,
@@ -1026,6 +1062,7 @@ def main(argv=None) -> int:
     ap.add_argument("--spec", default="")
     ap.add_argument("--kernel-len", type=int, default=1024)
     ap.add_argument("--decode-len", type=int, default=4096)
+    ap.add_argument("--ce-shape", default="8192,4096,32768")
     args = ap.parse_args(argv)
 
     if args.child:
@@ -1033,8 +1070,9 @@ def main(argv=None) -> int:
         return {"device": child_device,
                 "ckpt": lambda: child_ckpt(args.config, args.dir, args.seed),
                 "generate": lambda: child_generate(args.spec),
-                "kernels": lambda: child_kernels(args.kernel_len,
-                                                 args.decode_len),
+                "kernels": lambda: child_kernels(
+                    args.kernel_len, args.decode_len,
+                    tuple(map(int, args.ce_shape.split(",")))),
                 }[args.child]()
 
     if not (ROOT / "tony_tpu" / "__init__.py").is_file():

@@ -432,7 +432,7 @@ def test_loss_fn_blockwise_ce_matches_dense():
     import dataclasses
 
     cfg_dense = dataclasses.replace(TINY, ce_impl="dense")
-    cfg_blk = dataclasses.replace(TINY, ce_impl="blockwise", ce_block_v=32)
+    cfg_blk = dataclasses.replace(TINY, ce_impl="blockwise")
     params = transformer.init(jax.random.PRNGKey(0), TINY)
     tokens, targets = synthetic_lm_batch(jax.random.PRNGKey(0), 2, 16, TINY.vocab_size)
     # pad a few targets to exercise the valid-mask path
@@ -447,13 +447,58 @@ def test_loss_fn_blockwise_ce_matches_dense():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+def test_blockwise_ce_on_a_mesh_chunks_local_rows_and_reduces_dw_once(monkeypatch):
+    """On data=2 x fsdp=4 every device chunks its own rows (two rows of 16
+    tokens a device, here forced into four chunks): loss and gradients
+    equal the dense step's, and the compiled train step sums dW across the
+    devices in one collective, outside every loop."""
+    import dataclasses
+    import re
+
+    import tony_tpu.ops.cross_entropy as ce
+
+    # the chunk's rows come from the shapes; shrink the budget, not the API
+    monkeypatch.setattr(ce, "LOGITS_BUFFER_BYTES", 8 * 256 * 4)
+    monkeypatch.setattr(ce, "_ROW_GRANULE", 8)
+    # a vocabulary no other width of TINY equals: dW is found by its shape
+    cfg = dataclasses.replace(TINY, vocab_size=256, ce_impl="blockwise")
+    cfg_dense = dataclasses.replace(cfg, ce_impl="dense")
+    assert ce.chunk_rows(2 * 16, cfg.vocab_size) == 8
+    mesh = build_mesh(MeshSpec(data=2, fsdp=4))
+    rules = dict(FSDP_TP_RULES)
+    params = transformer.init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = synthetic_lm_batch(jax.random.PRNGKey(1), 16, 16, cfg.vocab_size)
+    targets = targets.at[0, :3].set(-1)
+
+    def loss_and_grads(c):
+        return jax.jit(jax.value_and_grad(
+            lambda p: transformer.loss_fn(p, tokens, targets, c, mesh, rules)))(params)
+
+    l_blk, g_blk = loss_and_grads(cfg)
+    l_dense, g_dense = loss_and_grads(cfg_dense)
+    np.testing.assert_allclose(float(l_blk), float(l_dense), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_blk), jax.tree.leaves(g_dense)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+    bundle = create_train_step(cfg, mesh, rules=rules, key=jax.random.PRNGKey(0))
+    hlo = bundle.step_fn.lower(
+        bundle.params, bundle.opt_state, tokens, targets).compile().as_text()
+    d, v = cfg.d_model, cfg.vocab_size
+    # dW whole ([D, V]) or as the fsdp shard a reduce-scatter leaves
+    dw_shape = re.compile(rf"f32\[({d}|{d // 4}),{v}\]")
+    reduces = [line for line in hlo.splitlines()
+               if re.search(r" (all-reduce|reduce-scatter)(-start)?\(", line)
+               and dw_shape.search(line.split(" all-reduce")[0].split(" reduce-scatter")[0])]
+    assert len(reduces) == 1 and "/while/" not in reduces[0], reduces
+
+
 @pytest.mark.slow
 def test_blockwise_ce_trains_sharded():
     """Blockwise CE inside the sharded train step (fsdp mesh, unembed
     sharded): loss must decrease and match the dense-CE step."""
     import dataclasses
 
-    cfg = dataclasses.replace(TINY, ce_impl="blockwise", ce_block_v=32)
+    cfg = dataclasses.replace(TINY, ce_impl="blockwise")
     mesh = build_mesh(MeshSpec(data=2, fsdp=4))
     bundle = create_train_step(
         cfg, mesh, rules=dict(FSDP_TP_RULES), key=jax.random.PRNGKey(0))

@@ -134,56 +134,175 @@ def test_flash_bfloat16():
 
 # -------------------------------------------------------- blockwise CE
 
-@pytest.mark.parametrize("v,block_v", [(64, 16), (50, 16), (40, 64)])
-def test_blockwise_ce_matches_dense(v, block_v):
-    """Streaming logsumexp + in-block target gather == dense log_softmax,
-    including ragged vocab (v % block != 0) and block > vocab."""
+def _ce_inputs(n, d, v, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], (n, d), dtype)
+    w = jax.random.normal(ks[1], (d, v), dtype)
+    t = jax.random.randint(ks[2], (n,), 0, v)
+    return x, w, t
+
+
+def _subjaxprs(jaxpr):
+    """The jaxpr and every jaxpr nested in its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _subjaxprs(sub)
+
+
+def _dots(closed):
+    return [e for j in _subjaxprs(closed.jaxpr) for e in j.eqns
+            if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("v,rows", [(64, 8), (50, 16), (40, None)])
+def test_blockwise_ce_matches_dense(v, rows):
+    """Chunk-by-chunk logsumexp + target gather == dense log_softmax, over
+    several chunks of rows and over one: the weighted sum under each row's
+    one-hot weights (the row's own loss) and under a linspace weight
+    vector."""
     from tony_tpu.ops import blockwise_cross_entropy, dense_cross_entropy
 
-    key = jax.random.PRNGKey(0)
     n, d = 32, 16
-    x = jax.random.normal(key, (n, d), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(1), (d, v), jnp.float32)
-    t = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
-    nll = blockwise_cross_entropy(x, w, t, block_v)
+    x, w, t = _ce_inputs(n, d, v)
     expected = dense_cross_entropy(x, w, t)
-    np.testing.assert_allclose(np.asarray(nll), np.asarray(expected), atol=1e-5)
+    per_row = jax.vmap(
+        lambda rw: blockwise_cross_entropy(x, w, t, rw, rows))(jnp.eye(n))
+    np.testing.assert_allclose(
+        np.asarray(per_row), np.asarray(expected), atol=1e-5)
+    weights = jnp.linspace(0.1, 1.0, n)
+    np.testing.assert_allclose(
+        float(blockwise_cross_entropy(x, w, t, weights, rows)),
+        float(jnp.sum(expected * weights)), rtol=1e-6)
 
 
 def test_blockwise_ce_gradients_match_dense():
-    """Custom VJP (blockwise dx and dW, never [N,V]) == XLA autodiff of the
-    dense path, for a non-uniform per-row cotangent."""
+    """Custom VJP (dx and dW formed chunk by chunk in the forward rule, never
+    [N,V]) == XLA autodiff of the dense path, for non-uniform row weights
+    and a cotangent that is not 1; the row weights' own gradient is each
+    row's loss."""
     from tony_tpu.ops import blockwise_cross_entropy, dense_cross_entropy
 
-    n, d, v, bv = 24, 8, 50, 16
-    x = jax.random.normal(jax.random.PRNGKey(3), (n, d), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(4), (d, v), jnp.float32)
-    t = jax.random.randint(jax.random.PRNGKey(5), (n,), 0, v)
+    n, d, v, rows = 24, 8, 50, 8
+    x, w, t = _ce_inputs(n, d, v, seed=3)
     weights = jnp.linspace(0.1, 1.0, n)
 
-    def loss_blk(x, w):
-        return jnp.sum(blockwise_cross_entropy(x, w, t, bv) * weights)
+    def loss_blk(x, w, rw):
+        return 0.7 * blockwise_cross_entropy(x, w, t, rw, rows)
 
-    def loss_dense(x, w):
-        return jnp.sum(dense_cross_entropy(x, w, t) * weights)
+    def loss_dense(x, w, rw):
+        return 0.7 * jnp.sum(dense_cross_entropy(x, w, t) * rw)
 
-    gx1, gw1 = jax.grad(loss_blk, argnums=(0, 1))(x, w)
-    gx2, gw2 = jax.grad(loss_dense, argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx2), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(gw1), np.asarray(gw2), atol=1e-5)
+    got = jax.grad(loss_blk, argnums=(0, 1, 2))(x, w, weights)
+    want = jax.grad(loss_dense, argnums=(0, 1, 2))(x, w, weights)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
 def test_blockwise_ce_bfloat16_inputs():
     from tony_tpu.ops import blockwise_cross_entropy, dense_cross_entropy
 
     n, d, v = 16, 8, 64
-    x = jax.random.normal(jax.random.PRNGKey(6), (n, d), jnp.bfloat16)
-    w = jax.random.normal(jax.random.PRNGKey(7), (d, v), jnp.bfloat16)
-    t = jax.random.randint(jax.random.PRNGKey(8), (n,), 0, v)
-    nll = blockwise_cross_entropy(x, w, t, 16)
-    assert nll.dtype == jnp.float32
+    x, w, t = _ce_inputs(n, d, v, jnp.bfloat16, seed=6)
+    weights = jnp.linspace(0.1, 1.0, n)
+    loss, (dx, dw) = jax.value_and_grad(
+        lambda x, w: blockwise_cross_entropy(x, w, t, weights, 8), (0, 1))(x, w)
+    assert loss.dtype == jnp.float32
+    assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.bfloat16
     expected = dense_cross_entropy(x.astype(jnp.float32), w.astype(jnp.float32), t)
-    np.testing.assert_allclose(np.asarray(nll), np.asarray(expected), atol=5e-2)
+    np.testing.assert_allclose(
+        float(loss), float(jnp.sum(expected * weights)), rtol=5e-3)
+
+
+def test_blockwise_ce_three_products_none_in_the_backward_rule():
+    """value_and_grad of the op holds three products with a V-sized side,
+    each on a chunk of rows inside the one loop; the pullback (the backward
+    rule alone) holds none; the loss alone holds one."""
+    from tony_tpu.ops import blockwise_cross_entropy
+
+    n, d, v, rows = 64, 8, 64, 16
+    x, w, t = _ce_inputs(n, d, v)
+    rw = jnp.full((n,), 1.0 / n)
+
+    def op(x, w):
+        return blockwise_cross_entropy(x, w, t, rw, rows)
+
+    dots = _dots(jax.make_jaxpr(jax.value_and_grad(op, (0, 1)))(x, w))
+    shapes = sorted(tuple(v.aval.shape for v in e.invars) for e in dots)
+    assert shapes == sorted([
+        ((rows, d), (d, v)),        # a chunk's logits
+        ((rows, v), (v, d)),        # dx[chunk] = ds @ w^T
+        ((d, rows), (rows, v)),     # dW += x[chunk]^T @ ds
+    ])
+    _, pullback = jax.vjp(op, x, w)
+    assert _dots(jax.make_jaxpr(pullback)(jnp.float32(1.0))) == []
+    assert len(_dots(jax.make_jaxpr(op)(x, w))) == 1
+
+
+@pytest.mark.parametrize("grad", [True, False])
+def test_blockwise_ce_keeps_nothing_larger_than_a_chunk_of_logits(grad):
+    """With N of several chunks no value of the jaxpr (value_and_grad's, or
+    the loss's alone) is larger than [chunk, V] (here D*V and N*D are
+    smaller still), and the chunk's logits are there."""
+    from tony_tpu.ops import blockwise_cross_entropy
+
+    n, d, v, rows = 64, 8, 64, 16
+    x, w, t = _ce_inputs(n, d, v)
+    rw = jnp.full((n,), 1.0 / n)
+
+    def op(x, w):
+        return blockwise_cross_entropy(x, w, t, rw, rows)
+
+    closed = jax.make_jaxpr(jax.value_and_grad(op, (0, 1)) if grad else op)(x, w)
+    sizes = [v.aval.size for j in _subjaxprs(closed.jaxpr) for e in j.eqns
+             for v in e.outvars]
+    assert max(sizes) == rows * v < n * v
+
+
+@pytest.mark.parametrize("n,rows", [(30, 8), (30, None), (32, 8)])
+def test_blockwise_ce_ragged_rows_vocab_and_zero_weights(n, rows):
+    """N not a multiple of the chunk (the last chunk filled with rows of
+    weight 0), a vocabulary of 50 columns, and padding rows: loss and
+    gradients match dense autodiff to 1e-5, and a row of weight 0 gets an
+    exactly zero row of dx."""
+    from tony_tpu.ops import blockwise_cross_entropy, dense_cross_entropy
+
+    d, v = 8, 50
+    x, w, t = _ce_inputs(n, d, v, seed=9)
+    rw = jnp.linspace(0.1, 1.0, n).at[jnp.array([0, 7, n - 1])].set(0.0)
+    got_l, got = jax.value_and_grad(
+        lambda x, w: blockwise_cross_entropy(x, w, t, rw, rows), (0, 1))(x, w)
+    want_l, want = jax.value_and_grad(
+        lambda x, w: jnp.sum(dense_cross_entropy(x, w, t) * rw), (0, 1))(x, w)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    assert not np.asarray(got[0])[[0, 7, n - 1]].any()
+
+
+@pytest.mark.parametrize("n,v,want", [
+    (8192, 32768, 4096),        # the benchmark's train cell: two chunks
+    (4096, 32768, 4096),        # a device's rows of the fsdp=4 cell: one
+    (32768, 262144, 512),       # the docstring's 32 GB of logits: 64 chunks
+    (8192, 128256, 1024),       # Llama-3 vocabulary
+    (8193, 32768, 2816),        # ragged: three even chunks, not 4096+4096+1
+    (5000, 32000, 2560),
+    (24, 50, 24),               # everything in one chunk: the rows themselves
+])
+def test_blockwise_ce_chunk_rows_follow_the_shapes(n, v, want):
+    from tony_tpu.ops.cross_entropy import LOGITS_BUFFER_BYTES, chunk_rows
+
+    rows = chunk_rows(n, v)
+    assert rows == want
+    chunks = -(-n // rows)
+    if chunks > 1:
+        assert rows * v * 4 <= LOGITS_BUFFER_BYTES
+        # one chunk fewer would not fit
+        assert -(-n // (chunks - 1)) * v * 4 > LOGITS_BUFFER_BYTES
 
 
 def test_flash_multi_qblock_paths_small_blocks():

@@ -78,12 +78,11 @@ class TransformerConfig:
     # outputs and recomputes only elementwise ops (jax dots_saveable) —
     # most of full-remat's memory saving at a fraction of its FLOPs cost
     remat_policy: str = "full"
-    # cross-entropy: "dense" materializes [B,L,V] logits; "blockwise" streams
-    # the vocab in ce_block_v blocks (ops/cross_entropy.py) so nothing of
-    # size [N,V] is ever live; "auto" goes blockwise at vocab >= 16384 unless
-    # the mesh has a tensor axis (vocab-sharded dense wins there)
+    # cross-entropy: "dense" materializes [B,L,V] logits; "blockwise" forms
+    # loss and gradients a chunk of rows at a time (ops/cross_entropy.py) so
+    # nothing of size [N,V] is ever live; "auto" goes blockwise at vocab >=
+    # 16384 unless the mesh has a tensor axis (vocab-sharded dense wins there)
     ce_impl: str = "auto"
-    ce_block_v: int = 2048
 
     @property
     def head_dim(self) -> int:
@@ -418,11 +417,12 @@ def _use_blockwise_ce(cfg: TransformerConfig, mesh=None, rules=None) -> bool:
     if cfg.ce_impl == "dense":
         return False
     # auto: blockwise pays at large vocab, EXCEPT when the unembed's vocab
-    # dim is mesh-sharded (tensor parallelism) — the blockwise sweep's traced
-    # dynamic_slice would make GSPMD gather the full unembed on every device,
-    # while the dense einsum keeps logits vocab-sharded (see
-    # ops/cross_entropy.py sharding note). The rules table's "vocab" row is
-    # the source of truth for which axis that is; default "tensor".
+    # dim is mesh-sharded (tensor parallelism) — there the dense einsum keeps
+    # the logits vocab-sharded, a device's share of them is small, and the
+    # blockwise op's chunk loop would leave the vocab axis to GSPMD inside
+    # every chunk (see ops/cross_entropy.py sharding note). The rules
+    # table's "vocab" row is the source of truth for which axis that is;
+    # default "tensor".
     from ..parallel.sharding import mesh_shards_rule
 
     if mesh_shards_rule(mesh, rules, "vocab", default=("tensor",)):
@@ -430,39 +430,48 @@ def _use_blockwise_ce(cfg: TransformerConfig, mesh=None, rules=None) -> bool:
     return cfg.vocab_size >= 16384
 
 
+def _rows_shard(mesh, rules):
+    """What ``blockwise_cross_entropy`` needs to know of the mesh: the axes
+    that shard the rows' [B, L] (batch, and sequence under SP), or None
+    where every device holds every row. The op then chunks each device's
+    own rows and sums dW across them once (ops/cross_entropy.py)."""
+    from ..parallel.sharding import mesh_shards_rule
+
+    batch = mesh_shards_rule(mesh, rules, "batch", default=("data", "fsdp"))
+    seq = mesh_shards_rule(mesh, rules, "act_seq")
+    return (mesh, (batch or None, seq or None)) if batch + seq else None
+
+
 def token_nll(x, unembed, targets, cfg: TransformerConfig, mesh=None,
               rules=None, reduction: str = "mean"):
     """Masked mean next-token NLL from final hidden states, dispatching on
-    cfg.ce_impl: blockwise CE streams the unembed matmul + softmax over
-    vocab blocks so the [B, L, V] logits tensor never materializes (forward
-    or backward); dense CE is the materializing reference path. ``auto``
+    cfg.ce_impl: blockwise CE runs the unembed matmul + softmax (and, under
+    grad, both gradient products) a chunk of rows at a time so the [B, L, V]
+    logits tensor never materializes (forward or backward); dense CE is the
+    materializing reference path. ``auto``
     also inspects the mesh/rules: with the vocab dim mesh-sharded the dense
     path stays vocab-sharded and wins.
 
     x: [B, L, D] hidden (post final norm), unembed: [D, V], targets: [B, L]
     int with -1 = pad (masked out here) -> scalar mean NLL (f32).
+    ``reduction="sum"`` leaves the division to the caller's own (e.g.
+    global) valid count — the pipelined head path, where per-microbatch
+    means would up-weight pad-heavy microbatches.
     """
     valid = targets >= 0
     safe_targets = jnp.where(valid, targets, 0)
+    count = 1 if reduction == "sum" else jnp.maximum(valid.sum(), 1)
     if _use_blockwise_ce(cfg, mesh, rules):
-        from ..ops.cross_entropy import blockwise_cross_entropy as _ce
-        nll = _ce(
-            x.reshape(-1, x.shape[-1]), unembed.astype(cfg.dtype),
-            safe_targets.reshape(-1), cfg.ce_block_v,
-        )
-    else:
-        from ..ops.cross_entropy import dense_cross_entropy
-        nll = dense_cross_entropy(
-            x.reshape(-1, x.shape[-1]), unembed.astype(cfg.dtype),
-            safe_targets.reshape(-1),
-        )
-    nll = nll.reshape(targets.shape)
-    if reduction == "sum":
-        # caller divides by its own (e.g. global) valid count — the
-        # pipelined head path, where per-microbatch means would up-weight
-        # pad-heavy microbatches
-        return (nll * valid).sum()
-    return (nll * valid).sum() / jnp.maximum(valid.sum(), 1)
+        from ..ops.cross_entropy import blockwise_cross_entropy
+        return blockwise_cross_entropy(
+            x, unembed.astype(cfg.dtype), safe_targets,
+            valid.astype(jnp.float32) / count, shard=_rows_shard(mesh, rules))
+    from ..ops.cross_entropy import dense_cross_entropy
+    nll = dense_cross_entropy(
+        x.reshape(-1, x.shape[-1]), unembed.astype(cfg.dtype),
+        safe_targets.reshape(-1),
+    ).reshape(targets.shape)
+    return (nll * valid).sum() / count
 
 
 def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
@@ -470,8 +479,8 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
     """Next-token cross entropy (+ MoE aux); targets [B, L] with -1 = pad.
 
     With blockwise CE (cfg.ce_impl, default at large vocab) the [B, L, V]
-    logits tensor is never materialized — the unembed matmul and softmax
-    stream the vocabulary in blocks, forward and backward."""
+    logits tensor is never materialized — the unembed matmul, the softmax
+    and the loss's gradients go a chunk of rows at a time."""
     x, aux = apply_hidden(params, tokens, cfg, mesh)
     return token_nll(x, params["unembed"], targets, cfg, mesh, rules) + aux
 

@@ -4,31 +4,40 @@ The last matmul of an LM — ``hidden @ unembed`` — produces a [B*L, V] f32
 logits tensor that usually dwarfs every activation in the model: at
 B*L=32k, V=256k that is 32GB, and XLA autodiff keeps it (plus the softmax)
 alive for the backward. This op fuses the unembed matmul with the softmax
-cross entropy by streaming the vocabulary in blocks under ``lax.scan``:
+cross entropy and with the reduction its callers make at once: it takes the
+row weights (``valid / count`` for a mean, ``valid`` for a sum) and returns
+the weighted sum of the rows' losses. Its cotangent is then one scalar, so
+the gradients can be formed while a block of logits exists.
 
-- forward: running (max, sumexp) over vocab blocks — the classic online
-  logsumexp — plus an in-block gather of each row's target logit. Peak
-  live memory is [N, block_v] instead of [N, V].
-- backward (custom VJP): one more sweep over vocab blocks recomputing the
-  block logits from the saved (hidden, unembed, lse) residuals;
-  ``ds = g * (softmax_block - onehot_block)`` feeds both dx (accumulated)
-  and dW (written block-by-block into a single [D, V] carry). Nothing of
-  size [N, V] ever exists, and no extra copy of the unembed is made:
-  ragged vocabularies are handled by clamping the last block's start and
-  masking the overlapped columns, not by padding the matrix.
+The blocks are chunks of rows under ``lax.scan``; a chunk's f32 logits
+[chunk, V] are the largest value ever live, and the chunk's rows come from
+the shapes (``chunk_rows``: the most rows whose logits fit
+LOGITS_BUFFER_BYTES).
 
-Every block op is a large dense matmul -> MXU-friendly; block_v defaults to
-a lane-aligned 2048. This is an XLA-level fusion (scan + matmuls), not a
-Pallas kernel: the matmuls already saturate the MXU and XLA fuses the
-elementwise tail into them, so a hand kernel would only re-derive the same
-schedule.
+- loss only (the primal: evaluation, ``jit(loss_fn)`` without grad): one
+  sweep of the unembedding — per chunk the logits, their logsumexp and each
+  row's target logit.
+- loss and gradients (the custom VJP's forward rule): **three** sweeps,
+  forward + backward of one matrix product and no more. While a chunk's
+  logits are live, ``ds = row_weight * (softmax - onehot)`` feeds dx[chunk]
+  and dW (one [D, V] f32 accumulator carried over the chunks). The backward
+  rule only scales dx and dW by the scalar cotangent: it has no sweep of
+  its own, and nothing is recomputed.
 
-Sharding note: the blockwise sweep slices the vocab axis with a traced
-start index, which forces GSPMD to gather a vocab-sharded (tensor-parallel)
-unembed. The model-side dispatch (models/transformer.py token_nll) therefore
-keeps the dense sharded path whenever the mesh has a tensor axis; blockwise
-is for the DP/FSDP/SP regimes where the unembed is replicated or
-fully-sharded-then-gathered anyway.
+Every product is a large dense matmul -> MXU-friendly. This is an XLA-level
+fusion (scan + matmuls), not a Pallas kernel: the matmuls already saturate
+the MXU and XLA fuses the elementwise tail into them, so a hand kernel
+would only re-derive the same schedule.
+
+Sharding note: on a mesh whose batch or sequence axes shard the rows, the
+model-side dispatch (models/transformer.py token_nll) names those axes in
+``shard``, and both rules run their loop under a ``shard_map`` over them:
+each device chunks its own rows by its own row count, and dW is summed
+across devices once, after the loop, in f32 (a scan carry under GSPMD could
+not hold a partial sum, so GSPMD alone would reduce it every chunk). The
+custom VJP sits outside the shard_map, so nothing is differentiated through
+it. With the vocab dim mesh-sharded (tensor parallelism) token_nll keeps the
+dense path, whose logits stay vocab-sharded.
 
 No reference counterpart: TonY has no compute layer (SURVEY.md §2.3); this
 is part of the TPU-native capability layer.
@@ -42,112 +51,148 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-NEG_INF = -1e30
-DEFAULT_BLOCK_V = 2048
+# the most a chunk's f32 logits may take; with the row granule below, the
+# whole rule for the chunk's rows
+LOGITS_BUFFER_BYTES = 512 * 2**20
+_ROW_GRANULE = 128
 
 
-def _num_blocks(v: int, block_v: int) -> int:
-    return -(-v // block_v)
+def chunk_rows(n: int, v: int) -> int:
+    """Rows of one chunk for ``n`` rows against ``v`` columns: the fewest
+    chunks whose [rows, V] f32 logits fit LOGITS_BUFFER_BYTES, the rows
+    spread evenly over them in multiples of the row granule. ``n`` itself
+    where one chunk holds every row."""
+    most = max(LOGITS_BUFFER_BYTES // (4 * v) // _ROW_GRANULE, 1) * _ROW_GRANULE
+    chunks = -(-n // most)
+    if chunks == 1:
+        return n
+    return -(-n // (chunks * _ROW_GRANULE)) * _ROW_GRANULE
 
 
-def _block_cols(x, w, j, block_v, v):
-    """Logits for vocab block j in f32 without copying/padding w: the last
-    block's start is clamped to v - block_v, and columns already covered by
-    the previous block are masked to NEG_INF. Returns (logits [N, BV],
-    start, cols [N, BV] global column ids, owned mask or None)."""
-    lo = j * block_v
-    start = jnp.minimum(lo, v - block_v)
-    wj = lax.dynamic_slice_in_dim(w, start, block_v, axis=1)
-    logits = jnp.dot(x, wj, preferred_element_type=jnp.float32)
-    cols = start[None, None] + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    if v % block_v != 0:
-        owned = cols >= lo
-        logits = jnp.where(owned, logits, NEG_INF)
-    else:
-        owned = None
-    return logits, start, cols, owned
+def _in_chunks(rows_per_chunk, v, *per_row):
+    """[N, ...] arrays -> [chunks, rows, ...], the last chunk filled with
+    zeros (rows of weight 0: they add exact zeros to loss, dx and dW)."""
+    n = per_row[0].shape[0]
+    rows = min(rows_per_chunk or chunk_rows(n, v), n)
+    chunks = -(-n // rows)
+    if chunks * rows != n:
+        per_row = [jnp.pad(a, [(0, chunks * rows - n)] + [(0, 0)] * (a.ndim - 1))
+                   for a in per_row]
+    return [a.reshape(chunks, rows, *a.shape[1:]) for a in per_row]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def blockwise_cross_entropy(x, w, targets, block_v=DEFAULT_BLOCK_V):
-    """Per-row softmax cross entropy of ``x @ w`` against ``targets``
-    without materializing the [N, V] logits.
-
-    x: [N, D] hidden states (any float dtype; accumulation in f32)
-    w: [D, V] unembedding matrix
-    targets: [N] int — caller handles padding rows (mask the returned nll)
-    -> nll [N] f32
-    """
-    nll, _ = _ce_fwd_pass(x, w, targets, block_v)
-    return nll
+def _chunk_nll(xc, w, tc):
+    """A chunk's f32 logits [C, V], their logsumexp and the rows' losses."""
+    logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    target_logit = jnp.take_along_axis(logits, tc[:, None], axis=1)[:, 0]
+    return logits, lse, lse - target_logit
 
 
-def _ce_fwd_pass(x, w, targets, block_v):
-    v = w.shape[1]
-    block_v = min(block_v, v)
-    nb = _num_blocks(v, block_v)
+def _local_loss(rows_per_chunk, x, w, targets, row_weights):
+    """-> ((loss,), ()): what is summed over devices, what is per row."""
+    def chunk(loss, rows_in):
+        xc, tc, rwc = rows_in
+        _, _, nll = _chunk_nll(xc, w, tc)
+        return loss + jnp.sum(rwc * nll), None
+
+    loss, _ = lax.scan(chunk, jnp.zeros((), jnp.float32), _in_chunks(
+        rows_per_chunk, w.shape[1], x, targets, row_weights))
+    return (loss,), ()
+
+
+def _local_loss_and_grads(rows_per_chunk, x, w, targets, row_weights):
+    """For a unit cotangent, chunk of rows by chunk -> ((loss, dW still
+    f32), (dx, the rows' losses))."""
     n = x.shape[0]
 
-    def body(carry, j):
-        m, l, tl = carry
-        logits, start, _, _ = _block_cols(x, w, j, block_v, v)   # [N, BV]
-        bm = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m, bm)
-        l_new = l * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[:, None]), axis=-1
-        )
-        # in-block target gather: rows whose target this block owns
-        lo = j * block_v
-        in_blk = (targets >= lo) & (targets < lo + block_v)
-        idx = jnp.clip(targets - start, 0, block_v - 1)
-        row_logit = jnp.take_along_axis(logits, idx[:, None], axis=1)[:, 0]
-        tl = jnp.where(in_blk, row_logit, tl)
-        return (m_new, l_new, tl), None
+    def chunk(carry, rows_in):
+        loss, dw = carry
+        xc, tc, rwc = rows_in
+        logits, lse, nll = _chunk_nll(xc, w, tc)
+        onehot = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == tc[:, None]
+        ds = rwc[:, None] * (jnp.exp(logits - lse[:, None]) - onehot)  # [C, V] f32
+        dxc = jnp.dot(ds, w.T.astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+        dw = dw + jnp.dot(xc.astype(jnp.float32).T, ds,
+                          preferred_element_type=jnp.float32)
+        return (loss + jnp.sum(rwc * nll), dw), (dxc.astype(x.dtype), nll)
 
-    m0 = jnp.full((n,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n,), jnp.float32)
-    tl0 = jnp.zeros((n,), jnp.float32)
-    (m, l, tl), _ = lax.scan(body, (m0, l0, tl0), jnp.arange(nb))
-    lse = m + jnp.log(jnp.maximum(l, 1e-37))
-    return lse - tl, lse
+    carry0 = (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32))
+    (loss, dw), (dx, nll) = lax.scan(chunk, carry0, _in_chunks(
+        rows_per_chunk, w.shape[1], x, targets, row_weights))
+    return (loss, dw), (dx.reshape(-1, x.shape[1])[:n], nll.reshape(-1)[:n])
 
 
-def _ce_vjp_fwd(x, w, targets, block_v):
-    nll, lse = _ce_fwd_pass(x, w, targets, block_v)
-    return nll, (x, w, targets, lse)
+def _on_own_rows(local, shard, x, w, targets, row_weights):
+    """``local`` on the rows flattened: all of them, or, with ``shard`` =
+    (mesh, for each leading dim of the rows the mesh axes that shard it),
+    each device's own under a shard_map over those axes, its sums (the
+    loss; dW, still f32) then summed over the devices, once. What it
+    returns per row comes back in the rows' shape."""
+    def flat(x, w, targets, row_weights):
+        sums, per_row = local(
+            x.reshape(-1, x.shape[-1]), w, targets.reshape(-1),
+            row_weights.reshape(-1).astype(jnp.float32))
+        return sums, tuple(a.reshape(targets.shape + a.shape[1:])
+                           for a in per_row)
+
+    if shard is None:
+        return flat(x, w, targets, row_weights)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    mesh, row_axes = shard
+    axes = tuple(a for dim in row_axes for a in dim or ())
+
+    def per_device(*args):
+        sums, per_row = flat(*args)
+        return lax.psum(sums, axes), per_row
+
+    rows = P(*row_axes)
+    return shard_map(
+        per_device, mesh=mesh, in_specs=(rows, P(), rows, rows),
+        out_specs=(P(), rows), axis_names=frozenset(axes), check_vma=False,
+    )(x, w, targets, row_weights)
 
 
-def _ce_vjp_bwd(block_v, res, g):
-    x, w, targets, lse = res
-    v = w.shape[1]
-    block_v = min(block_v, v)
-    nb = _num_blocks(v, block_v)
-    gf = g.astype(jnp.float32)
-    xf32t = x.astype(jnp.float32).T
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def blockwise_cross_entropy(x, w, targets, row_weights, rows_per_chunk=None,
+                            shard=None):
+    """Weighted sum over rows of the softmax cross entropy of ``x @ w``
+    against ``targets``, without materializing the [N, V] logits.
 
-    def body(carry, j):
-        dx, dw = carry
-        logits, start, cols, owned = _block_cols(x, w, j, block_v, v)
-        p = jnp.exp(logits - lse[:, None])            # masked cols: exp->0
-        onehot = cols == targets[:, None]
-        if owned is not None:
-            onehot &= owned                           # target owned elsewhere
-        ds = gf[:, None] * (p - onehot)               # [N, BV] f32, 0 in overlap
-        wj = lax.dynamic_slice_in_dim(w, start, block_v, axis=1)
-        dx = dx + jnp.dot(
-            ds, wj.T.astype(jnp.float32), preferred_element_type=jnp.float32
-        )
-        dwj = jnp.dot(xf32t, ds, preferred_element_type=jnp.float32)  # [D, BV]
-        # read-modify-write the block into the single [D, V] accumulator;
-        # overlapped columns add exact zeros (ds masked), so no double count
-        cur = lax.dynamic_slice_in_dim(dw, start, block_v, axis=1)
-        dw = lax.dynamic_update_slice_in_dim(dw, cur + dwj, start, axis=1)
-        return (dx, dw), None
+    x: [..., D] hidden states (any float dtype; accumulation in f32)
+    w: [D, V] unembedding matrix
+    targets: [...] int
+    row_weights: [...] float — 0 for padding rows, ``valid / count`` for a
+        masked mean, ``valid`` for a sum
+    rows_per_chunk (static): the rows whose logits are live at a time;
+        None takes ``chunk_rows`` of the (device's) rows
+    shard (static): None, or (mesh, for each leading dim of the rows the
+        mesh axes that shard it, or None) — see ``_on_own_rows``
+    -> scalar f32
+    """
+    (loss,), _ = _on_own_rows(
+        functools.partial(_local_loss, rows_per_chunk), shard,
+        x, w, targets, row_weights)
+    return loss
 
-    dx0 = jnp.zeros(x.shape, jnp.float32)
-    dw0 = jnp.zeros(w.shape, jnp.float32)
-    (dx, dw), _ = lax.scan(body, (dx0, dw0), jnp.arange(nb))
-    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+def _ce_vjp_fwd(x, w, targets, row_weights, rows_per_chunk, shard):
+    (loss, dw), (dx, nll) = _on_own_rows(
+        functools.partial(_local_loss_and_grads, rows_per_chunk), shard,
+        x, w, targets, row_weights)
+    return loss, (dx, dw.astype(w.dtype), nll.astype(row_weights.dtype))
+
+
+def _ce_vjp_bwd(rows_per_chunk, shard, res, g):
+    def scaled(a):
+        return (g * a.astype(jnp.float32)).astype(a.dtype)
+
+    dx, dw, nll = res
+    # targets take no cotangent; the row weights' is each row's loss
+    return scaled(dx), scaled(dw), None, scaled(nll)
 
 
 blockwise_cross_entropy.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
@@ -160,4 +205,4 @@ def dense_cross_entropy(x, w, targets):
     return -jnp.take_along_axis(logp, targets[:, None], axis=1)[:, 0]
 
 
-__all__ = ["blockwise_cross_entropy", "dense_cross_entropy"]
+__all__ = ["blockwise_cross_entropy", "chunk_rows", "dense_cross_entropy"]
