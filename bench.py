@@ -401,10 +401,8 @@ def run_serving_bench() -> int:
     """Continuous-batching serving benchmark (in-process, one JSON line).
 
     One warm-up pass compiles every program variant; the timed pass then
-    measures pure serving throughput. The admission-burst comparison is
-    the tentpole number: all requests submitted up front, so the first
-    _admit() sees a full burst of free slots — the batched path collapses
-    its sum-of-chunks dispatches into max-chunks rounds."""
+    measures pure serving throughput: all requests submitted up front, so
+    the first _admit() sees a full burst of free slots."""
     import time as _time
 
     sys.path.insert(0, str(REPO))
@@ -438,11 +436,10 @@ def run_serving_bench() -> int:
         for i in range(n_requests)
     ]
 
-    def serve(server_params, *, batched, mesh=None):
+    def serve(server_params, *, mesh=None):
         srv = SlotServer(
             server_params, cfg, slots=slots, max_len=max_len,
-            block_size=16, prefill_chunk=64, batched_admission=batched,
-            mesh=mesh)
+            block_size=16, prefill_chunk=64, mesh=mesh)
         reqs = [Request(prompt=p, max_new_tokens=budgets[i % len(budgets)])
                 for i, p in enumerate(prompts)]
         for r in reqs:
@@ -466,18 +463,15 @@ def run_serving_bench() -> int:
         srv.shutdown()      # bench builds many servers: no thread pile-up
         return out, toks
 
-    serve(params, batched=True)                       # compile warm-up
+    serve(params)                                     # compile warm-up
     # warmup line: compiles past here are RECOMPILES — the timed pass
     # replays warm shapes, so a healthy run reads ~0 post-warm
     compile_telemetry.mark_warm()
-    batched, toks_b = serve(params, batched=True)
-    # snapshot BEFORE the per-slot/TP passes, which legitimately compile
-    # new program shapes (serial admission, sharded programs) and would
-    # drown the timed pass's recompile signal
+    batched, toks_b = serve(params)
+    # snapshot BEFORE the TP pass, which legitimately compiles new
+    # program shapes (sharded programs) and would drown the timed pass's
+    # recompile signal
     compile_snap = compile_telemetry.snapshot()
-    serve(params, batched=False)                      # warm per-slot too
-    perslot, toks_p = serve(params, batched=False)
-    assert toks_b == toks_p, "admission policy changed completions"
 
     # open-loop Poisson arrivals (ROADMAP leftover, ISSUE 16): the same
     # workload offered the way real traffic arrives — seeded
@@ -488,8 +482,7 @@ def run_serving_bench() -> int:
     # open-loop one rather than the burst's deep-backlog artifact.
     def serve_open_loop(offered_tok_s):
         srv = SlotServer(params, cfg, slots=slots, max_len=max_len,
-                         block_size=16, prefill_chunk=64,
-                         batched_admission=True)
+                         block_size=16, prefill_chunk=64)
         mean_new = sum(budgets) / len(budgets)
         interarrival = mean_new / offered_tok_s
         sched = np.cumsum(np.random.default_rng(16).exponential(
@@ -565,8 +558,6 @@ def run_serving_bench() -> int:
         "p99_device_lag_s": device_lag.get("p99_s", 0.0),
         "compile": compile_snap,
     }
-    perslot.pop("latency", None)
-    perslot.pop("device", None)
     out = {
         "metric": "continuous_batching_serving_tokens_per_sec",
         "value": batched["tokens_per_sec"],
@@ -578,12 +569,8 @@ def run_serving_bench() -> int:
         "serving_latency": serving_latency,
         "device_time": device_time,
         "batched_admission": batched,
-        "per_slot_admission": perslot,
         "open_loop": {**open_loop,
                       "byte_identical_vs_burst": toks_ol == toks_b},
-        "admission_dispatch_ratio": round(
-            perslot["admission_dispatches"]
-            / max(1, batched["admission_dispatches"]), 2),
         "num_devices": jax.device_count(),
     }
     if jax.device_count() >= 2:
@@ -595,8 +582,8 @@ def run_serving_bench() -> int:
         mesh = build_mesh(MeshSpec(data=data, fsdp=1, tensor=tensor),
                           devices=jax.devices()[:data * tensor])
         prep = prepare_decode(params, cfg, mesh=mesh)
-        serve(prep, batched=True, mesh=mesh)          # warm-up
-        tp, toks_tp = serve(prep, batched=True, mesh=mesh)
+        serve(prep, mesh=mesh)                        # warm-up
+        tp, toks_tp = serve(prep, mesh=mesh)
         tp.pop("latency", None)
         tp.pop("device", None)
         out["tp"] = {**tp, "mesh": dict(mesh.shape),

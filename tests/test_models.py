@@ -1344,3 +1344,58 @@ def test_generate_sliding_window_matches_teacher_forcing():
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         np.testing.assert_array_equal(np.asarray(out[:, i]), np.asarray(nxt))
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
+
+
+def test_decoder_layer_threads_the_callers_state():
+    """The seam every caller of the block writes against: `decoder_layer`
+    hands ``attend`` q roped [B, L, H, D] and k, v roped and UN-repeated
+    [B, L, kvH, D] with whatever ``kv`` the caller threads, and returns
+    what ``attend`` returns. A toy attend that counts its calls in ``kv``
+    and attends causally inside the block advances the counter once a
+    layer and gives `_layer`'s hidden states bit for bit."""
+    from tony_tpu.parallel.ring_attention import reference_attention
+
+    params = transformer.init(jax.random.PRNGKey(3), TINY)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 12), 0,
+                                TINY.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
+
+    def attend(kv, q, k, v):
+        assert q.shape == (2, 12, TINY.n_heads, TINY.head_dim)
+        assert k.shape == v.shape == (2, 12, TINY.n_kv_heads, TINY.head_dim)
+        k, v = transformer._repeat_kv(TINY, k, v)
+        return reference_attention(q, k, v, causal=True), kv + 1
+
+    x = ref = params["embed"][tokens]
+    calls = 0
+    for i in range(TINY.n_layers):
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        x, aux, calls = transformer.decoder_layer(
+            TINY, x, positions, lp, attend, calls)
+        ref, ref_aux = transformer._layer(TINY, None, ref, positions, lp)
+        assert float(aux) == float(ref_aux) == 0.0
+    assert calls == TINY.n_layers
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(ref))
+
+
+def test_fused_weight_formats_bit_equal_in_float32():
+    """`_qkv` and `_mlp` read the fused ``wqkv`` / ``w_gu`` that
+    `_fuse_decode_weights` makes where ``lp`` carries them; the fusion is a
+    concatenation of the training weights, so in float32 both forms give
+    the same bits."""
+    from tony_tpu.models.generate import _fuse_decode_weights
+
+    params = transformer.init(jax.random.PRNGKey(5), TINY)
+    fused = _fuse_decode_weights(params, TINY)
+    assert set(fused) == {"wqkv", "w_gu"}
+    h = jax.random.normal(jax.random.PRNGKey(6), (2, 5, TINY.d_model))
+    positions = jnp.broadcast_to(3 + jnp.arange(5), (2, 5))
+    for i in range(TINY.n_layers):
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp_fused = {**lp, **jax.tree.map(lambda a: a[i], fused)}
+        for a, b in zip(transformer._qkv(TINY, h, positions, lp),
+                        transformer._qkv(TINY, h, positions, lp_fused)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(transformer._mlp(TINY, h, lp)[0]),
+            np.asarray(transformer._mlp(TINY, h, lp_fused)[0]))

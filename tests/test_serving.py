@@ -101,36 +101,31 @@ def test_slot_server_eos_frees_slot_and_matches_generate(params):
 
 def test_slot_server_int8_kv_and_weights(params):
     """kv_dtype/weight_dtype wire through the slot pool: quantized cache +
-    scale buffers + int8 decode weights serve mixed bursts, with identical
-    completions regardless of admission policy. vs solo generate() the
-    int8 paths agree within QUANTIZATION TOLERANCE, not bit-exactly:
+    scale buffers + int8 decode weights serve mixed bursts. vs solo
+    generate() the int8 paths agree within QUANTIZATION TOLERANCE, not
+    bit-exactly:
     serving chunk-prefills the prompt body through the quantized cache
     (and raw, unfused prefill weights) where generate's true prefill
     attends raw K/V (and the w8-fused weights) — a near-tie at int8
     resolution can flip a greedy token, and does under some jax versions.
     Exactness claims belong to the native-dtype paths (tested above);
-    here we assert policy-invariance plus majority agreement with solo
-    (a plumbing regression produces garbage everywhere, not one flipped
-    near-tie)."""
+    here we assert majority agreement with solo (a plumbing regression
+    produces garbage everywhere, not one flipped near-tie)."""
     prompts = _prompts(4, key=7)
-    outs = {}
-    for batched in (True, False):
-        srv = SlotServer(params, TINY, slots=2, max_len=64, block_size=4,
-                         prefill_chunk=8, kv_dtype="int8",
-                         weight_dtype="int8", batched_admission=batched)
-        reqs = [Request(prompt=p, max_new_tokens=5) for p in prompts]
-        for r in reqs:
-            srv.submit(r)
-        done = srv.run_until_drained()
-        outs[batched] = [done[r.id].tokens for r in reqs]
-    assert outs[True] == outs[False]
+    srv = SlotServer(params, TINY, slots=2, max_len=64, block_size=4,
+                     prefill_chunk=8, kv_dtype="int8", weight_dtype="int8")
+    reqs = [Request(prompt=p, max_new_tokens=5) for p in prompts]
+    for r in reqs:
+        srv.submit(r)
+    done = srv.run_until_drained()
+    outs = [done[r.id].tokens for r in reqs]
     refs = [_solo(params, p, 5, kv_dtype="int8", weight_dtype="int8")
             for p in prompts]
-    for toks in outs[True]:
+    for toks in outs:
         assert len(toks) == 5
         assert all(0 <= t < TINY.vocab_size for t in toks)
-    agree = sum(t == r for t, r in zip(outs[True], refs))
-    assert agree * 2 >= len(refs), (outs[True], refs)
+    agree = sum(t == r for t, r in zip(outs, refs))
+    assert agree * 2 >= len(refs), (outs, refs)
 
 
 def test_slot_server_prepared_weights_and_incremental_api(params):
@@ -319,38 +314,102 @@ def test_slot_server_prefill_tail_past_ring_capacity(params):
     assert done[r.id].tokens == _solo(params, prompt, 4)
 
 
-def test_slot_server_batched_admission_matches_per_slot(params):
-    """Batched multi-slot admission (one _prefill_batch dispatch per chunk
-    ROUND) must emit exactly the per-slot path's tokens — admission policy
-    can never change results — while dispatching strictly fewer prefill
-    programs on a burst (that serial sum-of-chunks dispatch train is the
-    admission stall it exists to remove)."""
+@pytest.mark.parametrize("burst", [1, 3, 9])
+def test_slot_server_admission_bursts_match_solo(params, burst):
+    """One admission program whatever the burst: 9 requests admitted in
+    bursts of 1, 3 or 9 (one `_prefill_batch` dispatch per chunk ROUND, at
+    1, 4 or 16 rows) emit exactly solo generate()'s tokens — the size of
+    a burst can never change results — and a burst costs its LONGEST
+    prompt's chunk rounds in dispatches, not the sum over its prompts."""
     prompts = _prompts(9, key=61, lo=2, hi=22)   # multi-chunk at chunk=8
-    # a 1-token prompt in a burst: its batched row is finalize-only
-    # (n_valid=0, every KV write dropped) — the degenerate case must ride
-    # along exactly
+    # a 1-token prompt in a burst: its row is commit-only (n_valid=0,
+    # every KV write dropped) — the degenerate case must ride along
+    # exactly
     prompts[4] = prompts[4][:1]
-    outs, counts = {}, {}
-    for batched in (True, False):
-        srv = SlotServer(params, TINY, slots=3, max_len=64, block_size=4,
-                         prefill_chunk=8, batched_admission=batched)
-        reqs = [Request(prompt=p, max_new_tokens=5 + (i % 3))
-                for i, p in enumerate(prompts)]
-        for r in reqs:
+    budgets = [5 + (i % 3) for i in range(len(prompts))]
+    srv = SlotServer(params, TINY, slots=burst, max_len=64, block_size=4,
+                     prefill_chunk=8)
+    reqs = [Request(prompt=p, max_new_tokens=b)
+            for p, b in zip(prompts, budgets)]
+    done, rounds = {}, 0
+    for g in range(0, len(reqs), burst):    # every slot is free: one burst
+        for r in reqs[g:g + burst]:
             srv.submit(r)
-        done = srv.run_until_drained()
-        outs[batched] = [done[r.id].tokens for r in reqs]
-        counts[batched] = srv.admission_dispatches
-    assert outs[True] == outs[False]
-    assert counts[True] < counts[False], counts
-    # and the batched path stays exact vs solo generate
-    for toks, p, r in zip(outs[True], prompts,
-                          [5 + (i % 3) for i in range(len(prompts))]):
-        assert toks == _solo(params, p, r)
+        done.update(srv.run_until_drained())
+        rounds += max(max(1, -(-(len(p) - 1) // 8))
+                      for p in prompts[g:g + burst])
+    assert srv.admission_dispatches == rounds
+    for r, p, b in zip(reqs, prompts, budgets):
+        assert done[r.id].tokens == _solo(params, p, b)
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+def test_prefill_batch_rows_equal_successive_one_row_calls(params, kv_dtype):
+    """The guarantee the one-slot admission program used to be compared
+    for: a `_prefill_batch` of K rows leaves every slot's cache rows,
+    lengths and committed decode state BYTE-identical to K successive
+    one-row calls. Two chunk rounds over three slots at bf16 (and an int8
+    cache): a row that commits in round 0, a commit-only row of a 1-token
+    prompt (n_valid 0), a row whose second chunk wraps its ring, and the
+    out-of-bounds padding row of the burst's power-of-two width."""
+    import dataclasses
+
+    from tony_tpu.models.generate import init_cache
+    from tony_tpu.models.serving import _prefill_batch
+
+    cfg = dataclasses.replace(TINY, dtype=jnp.bfloat16)
+    S, M, C = 4, 32, 8
+    rng = np.random.default_rng(5)
+    # (slot, start, ring offset, n_valid, final) per row, per round
+    rounds = [[(2, 0, 5, 8, False), (0, 0, 0, 0, True), (3, 0, 20, 8, False)],
+              [(2, 8, 5, 3, True), (3, 8, 20, 8, True)]]
+    toks = [rng.integers(0, cfg.vocab_size, (len(r), C), dtype=np.int32)
+            for r in rounds]
+
+    def pack(rows, tokens):
+        k = 1 << (len(rows) - 1).bit_length()
+        a = {n: np.zeros(k, np.int32) for n in
+             ("slots", "starts", "offsets", "n_valids", "lasts", "targets",
+              "topks")}
+        a["slots"][:] = S + np.arange(k)            # padding rows: OOB
+        temps, fin = np.zeros(k, np.float32), np.zeros(k, bool)
+        tk = np.zeros((k, C), np.int32)
+        for i, (slot, start, off, nv, final) in enumerate(rows):
+            tk[i, :nv] = tokens[i, :nv]
+            a["slots"][i], a["starts"][i] = slot, start
+            a["offsets"][i], a["n_valids"][i] = off, nv
+            a["lasts"][i], a["targets"][i] = 7 + slot, 40 + slot
+            temps[i], a["topks"][i], fin[i] = 0.5 * slot, slot, final
+        return [jnp.asarray(x) for x in (
+            tk, a["slots"], a["starts"], a["offsets"], a["n_valids"],
+            a["lasts"], a["targets"], temps, a["topks"], fin)]
+
+    def run(groups):
+        st = [init_cache(cfg, S, M, kv_dtype)._replace(
+                  length=jnp.zeros((S,), jnp.int32)),
+              jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool),
+              jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
+              jnp.zeros((S,), jnp.float32), jnp.zeros((S,), jnp.int32)]
+        for rows, tokens in groups:
+            *st, _ = _prefill_batch(params, *st, *pack(rows, tokens),
+                                    cfg=cfg, shardings=None)
+        return jax.tree.leaves(st)
+
+    batched = run(zip(rounds, toks))
+    serial = run(([row], t[i:i + 1]) for r, t in zip(rounds, toks)
+                 for i, row in enumerate(r))
+    assert len(batched) == len(serial) == (11 if kv_dtype == "int8" else 9)
+    for a, b in zip(batched, serial):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    cache_len, d_active, d_target = (np.asarray(batched[i]) for i in (2, -5, -4))
+    assert cache_len.tolist() == [0, 0, 11, 16]
+    assert d_active.tolist() == [True, False, True, True]
+    assert d_target.tolist() == [40, 0, 42, 43]
 
 
 @pytest.mark.slow
-def test_slot_server_batched_admission_with_eos(params):
+def test_slot_server_admission_bursts_with_eos(params):
     """Mid-flight re-admission bursts (slots freed by EOS at different
     times) go through the batched program too; completions still match
     generate(stop_tokens=...)."""
@@ -411,16 +470,15 @@ def test_slot_server_tp_mesh_parity(params):
 
 
 @pytest.mark.slow
-def test_slot_server_tp_mesh_eos_and_per_slot(params):
-    """EOS mode and the serial per-slot admission path both compose with
-    the mesh (the sync/burst bookkeeping is sharding-agnostic)."""
+def test_slot_server_tp_mesh_eos(params):
+    """EOS mode composes with the mesh (the sync/burst bookkeeping is
+    sharding-agnostic)."""
     mesh = _tp_mesh()
     prompts = _prompts(6, key=73)
     stop = _solo(params, prompts[0], 8)[2]
     prep = prepare_decode(params, TINY, mesh=mesh)
     srv = SlotServer(prep, TINY, slots=2, max_len=64, block_size=4,
-                     prefill_chunk=8, stop_tokens=(stop,), pad_id=255,
-                     batched_admission=False)
+                     prefill_chunk=8, stop_tokens=(stop,), pad_id=255)
     reqs = [Request(prompt=p, max_new_tokens=8) for p in prompts]
     for r in reqs:
         srv.submit(r)

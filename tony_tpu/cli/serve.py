@@ -94,10 +94,6 @@ def build_argparser() -> argparse.ArgumentParser:
                         "pairs (e.g. 'tensor=4' or 'data=2,tensor=2'); "
                         "axes from parallel.mesh.AXIS_ORDER. Empty = "
                         "single device")
-    p.add_argument("--per-slot-admission", action="store_true",
-                   help="disable batched multi-slot admission (debugging/"
-                        "comparison; one prefill dispatch per chunk per "
-                        "slot instead of per chunk round)")
     p.add_argument("--prefix-cache-blocks", type=int, default=0,
                    help="enable the chunk-aligned prefix KV cache with "
                         "this many shared prefill-chunk-sized blocks "
@@ -2294,7 +2290,6 @@ def main(argv=None) -> int:
             temperature=args.temperature, top_k=args.top_k,
             stop_tokens=tuple(int(t) for t in args.stop_tokens.split()),
             pad_id=args.pad_id, seed=args.seed,
-            batched_admission=not args.per_slot_admission,
             prefix_cache_blocks=args.prefix_cache_blocks,
             cache_prompts=not args.no_cache_prompts,
             max_queue=args.max_queue,

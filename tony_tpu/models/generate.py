@@ -170,6 +170,26 @@ def _quantize_kv(x):
     return q, scale[..., 0].astype(jnp.bfloat16)
 
 
+def _store_kv(kv, k, v, put):
+    """One layer's new K/V [B, L, kvH, D] into the cache buffers ``kv`` =
+    (k, v, k_scale, v_scale): head-major, in the cache's dtype, quantised
+    where that is int8 (the scales then go to their own buffers), each
+    stored by the caller's ``put(buf, new)`` with new [B, kvH, L(, D)] — a
+    shared-offset dynamic_update_slice (`_forward_with_cache`) or a
+    per-row ring scatter (`serving._rows_forward`)."""
+    ck, cv, ks_buf, vs_buf = kv
+    k_hm = k.transpose(0, 2, 1, 3)
+    v_hm = v.transpose(0, 2, 1, 3)
+    if ck.dtype == jnp.int8:
+        k_w, ks = _quantize_kv(k_hm)
+        v_w, vs = _quantize_kv(v_hm)
+        ks_buf = put(ks_buf, ks)
+        vs_buf = put(vs_buf, vs)
+    else:
+        k_w, v_w = k_hm.astype(ck.dtype), v_hm.astype(cv.dtype)
+    return put(ck, k_w), put(cv, v_w), ks_buf, vs_buf
+
+
 def decode_kernel_engages(cfg, m_cap: int) -> bool:
     """Whether a single-token decode step over an ``m_cap``-position cache
     may run the Pallas kernel (ops/decode_attention.py) instead of the
@@ -466,107 +486,49 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         # resharding mid-layer
         x = lax.with_sharding_constraint(x, shardings.act)
 
-    hd = cfg.head_dim
-    nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
     p_cfg = _prefill_cfg(cfg) if prefill else None
     w8 = fused is not None and "wqkv_s" in fused  # int8 decode weights
-    ck, cv = cache.k, cache.v
-    ks_buf, vs_buf = cache.k_scale, cache.v_scale
-    int8_cache = ck.dtype == jnp.int8
+    int8_cache = cache.k.dtype == jnp.int8
     zero = jnp.int32(0)
+    # the per-layer entries of the fused / int8 forms; the rest (the int8
+    # unembedding) is read after the loop
+    fused_layers = {k: w for k, w in (fused or {}).items()
+                    if not k.startswith("unembed")}
 
-    def write_kv(buf, new, layer):
-        """Write this layer's new K/V (or int8-scale) block into the cache:
-        buf [Ly, B, kvH, M(, D)], new [B, kvH, L(, D)] — one shared scalar
-        offset for every row: cache.length on the lockstep path, the ring
-        cursor on the per-row path (that is the point of the ring layout;
-        see the function docstring)."""
+    def attend(layer, kv, q, k, v):
+        """Store this layer's K/V at ONE shared scalar offset for every row
+        (cache.length on the lockstep path, the ring cursor on the per-row
+        path: that is the point of the ring layout, see the docstring),
+        then read the whole stack — or, on the empty cache of a prefill,
+        the block itself."""
         offset = cache.length if ring_cursor is None else ring_cursor
-        idx = (jnp.int32(layer), zero, zero, offset)
-        if new.ndim == 4:
-            idx += (zero,)
-        return lax.dynamic_update_slice(buf, new[None], idx)
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        if fused is not None:
-            qkv = jnp.einsum("bld,de->ble", h, fused["wqkv"][i].astype(dt))
-            if w8:
-                qkv = qkv * fused["wqkv_s"][i]
-            q = qkv[..., :nq].reshape(b, l, cfg.n_heads, hd)
-            k = qkv[..., nq:nq + nkv].reshape(b, l, cfg.n_kv_heads, hd)
-            v = qkv[..., nq + nkv:].reshape(b, l, cfg.n_kv_heads, hd)
-            q = transformer.rope(q, positions, cfg.rope_theta,
-                                 cfg.rope_scaling)
-            k = transformer.rope(k, positions, cfg.rope_theta,
-                                 cfg.rope_scaling)
-        else:
-            q, k, v = transformer._qkv(cfg, h, positions, lp)
-        k_hm = k.transpose(0, 2, 1, 3)  # [B, kvH, L, D] head-major
-        v_hm = v.transpose(0, 2, 1, 3)
-        if int8_cache:
-            k_w, ks = _quantize_kv(k_hm)
-            v_w, vs = _quantize_kv(v_hm)
-            ks_buf = write_kv(ks_buf, ks, i)
-            vs_buf = write_kv(vs_buf, vs, i)
-        else:
-            k_w, v_w = k_hm.astype(dt), v_hm.astype(dt)
-        ck = write_kv(ck, k_w, i)
-        cv = write_kv(cv, v_w, i)
+
+        def put(buf, new):  # buf [Ly, B, kvH, M(, D)], new [B, kvH, L(, D)]
+            idx = (jnp.int32(layer), zero, zero, offset)
+            return lax.dynamic_update_slice(
+                buf, new[None], idx + (zero,) * (new.ndim - 3))
+
+        kv = _store_kv(kv, k, v, put)
         if prefill:
             kr, vr = transformer._repeat_kv(cfg, k, v)
-            attn = transformer._attention(q, kr, vr, p_cfg, None)
-        else:
-            attn = _cached_attention(
-                cfg, q, ck, cv, cache.length, l,
-                ks_buf if int8_cache else None,
-                vs_buf if int8_cache else None,
-                ring_offsets=ring_offsets,
-                # a pallas call inside the GSPMD-sharded decode would need
-                # a shard_map wrapper; the sharded path keeps the einsum
-                allow_kernel=shardings is None,
-                layer_idx=i, active=ring_active,
-            )
-        if w8:
-            proj = jnp.einsum(
-                "ble,ed->bld", attn.reshape(b, l, nq),
-                fused["wo"][i].astype(dt),
-            ) * fused["wo_s"][i]
-        else:
-            proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
-        x = x + proj
-        hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if fused is not None and "w_gu" in fused:
-            gu = jnp.einsum("bld,de->ble", hh, fused["w_gu"][i].astype(dt))
-            if w8:
-                gu = gu * fused["w_gu_s"][i]
-            gate, up = gu[..., :cfg.d_ff], gu[..., cfg.d_ff:]
-            down = (fused["w_down"][i] if w8 else lp["w_down"]).astype(dt)
-            mlp_out = jnp.einsum(
-                "blf,fd->bld", jax.nn.silu(gate) * up, down
-            )
-            if w8:
-                mlp_out = mlp_out * fused["w_down_s"][i]
-        elif fused is not None and "w_in" in fused:
-            # w8 routed experts: int8 expert weights streamed, per-expert
-            # per-output-channel scales folded out of the matmuls
-            # (moe_ffn applies them post-matmul, broadcast over capacity).
-            # Same router/capacity/activation as transformer._mlp so
-            # routing decisions match the native path exactly.
-            from ..parallel.expert import moe_ffn
+            return transformer._attention(q, kr, vr, p_cfg, None), kv
+        ck, cv, ks_buf, vs_buf = kv
+        attn = _cached_attention(
+            cfg, q, ck, cv, cache.length, l, ks_buf, vs_buf,
+            ring_offsets=ring_offsets,
+            # a pallas call inside the GSPMD-sharded decode would need
+            # a shard_map wrapper; the sharded path keeps the einsum
+            allow_kernel=shardings is None,
+            layer_idx=layer, active=ring_active,
+        )
+        return attn, kv
 
-            flat = hh.reshape(b * l, cfg.d_model)
-            mlp_out = moe_ffn(
-                flat, lp["router"].astype(dt),
-                fused["w_in"][i], fused["w_out"][i],
-                k=cfg.expert_top_k, capacity_factor=cfg.capacity_factor,
-                activation=jax.nn.silu,
-                w_in_scale=fused["w_in_s"][i],
-                w_out_scale=fused["w_out_s"][i],
-            ).reshape(b, l, cfg.d_model)
-        else:
-            mlp_out, _ = transformer._mlp(cfg, hh, lp)
-        x = x + mlp_out
+    kv = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+    for i in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[i], {**params["layers"], **fused_layers})
+        x, _, kv = transformer.decoder_layer(
+            cfg, x, positions, lp, functools.partial(attend, i), kv)
+    ck, cv, ks_buf, vs_buf = kv
 
     # all_logits=True projects EVERY position ([B, L, V]) — the speculative
     # verify forward needs the target's prediction after each drafted

@@ -34,8 +34,8 @@ Design — everything stays one compiled program over static shapes:
   blocks that hold a position the slot's query may see — nothing for an
   inactive slot. Per-row EOS/budget masks freeze finished rows' lengths
   in-device so a row that stops mid-block stays exactly where it stopped.
-- **Chunked prefill into one slot, one dispatch per chunk.** A new
-  request's prompt (all but its last token) is fed through the
+- **Chunked prefill into a slot's ring, one dispatch per chunk round.**
+  A new request's prompt (all but its last token) is fed through the
   cached-attention path in fixed-size chunks that scatter K/V at the
   slot's ring indices — other slots are untouched, nothing recompiles
   for a new prompt length, and the padded tail's writes are DROPPED
@@ -64,19 +64,18 @@ Design — everything stays one compiled program over static shapes:
   single-device server (tested at f32; at bf16 the TP psum's different
   reduction order can flip a greedy near-tie, exactly as on generate's
   TP path).
-- **Batched multi-slot admission.** `_admit` collects the whole burst of
-  admissible (slot, request) pairs — all ring offsets derive from the
-  same cursor, so batching changes no layout decision — and dispatches
-  ONE `_prefill_batch` program per chunk round (rows padded to a power
-  of two; finished/padding rows write nowhere via out-of-bounds indices
-  + mode="drop"). A burst of K arrivals costs max-chunks dispatches
-  instead of sum-of-chunks: the serial dispatch train that used to
-  stall the next decode block behind every burst collapses ~K-fold
-  (measured 42 -> 20 on the bench workload's mixed-length bursts).
-  The trade is garbage FLOPs for the padded rows — a win whenever host
-  dispatch cost is material (a real chip), a wash-to-loss on a
-  compute-bound CPU backend; ``batched_admission=False`` keeps the
-  serial path. Output is exactly the per-slot path's (tested).
+- **One admission program, whatever the burst.** `_admit` collects the
+  whole burst of admissible (slot, request) pairs — all ring offsets
+  derive from the same cursor, so batching changes no layout decision —
+  and dispatches ONE `_prefill_batch` program per chunk round (rows
+  padded to a power of two; finished/padding rows write nowhere via
+  out-of-bounds indices + mode="drop"); a burst of one is the same
+  program at one row (on the chip within 0.5% of the one-slot program
+  it replaced: PERF.md, PR 31). A burst of K arrivals costs max-chunks
+  dispatches instead of sum-of-chunks, so no serial dispatch train
+  stalls the next decode block behind a burst. The trade is garbage
+  FLOPs for the padded rows. K rows leave exactly the state K
+  successive one-row dispatches would (tested).
 - **Chunk-aligned prefix cache: shared prompts prefill once.** Real
   traffic is dominated by shared prefixes (system prompts, few-shot
   templates, multi-turn histories); ``prefix_cache_blocks=N`` keeps a
@@ -255,8 +254,8 @@ from .generate import (
     _decode_shardings,
     _forward_with_cache,
     _fuse_decode_weights,
-    _quantize_kv,
     _rule_size,
+    _store_kv,
     _validate_decode_mesh,
     decode_kernel_engages,
     init_cache,
@@ -396,6 +395,12 @@ class _Admission:
     last: int = 0               # the first fed token: full context's last
     prefix_len: int = 0
     hit_path: list = field(default_factory=list)
+
+
+def _pow2_rows(n: int) -> int:
+    """Rows of a batched program, padded to the next power of two so the
+    compiled widths stay O(log slots)."""
+    return 1 << (n - 1).bit_length()
 
 
 def _constrain_pool(shardings, cache, *vecs):
@@ -723,202 +728,117 @@ def _insert_prefix_blocks(pool, cache, slots, blocks, chunk_idx, offsets,
     return pool, fence
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "chunk", "kv_dtype", "finalize", "shardings"),
-    donate_argnames=("cache", "d_tokens", "d_active", "d_target",
-                     "d_offsets", "d_temps", "d_topks"),
-)
-def _prefill_chunk(params, cache, d_tokens, d_active, d_target, d_offsets,
-                   d_temps, d_topks, tokens, slot, start, offset, n_valid,
-                   last_token, target, temp, topk,
-                   *, cfg: TransformerConfig, chunk: int, kv_dtype: str,
-                   finalize: bool, shardings: DecodeShardings | None = None):
-    """Feed ``chunk`` prompt tokens ([1, C], padded past n_valid) into slot
-    ``slot``'s cache rows at logical positions start..start+C-1; returns
-    the cache with that slot's length = start + n_valid (others
-    untouched). The slot's buffer is a RING: logical position p lives at
-    index (p + offset) mod M, where ``offset`` was chosen at admission to
-    align the slot's decode writes with the global cursor (see SlotServer)
-    — so this chunk scatters at ring indices (admission-only cost; the
-    per-step decode write stays a cheap shared dynamic_update_slice).
-    Single-row layer loop: attention reads only this slot's [kvH, M, D]
-    rows, K/V writes land only in this slot — admission never disturbs
-    decoding slots. Padded-tail K/V land at logical positions >= the
-    final length, where the attention mask never looks and the slot's own
-    future writes overwrite them. No fused/quantized weights: prefill is
-    MXU-bound, the fusions are decode (weight-streaming) optimizations.
+def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
+    """The one multi-token, per-row-position forward of the serving
+    programs: ``tokens`` [K, L] run through the stack at logical positions
+    ``starts[r]..starts[r]+L-1``, row r against slot ``rows[r]``'s ring —
+    the forward the shared-cursor decode step deliberately avoids
+    (per-row-offset writes lower to scatters), paid once per admission
+    chunk round (`_prefill_batch`) or speculative round (`_spec_block`).
 
-    ``finalize`` (the prompt's last chunk — including the degenerate
-    zero-valid chunk of a 1-token prompt) also commits the slot's decode
-    state in the same dispatch: fed token, active, budget target, ring
-    offset. An admission is then exactly one dispatch per chunk — the
-    four separate .at[].set pokes measured ~8ms of host dispatch work per
-    admission, a third of the whole serving loop's host cost."""
+    ``bufs`` = the cache's (k, v, k_scale, v_scale) buffers [layers, S,
+    kvH, M(, D)]. A slot's buffer is a RING: logical position p lives at
+    index (p + offsets[r]) mod M, and each layer's K/V scatter there where
+    ``write_ok`` [K, L] holds. Every other position — a chunk's pad tail, a
+    row with nothing to write, a window overhanging the row's budget — gets
+    a distinct OUT-OF-BOUNDS index and mode="drop": written nowhere at all.
+    (Wrapping them with the mod would land them on the slot's own EARLIEST
+    positions, which the mask legitimately reads.) So does every write of a
+    row whose ``rows[r]`` is out of bounds (padding rows); such a row reads
+    through the clamped gather and computes garbage that touches nothing,
+    exactly like an inactive decode row. ``rows=None``: row r IS slot r,
+    all S of them, and the attention reads the buffers as they stand, no
+    gather. Attention reads only the row's own slot (the per-row-vector
+    cache_len + ring_offsets branch of `_cached_attention`), so rows never
+    disturb one another or a decoding slot.
+
+    No fused/quantized weights: prefill is MXU-bound (the fusions are
+    decode, weight-streaming, optimizations) and the speculative verify
+    must keep the raw-weight numerics. Returns (hidden states [K, L, d]
+    before the final norm, bufs)."""
     dt = cfg.dtype
-    params = _cast_decode_params(params, cfg)
-    l = tokens.shape[1]
-    m_cap = cache.k.shape[3]
-    positions = jnp.broadcast_to(start + jnp.arange(l), (1, l))
-    # pad-tail positions (j >= n_valid, final chunk only) get distinct
-    # OUT-OF-BOUNDS indices and mode="drop": written nowhere at all. The
-    # naive (offset+pos) % m_cap would wrap a tail that runs past the ring
-    # capacity back onto the slot's own EARLIEST prompt K/V — positions
-    # the mask legitimately reads — silently corrupting generation
-    # whenever the last chunk's span crosses max_len.
-    j = jnp.arange(l)
-    ring_idx = jnp.where(j < n_valid, (offset + start + j) % m_cap,
-                         m_cap + j)
-    off_vec = offset[None] if jnp.ndim(offset) == 0 else offset
+    k_rows, l = tokens.shape
+    n_slots, m_cap = bufs[0].shape[1], bufs[0].shape[3]
+    positions = starts[:, None] + jnp.arange(l)[None, :]        # [K, L]
+    ring_idx = jnp.where(write_ok, (offsets[:, None] + positions) % m_cap,
+                         m_cap + jnp.arange(l)[None, :])
+    if rows is None:
+        rows, read_rows = jnp.arange(k_rows), None
+    else:
+        read_rows = jnp.minimum(rows, n_slots - 1)  # clamp padding rows
+
+    def attend(layer, bufs, q, k, v):
+        def put(buf, new):
+            # advanced indices [K,1] x [K,L] around the kvH slice put the
+            # broadcast dims first: the updates arrive [K, L, kvH(, D)]
+            return buf.at[layer, rows[:, None], :, ring_idx].set(
+                jnp.moveaxis(new, 1, 2), unique_indices=True, mode="drop")
+
+        def rows_of(buf):       # what the rows attend over: [K, kvH, M(, D)]
+            if buf is None:
+                return None
+            return buf[layer] if read_rows is None else buf[layer][read_rows]
+
+        bufs = _store_kv(bufs, k, v, put)
+        ck, cv, ks, vs = map(rows_of, bufs)
+        # the einsum, also for a draft's single-token steps: this program
+        # hands the attention a slice of the cache, which as a Pallas
+        # operand would be a copy
+        attn = _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
+                                 ring_offsets=offsets, allow_kernel=False)
+        return attn, bufs
+
     x = params["embed"].astype(dt)[tokens]
-    ck, cv = cache.k, cache.v
-    ks_buf, vs_buf = cache.k_scale, cache.v_scale
-    int8_cache = kv_dtype == "int8"
-    zero = jnp.int32(0)
-    swr = dict(unique_indices=True, mode="drop")   # drops the pad tail
     for i in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = transformer._qkv(cfg, h, positions, lp)
-        k_hm = k.transpose(0, 2, 1, 3)          # [1, kvH, C, D]
-        v_hm = v.transpose(0, 2, 1, 3)
-        if int8_cache:
-            k_w, ks = _quantize_kv(k_hm)
-            v_w, vs = _quantize_kv(v_hm)
-            ks_buf = ks_buf.at[i, slot, :, ring_idx].set(
-                ks[0].transpose(1, 0), **swr)
-            vs_buf = vs_buf.at[i, slot, :, ring_idx].set(
-                vs[0].transpose(1, 0), **swr)
-        else:
-            k_w, v_w = k_hm.astype(dt), v_hm.astype(dt)
-        ck = ck.at[i, slot, :, ring_idx, :].set(
-            k_w[0].transpose(1, 0, 2), **swr)
-        cv = cv.at[i, slot, :, ring_idx, :].set(
-            v_w[0].transpose(1, 0, 2), **swr)
-        row_k = lax.dynamic_slice(
-            ck[i], (slot, zero, zero, zero), (1,) + ck.shape[2:])
-        row_v = lax.dynamic_slice(
-            cv[i], (slot, zero, zero, zero), (1,) + cv.shape[2:])
-        if int8_cache:
-            row_ks = lax.dynamic_slice(
-                ks_buf[i], (slot, zero, zero), (1,) + ks_buf.shape[2:])
-            row_vs = lax.dynamic_slice(
-                vs_buf[i], (slot, zero, zero), (1,) + vs_buf.shape[2:])
-        else:
-            row_ks = row_vs = None
-        attn = _cached_attention(cfg, q, row_k, row_v, start, l,
-                                 row_ks, row_vs, ring_offsets=off_vec,
-                                 allow_kernel=False)
-        proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
-        x = x + proj
-        hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        mlp_out, _ = transformer._mlp(cfg, hh, lp)
-        x = x + mlp_out
-    new_len = lax.dynamic_update_slice(
-        cache.length, (start + n_valid)[None].astype(jnp.int32), (slot,))
-    cache = KVCache(k=ck, v=cv, length=new_len,
-                    k_scale=ks_buf, v_scale=vs_buf)
-    if finalize:
-        d_tokens = d_tokens.at[slot].set(last_token)
-        d_active = d_active.at[slot].set(True)
-        d_target = d_target.at[slot].set(target)
-        d_offsets = d_offsets.at[slot].set(offset)
-        d_temps = d_temps.at[slot].set(temp)
-        d_topks = d_topks.at[slot].set(topk)
-    # dispatch-tracker fence (see _copy_prefix_blocks): every state
-    # output is donated into the next prefill/decode dispatch
-    fence = jnp.sum(new_len).astype(jnp.int32)
-    return (*_constrain_pool(shardings, cache, d_tokens, d_active, d_target,
-                             d_offsets, d_temps, d_topks), fence)
+        x, _, bufs = transformer.decoder_layer(
+            cfg, x, positions, lp, functools.partial(attend, i), bufs)
+    return x, bufs
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "chunk", "kv_dtype", "shardings"),
+    static_argnames=("cfg", "shardings"),
     donate_argnames=("cache", "d_tokens", "d_active", "d_target",
                      "d_offsets", "d_temps", "d_topks"),
 )
 def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
                    d_temps, d_topks, tokens, slots, starts, offsets, n_valids,
                    last_tokens, targets, temps, topks, fin,
-                   *, cfg: TransformerConfig, chunk: int, kv_dtype: str,
+                   *, cfg: TransformerConfig,
                    shardings: DecodeShardings | None = None):
-    """Batched multi-slot admission: ONE dispatch feeds chunk tokens
-    [K, C] into K slots' cache rows at once — the K-row analogue of
-    `_prefill_chunk` (same ring indexing, same pad-tail drop, same
-    finalize semantics, per ROW). An admission burst of K requests with
-    up to R chunks each is then R dispatches instead of the per-slot
-    path's sum-of-chunks (K x R worst case): the serial host dispatches
-    that used to stall the next decode block behind every arrival burst
-    collapse into one program per chunk ROUND.
+    """The admission program: ONE dispatch feeds chunk tokens [K, C]
+    (padded past ``n_valids``) into K slots' cache rows at once through
+    `_rows_forward`; a burst of one is K = 1. An admission burst of K
+    requests with up to R chunks each is R dispatches, one program per
+    chunk ROUND, not a serial train of K x R that stalls the next decode
+    block behind every arrival burst.
 
-    Row r writes slot ``slots[r]`` at logical positions ``starts[r]..``;
-    attention reads only that slot's gathered [kvH, M, D] rows (the
-    per-row-vector cache_len + ring_offsets branch of _cached_attention).
-    Rows whose request has no chunk this round (shorter prompts in the
-    burst, or power-of-two padding — K is padded so compiled variants
-    stay O(log slots)) carry n_valid=0 and an OUT-OF-BOUNDS slot id:
-    every one of their writes — KV scatter, length, decode-state commit —
-    falls off the end and is dropped (mode="drop"), so a padding row
-    computes garbage that touches nothing, exactly like an inactive
-    decode row. ``fin`` [K] bool marks each request's LAST chunk: only
-    those rows commit fed token/active/budget/offset/temp, via scatter
-    indices diverted out of bounds for non-final rows (the indices stay
-    pairwise distinct, so the scatters keep unique_indices)."""
-    dt = cfg.dtype
+    Row r writes slot ``slots[r]`` at logical positions ``starts[r]..``
+    and leaves that slot's length at ``starts[r] + n_valids[r]`` (others
+    untouched); the padded tail is dropped. Rows whose request has no
+    chunk this round (shorter prompts in the burst, or power-of-two
+    padding — K is padded so compiled variants stay O(log slots)) carry
+    n_valid=0 and an OUT-OF-BOUNDS slot id: every one of their writes —
+    KV scatter, length, decode-state commit — falls off the end and is
+    dropped (mode="drop"). ``fin`` [K] bool marks each request's LAST chunk
+    (including the degenerate zero-valid chunk of a 1-token prompt): only
+    those rows commit the slot's decode state — fed token, active, budget
+    target, ring offset, temperature, top-k — in the same dispatch, via
+    scatter indices diverted out of bounds for non-final rows (the indices
+    stay pairwise distinct, so the scatters keep unique_indices). An
+    admission is then exactly one dispatch per chunk round: separate
+    .at[].set pokes measured ~8ms of host dispatch work per admission, a
+    third of the whole serving loop's host cost."""
     params = _cast_decode_params(params, cfg)
     k_rows, l = tokens.shape
-    m_cap = cache.k.shape[3]
     n_slots = cache.k.shape[1]
-    positions = starts[:, None] + jnp.arange(l)[None, :]        # [K, C]
-    j = jnp.arange(l)[None, :]
-    # per-row ring indices; pad tails (j >= n_valid) go out of bounds and
-    # drop — same wrap-corruption guard as the single-slot program
-    ring_idx = jnp.where(j < n_valids[:, None],
-                         (offsets[:, None] + positions) % m_cap,
-                         m_cap + j)
-    gather_rows = jnp.minimum(slots, n_slots - 1)   # clamp padding rows
-    x = params["embed"].astype(dt)[tokens]
-    ck, cv = cache.k, cache.v
-    ks_buf, vs_buf = cache.k_scale, cache.v_scale
-    int8_cache = kv_dtype == "int8"
+    write_ok = jnp.arange(l)[None, :] < n_valids[:, None]
+    _, (ck, cv, ks_buf, vs_buf) = _rows_forward(
+        params, cfg, tokens,
+        (cache.k, cache.v, cache.k_scale, cache.v_scale),
+        slots, starts, offsets, write_ok)
     swr = dict(unique_indices=True, mode="drop")
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = transformer._qkv(cfg, h, positions, lp)
-        k_hm = k.transpose(0, 2, 1, 3)              # [K, kvH, C, D]
-        v_hm = v.transpose(0, 2, 1, 3)
-        if int8_cache:
-            k_w, ks = _quantize_kv(k_hm)
-            v_w, vs = _quantize_kv(v_hm)
-            # advanced indices [K,1]x[K,C] around the kvH slice put the
-            # broadcast dims first: the updates arrive [K, C, kvH]
-            ks_buf = ks_buf.at[i, slots[:, None], :, ring_idx].set(
-                ks.transpose(0, 2, 1), **swr)
-            vs_buf = vs_buf.at[i, slots[:, None], :, ring_idx].set(
-                vs.transpose(0, 2, 1), **swr)
-        else:
-            k_w, v_w = k_hm.astype(dt), v_hm.astype(dt)
-        ck = ck.at[i, slots[:, None], :, ring_idx, :].set(
-            k_w.transpose(0, 2, 1, 3), **swr)
-        cv = cv.at[i, slots[:, None], :, ring_idx, :].set(
-            v_w.transpose(0, 2, 1, 3), **swr)
-        row_k = ck[i][gather_rows]                  # [K, kvH, M, D]
-        row_v = cv[i][gather_rows]
-        if int8_cache:
-            row_ks = ks_buf[i][gather_rows]
-            row_vs = vs_buf[i][gather_rows]
-        else:
-            row_ks = row_vs = None
-        attn = _cached_attention(cfg, q, row_k, row_v, starts, l,
-                                 row_ks, row_vs, ring_offsets=offsets,
-                                 allow_kernel=False)
-        proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
-        x = x + proj
-        hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        mlp_out, _ = transformer._mlp(cfg, hh, lp)
-        x = x + mlp_out
     new_len = cache.length.at[slots].set(
         (starts + n_valids).astype(jnp.int32), **swr)
     cache = KVCache(k=ck, v=cv, length=new_len,
@@ -1062,83 +982,6 @@ def _cancel_slot(active, slot, *, shardings: DecodeShardings | None = None):
     return active
 
 
-def _spec_rows_forward(params, cfg, tokens, ck, cv, ks_buf, vs_buf,
-                       lens, offsets, active, cap):
-    """Forward L new tokens PER ROW (rows = slots) at per-row logical
-    positions ``lens[r]..lens[r]+L-1``, scattering each row's K/V into
-    its own ring — the building block of the speculative propose/verify
-    round. This is the multi-token per-row-position forward the shared-
-    cursor decode path deliberately avoids (per-row-offset writes lower
-    to scatters): speculation amortizes the scatter over up to gamma+1
-    tokens per dispatch, the same trade `_prefill_batch` already makes
-    per admission burst, and pays it back by streaming the target
-    weights once per ROUND instead of once per token.
-
-    Writes land only for ``active`` rows at positions ``< cap[r]`` —
-    everything else diverts out of bounds and drops. The cap matters for
-    ring safety: without the shared cursor, a row's ring holds logical
-    position p at index (offset+p) mod M, and a verify window overhanging
-    ``max_len`` would wrap onto the row's own earliest prompt KV. No
-    delivered emission ever needs KV at positions >= target (the row
-    freezes at target), so dropping those writes is exact, not lossy.
-
-    Returns (all-position logits [S, L, V] f32, ck, cv, k_scales,
-    v_scales). No fused/quantized weights — like the prefill programs,
-    exactness vs the plain decode path requires the raw-weight numerics
-    (the qkv/gate-up fusion is value-identical, but w8a16 is not, which
-    is why speculative serving rejects weight_dtype="int8")."""
-    dt = cfg.dtype
-    s, l = tokens.shape
-    m_cap = ck.shape[3]
-    positions = lens[:, None] + jnp.arange(l)[None, :]          # [S, L]
-    ok = active[:, None] & (positions < cap[:, None])
-    ring_idx = jnp.where(ok, (offsets[:, None] + positions) % m_cap,
-                         m_cap + jnp.arange(l)[None, :])
-    rows = jnp.arange(s)
-    int8_cache = ck.dtype == jnp.int8
-    swr = dict(unique_indices=True, mode="drop")
-    x = params["embed"].astype(dt)[tokens]
-    for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = transformer._qkv(cfg, h, positions, lp)
-        k_hm = k.transpose(0, 2, 1, 3)                  # [S, kvH, L, D]
-        v_hm = v.transpose(0, 2, 1, 3)
-        if int8_cache:
-            k_w, ks = _quantize_kv(k_hm)
-            v_w, vs = _quantize_kv(v_hm)
-            ks_buf = ks_buf.at[i, rows[:, None], :, ring_idx].set(
-                ks.transpose(0, 2, 1), **swr)
-            vs_buf = vs_buf.at[i, rows[:, None], :, ring_idx].set(
-                vs.transpose(0, 2, 1), **swr)
-        else:
-            k_w, v_w = k_hm.astype(dt), v_hm.astype(dt)
-        ck = ck.at[i, rows[:, None], :, ring_idx, :].set(
-            k_w.transpose(0, 2, 1, 3), **swr)
-        cv = cv.at[i, rows[:, None], :, ring_idx, :].set(
-            v_w.transpose(0, 2, 1, 3), **swr)
-        attn = _cached_attention(
-            cfg, q, ck[i], cv[i], lens, l,
-            ks_buf[i] if int8_cache else None,
-            vs_buf[i] if int8_cache else None,
-            # the einsum, also for the draft's single-token steps: this
-            # program hands the kernel a slice of the cache, which as a
-            # Pallas operand is a copy (as in the two prefill programs)
-            ring_offsets=offsets, allow_kernel=False)
-        proj = jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
-        x = x + proj
-        hh = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        mlp_out, _ = transformer._mlp(cfg, hh, lp)
-        x = x + mlp_out
-    # every position's logits (the verify forward needs the target's
-    # prediction after each drafted token); L is the small draft window
-    x_out = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum(
-        "bld,dv->blv", x_out, params["unembed"].astype(dt)
-    ).astype(jnp.float32)
-    return logits, ck, cv, ks_buf, vs_buf
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "draft_cfg", "gamma", "stop_tokens", "pad_id"),
@@ -1184,29 +1027,50 @@ def _spec_block(params, draft_params, cache, draft_cache, d_tokens,
     len0 = cache.length                                  # [S]
     active = d_active
     tok = d_tokens
-    cap = d_target      # ring-wrap write guard; see _spec_rows_forward
+
+    def rows_logits(p, p_cfg, toks, bufs, lens):
+        """``toks`` [S, L] at each row's ``lens[r]..`` through
+        `_rows_forward` (every slot in order) -> every position's logits
+        [S, L, V] f32 (the verify forward needs the target's prediction
+        after each drafted token; L is the small draft window). Speculation
+        amortizes the per-row scatter over up to gamma+1 tokens a dispatch
+        and pays it back by streaming the target weights once per ROUND.
+
+        Writes land only for active rows at positions < the row's target:
+        without the shared cursor a row's ring holds logical position p at
+        index (offset+p) mod M, and a verify window overhanging ``max_len``
+        would wrap onto the row's own earliest prompt KV. No delivered
+        emission ever needs KV at positions >= target (the row freezes
+        there), so dropping those writes is exact, not lossy."""
+        positions = lens[:, None] + jnp.arange(toks.shape[1])[None, :]
+        write_ok = active[:, None] & (positions < d_target[:, None])
+        x, bufs = _rows_forward(p, p_cfg, toks, bufs, None, lens,
+                                d_offsets, write_ok)
+        x = rms_norm(x, p["final_norm"], p_cfg.norm_eps)
+        logits = jnp.einsum("bld,dv->blv", x,
+                            p["unembed"].astype(p_cfg.dtype))
+        return logits.astype(jnp.float32), bufs
 
     def draft_step(carry, _):
-        t, dk, dv, dks, dvs, dlen = carry
-        lg, dk, dv, dks, dvs = _spec_rows_forward(
-            draft_params, draft_cfg, t[:, None], dk, dv, dks, dvs,
-            dlen, d_offsets, active, cap)
+        t, dbufs, dlen = carry
+        lg, dbufs = rows_logits(draft_params, draft_cfg, t[:, None],
+                                dbufs, dlen)
         nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
-        return (nxt, dk, dv, dks, dvs, dlen + 1), t
+        return (nxt, dbufs, dlen + 1), t
 
-    (_, dk, dv, dks, dvs, _), drafted_in = lax.scan(
+    (_, (dk, dv, dks, dvs), _), drafted_in = lax.scan(
         draft_step,
-        (tok, draft_cache.k, draft_cache.v, draft_cache.k_scale,
-         draft_cache.v_scale, draft_cache.length),
+        (tok, (draft_cache.k, draft_cache.v, draft_cache.k_scale,
+               draft_cache.v_scale), draft_cache.length),
         None, length=gamma + 1)
     # drafted_in[i] = the token INGESTED at step i = [tok, d_1..d_gamma]
     d = jnp.moveaxis(drafted_in[1:], 0, 1)               # [S, gamma]
 
     # --- target verifies all gamma+1 positions in ONE forward
     verify_in = jnp.concatenate([tok[:, None], d], axis=1)
-    lg, ck, cv, cks, cvs = _spec_rows_forward(
-        params, cfg, verify_in, cache.k, cache.v, cache.k_scale,
-        cache.v_scale, len0, d_offsets, active, cap)
+    lg, (ck, cv, cks, cvs) = rows_logits(
+        params, cfg, verify_in,
+        (cache.k, cache.v, cache.k_scale, cache.v_scale), len0)
     t_pred = jnp.argmax(lg, axis=-1).astype(jnp.int32)   # [S, gamma+1]
 
     matches = (d == t_pred[:, :gamma]).astype(jnp.int32)
@@ -1644,13 +1508,10 @@ class SlotServer:
     ``slots`` must divide by the batch axes' size. Greedy completions are
     token-identical to the single-device server (tested).
 
-    ``batched_admission`` (default True) admits a BURST of freed slots
-    with one `_prefill_batch` dispatch per chunk round instead of one
-    `_prefill_chunk` dispatch per chunk PER SLOT — K arrivals no longer
-    serialize K x chunks host dispatches in front of the next decode
-    block. Output is exactly the per-slot path's (tested); False keeps
-    the serial path (comparison/debugging). ``admission_dispatches``
-    counts prefill program dispatches either way.
+    A BURST of freed slots is admitted with one `_prefill_batch`
+    dispatch per chunk round (a burst of one at one row) — K arrivals do
+    not serialize K x chunks host dispatches in front of the next decode
+    block. ``admission_dispatches`` counts prefill program dispatches.
 
     ``prefix_cache_blocks=N`` enables the chunk-aligned prefix cache
     (module docstring): N ``prefill_chunk``-sized KV blocks in a shared
@@ -1705,7 +1566,7 @@ class SlotServer:
                  weight_dtype: str = "native", temperature: float = 0.0,
                  top_k: int = 0, stop_tokens: tuple = (), pad_id: int = 0,
                  seed: int = 0, pipeline_depth: int = 2,
-                 mesh=None, rules=None, batched_admission: bool = True,
+                 mesh=None, rules=None,
                  prefix_cache_blocks: int = 0, cache_prompts: bool = True,
                  max_queue: int = 0, trace_sink=None,
                  journal: RequestJournal | None = None,
@@ -1927,7 +1788,6 @@ class SlotServer:
         self.kv_exports = 0             # payloads serialized (stats())
         self.kv_imports = 0             # payloads installed (stats())
         self.kv_import_rejects = 0      # torn/invalid payloads refused
-        self.batched_admission = batched_admission
         self.admission_dispatches = 0   # prefill programs dispatched
         # prefix-cache dispatch + token counters (stats())
         self.prefix_copy_dispatches = 0
@@ -3159,8 +3019,7 @@ class SlotServer:
         phases whose device order is the correctness contract: (1) copy
         cached prefix blocks into the slot rings (one batched program),
         (2) prefill each request's uncached suffix (one `_prefill_batch`
-        program per chunk round by default, or the serial per-slot chunk
-        loop with ``batched_admission=False``), (3) gather the burst's
+        program per chunk round), (3) gather the burst's
         new full-body chunks into fresh pool blocks (one batched
         program). Prefix lookups all run against the trie as of the
         burst start — a same-burst template twin prefills too (its copy
@@ -3247,11 +3106,7 @@ class SlotServer:
         if not admissions:
             return
         self._dispatch_prefix_copy(admissions)
-        if self.batched_admission and len(admissions) > 1:
-            self._prefill_burst(admissions)
-        else:
-            for adm in admissions:
-                self._prefill_one(adm)
+        self._prefill_burst(admissions)
         # draft prefill BEFORE the trie insert: the insert now mirrors
         # each new chunk into the draft pool too, reading the draft
         # cache the suffix prefill just wrote
@@ -3348,8 +3203,7 @@ class SlotServer:
         of bounds (the destination axis named by ``oob``) so their writes
         drop, and leave the other (gather) index at 0 — `jnp.minimum`
         clamping in the programs keeps gathers in range anyway."""
-        n = len(rows)
-        k_rows = 1 << (n - 1).bit_length() if n > 1 else 1
+        k_rows = _pow2_rows(len(rows))
         slots = np.zeros(k_rows, np.int32)
         blocks = np.zeros(k_rows, np.int32)
         chunk_idx = np.zeros(k_rows, np.int32)
@@ -3364,88 +3218,69 @@ class SlotServer:
         return (jnp.asarray(slots), jnp.asarray(blocks),
                 jnp.asarray(chunk_idx), jnp.asarray(offsets))
 
-    def _prefill_one(self, adm: _Admission) -> None:
-        """Serial admission: one `_prefill_chunk` dispatch per chunk (of
-        the uncached suffix — chunk_starts begins at the cached prefix
-        length)."""
-        body, chunk_starts = adm.body, adm.chunk_starts
+    def _pack_rows(self, admissions, r: int, *, commit: bool = True):
+        """Chunk round ``r`` of a burst as `_prefill_batch`'s row
+        arguments (tokens, slots, starts, offsets, n_valids, last_tokens,
+        targets, temps, topks, fin) and the prompt tokens they hold (some
+        admission has a chunk in every round a caller asks for). Rows are
+        padded to the next power of two (O(log slots) compiled widths);
+        padding rows and rows whose prompt has already finished keep an
+        out-of-bounds slot id, so all their writes drop. ``commit=False``
+        (a draft model's cache; a prefill-role replica) leaves the commit
+        columns zero and ``fin`` all False: the rows write KV and lengths,
+        and the slots' committed decode state rides through the
+        program's donation untouched."""
         C = self.prefill_chunk
-        for c0 in chunk_starts:
-            n_valid = max(0, min(C, body.size - c0))
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :n_valid] = body[c0:c0 + n_valid]
-            final = c0 == chunk_starts[-1]
-            (self._cache, self._d_tokens, self._d_active,
-             self._d_target, self._d_offsets,
-             self._d_temps, self._d_topks, fence) = _prefill_chunk(
-                self._params, self._cache, self._d_tokens,
-                self._d_active, self._d_target, self._d_offsets,
-                self._d_temps, self._d_topks,
-                jnp.asarray(chunk), jnp.int32(adm.slot), jnp.int32(c0),
-                jnp.int32(adm.offset), jnp.int32(n_valid),
-                jnp.int32(adm.last), jnp.int32(adm.target),
-                jnp.float32(adm.temp), jnp.int32(adm.topk),
-                cfg=self.cfg, chunk=C, kv_dtype=self.kv_dtype,
-                finalize=final, shardings=self._shardings)
-            self.admission_dispatches += 1
-            self.dispatch_tracker.track("prefill", fence)
-            self.prefill_tokens_computed += n_valid
+        k_rows = _pow2_rows(len(admissions))
+        tokens = np.zeros((k_rows, C), np.int32)
+        slots = self.slots + np.arange(k_rows, dtype=np.int32)  # OOB default
+        starts, offsets, n_valids, lasts, targets, topks = (
+            np.zeros(k_rows, np.int32) for _ in range(6))
+        temps = np.zeros(k_rows, np.float32)
+        fin = np.zeros(k_rows, bool)
+        n_tokens = 0
+        for row, adm in enumerate(admissions):
+            if r >= len(adm.chunk_starts):
+                continue                # this prompt has no chunk round r
+            c0 = adm.chunk_starts[r]
+            nv = max(0, min(C, adm.body.size - c0))
+            tokens[row, :nv] = adm.body[c0:c0 + nv]
+            slots[row], starts[row] = adm.slot, c0
+            offsets[row], n_valids[row] = adm.offset, nv
+            n_tokens += nv
+            if commit:
+                lasts[row], targets[row] = adm.last, adm.target
+                temps[row], topks[row] = adm.temp, adm.topk
+                fin[row] = r == len(adm.chunk_starts) - 1
+        return tuple(jnp.asarray(a) for a in (
+            tokens, slots, starts, offsets, n_valids, lasts, targets,
+            temps, topks, fin)), n_tokens
+
+    def _dispatch_prefill(self, cache, rows, *, draft: bool = False):
+        """One `_prefill_batch` dispatch of packed ``rows`` into ``cache``
+        (the ring cache or a paged view; with ``draft`` the draft model's)
+        -> the written cache. The slots' state vectors ride through every
+        dispatch (donated), the target's and the draft's alike."""
+        (cache, self._d_tokens, self._d_active, self._d_target,
+         self._d_offsets, self._d_temps, self._d_topks,
+         fence) = _prefill_batch(
+            self._draft_params if draft else self._params, cache,
+            self._d_tokens, self._d_active, self._d_target,
+            self._d_offsets, self._d_temps, self._d_topks, *rows,
+            cfg=self._draft_cfg if draft else self.cfg,
+            shardings=None if draft else self._shardings)
+        self.admission_dispatches += 1
+        self.dispatch_tracker.track(
+            "draft_prefill" if draft else "prefill", fence)
+        return cache
 
     def _prefill_burst(self, admissions) -> None:
-        """Batched admission: chunk round r of EVERY admitted request in
-        one `_prefill_batch` dispatch — max-chunks rounds total instead
-        of sum-of-chunks. Rows are padded to the next power of two (at
-        most O(log slots) compiled widths); padding rows and rounds a
-        short prompt has already finished carry an out-of-bounds slot id,
-        so all their writes drop."""
-        C = self.prefill_chunk
-        n = len(admissions)
-        k_rows = 1 << (n - 1).bit_length()
-        rounds = max(len(a.chunk_starts) for a in admissions)
-        S = self.slots
-        for r in range(rounds):
-            tokens = np.zeros((k_rows, C), np.int32)
-            slots = S + np.arange(k_rows, dtype=np.int32)   # OOB default
-            starts = np.zeros(k_rows, np.int32)
-            offsets = np.zeros(k_rows, np.int32)
-            n_valids = np.zeros(k_rows, np.int32)
-            lasts = np.zeros(k_rows, np.int32)
-            targets = np.zeros(k_rows, np.int32)
-            temps = np.zeros(k_rows, np.float32)
-            topks = np.zeros(k_rows, np.int32)
-            fin = np.zeros(k_rows, bool)
-            for row, adm in enumerate(admissions):
-                chunk_starts, body = adm.chunk_starts, adm.body
-                if r >= len(chunk_starts):
-                    continue            # this prompt has no chunk round r
-                c0 = chunk_starts[r]
-                nv = max(0, min(C, body.size - c0))
-                tokens[row, :nv] = body[c0:c0 + nv]
-                slots[row] = adm.slot
-                starts[row] = c0
-                offsets[row] = adm.offset
-                n_valids[row] = nv
-                lasts[row] = adm.last
-                targets[row] = adm.target
-                temps[row] = adm.temp
-                topks[row] = adm.topk
-                fin[row] = r == len(chunk_starts) - 1
-                self.prefill_tokens_computed += nv
-            (self._cache, self._d_tokens, self._d_active,
-             self._d_target, self._d_offsets,
-             self._d_temps, self._d_topks, fence) = _prefill_batch(
-                self._params, self._cache, self._d_tokens,
-                self._d_active, self._d_target, self._d_offsets,
-                self._d_temps, self._d_topks,
-                jnp.asarray(tokens), jnp.asarray(slots),
-                jnp.asarray(starts), jnp.asarray(offsets),
-                jnp.asarray(n_valids), jnp.asarray(lasts),
-                jnp.asarray(targets), jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(fin),
-                cfg=self.cfg, chunk=C, kv_dtype=self.kv_dtype,
-                shardings=self._shardings)
-            self.admission_dispatches += 1
-            self.dispatch_tracker.track("prefill", fence)
+        """Chunk round r of EVERY admitted request in one `_prefill_batch`
+        dispatch — max-chunks rounds total, not sum-of-chunks."""
+        for r in range(max(len(a.chunk_starts) for a in admissions)):
+            rows, n_tokens = self._pack_rows(admissions, r)
+            self._cache = self._dispatch_prefill(self._cache, rows)
+            self.prefill_tokens_computed += n_tokens
 
     def _prefill_draft(self, admissions) -> None:
         """Speculative serving: the draft model needs the same context
@@ -3453,61 +3288,19 @@ class SlotServer:
         — the trie's blocks are mirrored into a draft-shaped pool by
         the same insert rows (``_dispatch_prefix_copy`` seeded the
         draft slot cache before this ran) — so only the uncached
-        suffix prefills, same ``chunk_starts`` as the target. One
-        `_prefill_batch` dispatch per chunk round (the draft config
-        compiles its own variant); every commit row is diverted
-        (``fin`` all False), so the target's committed slot state rides
-        through the donation untouched while the DRAFT cache's lengths
-        land at each row's body size. Every admission appears in round
-        0 even with an empty suffix (fully-cached or 1-token prompt):
-        the zero-valid row still RESETS the draft slot's stale length
-        from its previous occupant, exactly as the target's degenerate
-        finalize chunk does."""
-        C = self.prefill_chunk
-        n = len(admissions)
-        k_rows = 1 << (n - 1).bit_length() if n > 1 else 1
-        rounds = max(len(a.chunk_starts) for a in admissions)
-        S = self.slots
+        suffix prefills, same ``chunk_starts`` as the target, one
+        dispatch per chunk round (the draft config compiles its own
+        variant) with no commit: the DRAFT cache's lengths land at each
+        row's body size. Every admission appears in round 0 even with an
+        empty suffix (fully-cached or 1-token prompt): the zero-valid row
+        still RESETS the draft slot's stale length from its previous
+        occupant, exactly as the target's degenerate final chunk does."""
         for adm in admissions:
             self.draft_prefill_tokens_reused += adm.prefix_len
-        for r in range(rounds):
-            tokens = np.zeros((k_rows, C), np.int32)
-            slots = S + np.arange(k_rows, dtype=np.int32)   # OOB default
-            starts = np.zeros(k_rows, np.int32)
-            offsets = np.zeros(k_rows, np.int32)
-            n_valids = np.zeros(k_rows, np.int32)
-            zi = np.zeros(k_rows, np.int32)
-            zf = np.zeros(k_rows, np.float32)
-            fin = np.zeros(k_rows, bool)
-            any_row = False
-            for row, adm in enumerate(admissions):
-                if r >= len(adm.chunk_starts):
-                    continue            # this prompt has no chunk round r
-                c0 = adm.chunk_starts[r]
-                nv = max(0, min(C, adm.body.size - c0))
-                tokens[row, :nv] = adm.body[c0:c0 + nv]
-                slots[row] = adm.slot
-                starts[row] = c0
-                offsets[row] = adm.offset
-                n_valids[row] = nv
-                any_row = True
-            if not any_row:
-                continue
-            (self._draft_cache, self._d_tokens, self._d_active,
-             self._d_target, self._d_offsets,
-             self._d_temps, self._d_topks, fence) = _prefill_batch(
-                self._draft_params, self._draft_cache, self._d_tokens,
-                self._d_active, self._d_target, self._d_offsets,
-                self._d_temps, self._d_topks,
-                jnp.asarray(tokens), jnp.asarray(slots),
-                jnp.asarray(starts), jnp.asarray(offsets),
-                jnp.asarray(n_valids), jnp.asarray(zi),
-                jnp.asarray(zi), jnp.asarray(zf),
-                jnp.asarray(zi), jnp.asarray(fin),
-                cfg=self._draft_cfg, chunk=C, kv_dtype=self.kv_dtype,
-                shardings=None)
-            self.admission_dispatches += 1
-            self.dispatch_tracker.track("draft_prefill", fence)
+        for r in range(max(len(a.chunk_starts) for a in admissions)):
+            rows, _ = self._pack_rows(admissions, r, commit=False)
+            self._draft_cache = self._dispatch_prefill(
+                self._draft_cache, rows, draft=True)
 
     # ------------------------------------------------- paged-KV engine
     # Every dispatch is gather -> (unchanged ring program) -> scatter:
@@ -3713,7 +3506,6 @@ class SlotServer:
         interleave: a decode block dispatches between pumps, so an
         admission burst stretches across decode blocks instead of
         stalling every in-flight stream for the whole burst's prefill."""
-        C = self.prefill_chunk
         spent = 0
         while self._pending_prefill:
             if budget is not None and spent >= budget:
@@ -3721,9 +3513,7 @@ class SlotServer:
                 break
             pend = self._pending_prefill[0]
             adm, idx = pend
-            c0 = adm.chunk_starts[idx]
             final = idx == len(adm.chunk_starts) - 1
-            n_valid = max(0, min(C, adm.body.size - c0))
             if final and not self._spec and self.role != "prefill":
                 # the admission-time offset aligned the slot's first
                 # decode write with the cursor AS OF ADMISSION; decode
@@ -3737,43 +3527,29 @@ class SlotServer:
                 # never decodes, so its offset is moot.)
                 adm.offset = (self._cursor - adm.body.size) % self.max_len
                 self._np_offs[adm.slot] = adm.offset
-            self._dispatch_paged_prefill(adm, c0, n_valid, final)
-            spent += max(1, n_valid)
+            spent += max(1, self._dispatch_paged_prefill(adm, idx))
             if final:
                 self._pending_prefill.popleft()
                 self._finalize_admit_paged(adm)
             else:
                 pend[1] = idx + 1
 
-    def _dispatch_paged_prefill(self, adm: _Admission, c0: int,
-                                n_valid: int, final: bool) -> None:
-        """One `_prefill_chunk` dispatch on the gathered view, then
-        scatter the chunk's span back into the slot's blocks. A
-        prefill-role replica dispatches even the final chunk with
-        ``finalize=False``: the KV write is unconditional, only the
-        device-side slot ACTIVATION is finalize-gated — so the blocks
-        finish fully written while the slot never decodes (the export
-        snapshot is taken at `_finalize_admit_paged`). In speculative
-        mode the draft mirror pool prefills the same span right after
-        (same tables, same ring ids, its own length vector)."""
+    def _dispatch_paged_prefill(self, adm: _Admission, r: int) -> int:
+        """One one-row `_prefill_batch` dispatch of the admission's chunk
+        ``r`` on the gathered view, then scatter the chunk's span back
+        into the slot's blocks. A prefill-role replica dispatches even
+        the final chunk without the commit: the KV write is
+        unconditional, only the device-side slot ACTIVATION rides on
+        ``fin`` — so the blocks finish fully written while the slot never
+        decodes (the export snapshot is taken at `_finalize_admit_paged`). In speculative mode the draft mirror
+        pool prefills the same span right after (same tables, same ring
+        ids, its own length vector), never committing: the target's
+        commit owns the slot state. Returns the chunk's prompt tokens."""
         C = self.prefill_chunk
-        slot = adm.slot
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :n_valid] = adm.body[c0:c0 + n_valid]
-        view = self._gather_view()
-        (view, self._d_tokens, self._d_active,
-         self._d_target, self._d_offsets,
-         self._d_temps, self._d_topks, fence) = _prefill_chunk(
-            self._params, view, self._d_tokens,
-            self._d_active, self._d_target, self._d_offsets,
-            self._d_temps, self._d_topks,
-            jnp.asarray(chunk), jnp.int32(slot), jnp.int32(c0),
-            jnp.int32(adm.offset), jnp.int32(n_valid),
-            jnp.int32(adm.last), jnp.int32(adm.target),
-            jnp.float32(adm.temp), jnp.int32(adm.topk),
-            cfg=self.cfg, chunk=C, kv_dtype=self.kv_dtype,
-            finalize=final and self.role != "prefill",
-            shardings=self._shardings)
+        slot, c0 = adm.slot, adm.chunk_starts[r]
+        rows, n_valid = self._pack_rows(
+            [adm], r, commit=self.role != "prefill")
+        view = self._dispatch_prefill(self._gather_view(), rows)
         self._d_lens = view.length
         ring_ids = np.zeros((self.slots, C), np.int32)
         ring_ids[slot] = (adm.offset + c0
@@ -3784,32 +3560,17 @@ class SlotServer:
         # the floor will later protect
         floors = np.zeros((self.slots,), np.int32)
         self._scatter_view(view, ring_ids, n_valids, floors)
-        self.admission_dispatches += 1
-        self.dispatch_tracker.track("prefill", fence)
         self.prefill_tokens_computed += n_valid
         if self._spec:
-            # draft mirror: never finalizes (the target's commit owns
-            # the slot state; fin-False passes the state vecs through
-            # the donation untouched, like ring-mode _prefill_draft)
-            dview = self._gather_view(pool=self._draft_kv_pool,
-                                      lens=self._d_draft_lens)
-            (dview, self._d_tokens, self._d_active,
-             self._d_target, self._d_offsets,
-             self._d_temps, self._d_topks, dfence) = _prefill_chunk(
-                self._draft_params, dview, self._d_tokens,
-                self._d_active, self._d_target, self._d_offsets,
-                self._d_temps, self._d_topks,
-                jnp.asarray(chunk), jnp.int32(slot), jnp.int32(c0),
-                jnp.int32(adm.offset), jnp.int32(n_valid),
-                jnp.int32(adm.last), jnp.int32(adm.target),
-                jnp.float32(adm.temp), jnp.int32(adm.topk),
-                cfg=self._draft_cfg, chunk=C, kv_dtype=self.kv_dtype,
-                finalize=False, shardings=None)
+            draft_rows, _ = self._pack_rows([adm], r, commit=False)
+            dview = self._dispatch_prefill(
+                self._gather_view(pool=self._draft_kv_pool,
+                                  lens=self._d_draft_lens),
+                draft_rows, draft=True)
             self._d_draft_lens = dview.length
             self._scatter_view(dview, ring_ids, n_valids, floors,
                                draft=True)
-            self.admission_dispatches += 1
-            self.dispatch_tracker.track("draft_prefill", dfence)
+        return n_valid
 
     def _finalize_admit_paged(self, adm: _Admission) -> None:
         """The finalize chunk is dispatched: activate the slot for

@@ -295,11 +295,38 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh):
 
 def _qkv(cfg: TransformerConfig, h, positions, lp):
     """Projections + rope for a block of hidden states; k/v stay at
-    n_kv_heads (GQA repeat happens at attention time)."""
+    n_kv_heads (GQA repeat happens at attention time).
+
+    The weight formats a layer's ``lp`` may carry — this function,
+    `_attn_out` and `_mlp` are where they are read;
+    `generate._fuse_decode_weights` is the one producer of the fused and
+    int8 forms:
+
+    - training: ``wq`` / ``wk`` / ``wv`` [d, heads, hd], ``wo`` [heads, hd,
+      d], ``w_gate`` / ``w_up`` [d, f], ``w_down`` [f, d] (MoE: ``router``,
+      ``w_in`` [E, d, f], ``w_out`` [E, f, d]), cast to cfg.dtype at use;
+    - fused (decode): ``wqkv`` [d, (heads + 2 kv) * hd] and ``w_gu``
+      [d, 2 f], the concatenations of the above (same values, one skinny
+      matmul for three / two);
+    - int8 (w8a16 decode): any of ``wqkv``, ``wo`` (flattened to [heads *
+      hd, d]), ``w_gu``, ``w_down``, ``w_in``, ``w_out`` as int8 with a
+      per-output-channel scale ``<name>_s`` [.., 1, d_out] beside it, which
+      multiplies the matmul's OUTPUT so the streamed operand stays int8."""
     dt = cfg.dtype
-    q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
+    if "wqkv" in lp:
+        b, l, _ = h.shape
+        hd = cfg.head_dim
+        nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+        qkv = jnp.einsum("bld,de->ble", h, lp["wqkv"].astype(dt))
+        if "wqkv_s" in lp:
+            qkv = qkv * lp["wqkv_s"]
+        q = qkv[..., :nq].reshape(b, l, cfg.n_heads, hd)
+        k = qkv[..., nq:nq + nkv].reshape(b, l, cfg.n_kv_heads, hd)
+        v = qkv[..., nq + nkv:].reshape(b, l, cfg.n_kv_heads, hd)
+    else:
+        q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
+        k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
+        v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
     q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
     k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
@@ -311,6 +338,16 @@ def _repeat_kv(cfg: TransformerConfig, k, v):
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     return k, v
+
+
+def _attn_out(cfg: TransformerConfig, attn, lp):
+    """The output projection of attention [B, L, H, D] -> [B, L, d]."""
+    dt = cfg.dtype
+    if "wo_s" in lp:
+        b, l = attn.shape[:2]
+        return jnp.einsum("ble,ed->bld", attn.reshape(b, l, -1),
+                          lp["wo"].astype(dt)) * lp["wo_s"]
+    return jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
 
 
 def _mlp(cfg: TransformerConfig, h, lp):
@@ -325,25 +362,51 @@ def _mlp(cfg: TransformerConfig, h, lp):
             flat, lp["router"].astype(dt), lp["w_in"].astype(dt),
             lp["w_out"].astype(dt), k=cfg.expert_top_k,
             capacity_factor=cfg.capacity_factor, activation=jax.nn.silu,
+            w_in_scale=lp.get("w_in_s"), w_out_scale=lp.get("w_out_s"),
         )
         aux = load_balancing_loss(router_logits, cfg.expert_top_k)
         return out.reshape(b, l, d), aux
-    gate = jax.nn.silu(jnp.einsum("bld,df->blf", h, lp["w_gate"].astype(dt)))
-    up = jnp.einsum("bld,df->blf", h, lp["w_up"].astype(dt))
-    return jnp.einsum("blf,fd->bld", gate * up, lp["w_down"].astype(dt)), aux
+    if "w_gu" in lp:
+        gu = jnp.einsum("bld,de->ble", h, lp["w_gu"].astype(dt))
+        if "w_gu_s" in lp:
+            gu = gu * lp["w_gu_s"]
+        act = jax.nn.silu(gu[..., :cfg.d_ff]) * gu[..., cfg.d_ff:]
+    else:
+        gate = jax.nn.silu(jnp.einsum("bld,df->blf", h, lp["w_gate"].astype(dt)))
+        act = gate * jnp.einsum("bld,df->blf", h, lp["w_up"].astype(dt))
+    out = jnp.einsum("blf,fd->bld", act, lp["w_down"].astype(dt))
+    if "w_down_s" in lp:
+        out = out * lp["w_down_s"]
+    return out, aux
+
+
+def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None):
+    """One decoder block, the only one: norm -> _qkv -> attend -> wo ->
+    norm -> _mlp. lp = this layer's params (stack dim removed).
+
+    ``attend(kv, q, k, v) -> (attn [B, L, H, D], kv)`` is the one decision
+    the block's callers differ in: how this layer's K/V are stored and what
+    the attention then reads. It gets q roped [B, L, H, D] and k, v roped
+    and UN-repeated [B, L, kvH, D]; ``kv`` is whatever state the caller
+    threads through the layers (None in training; the cache buffers when
+    decoding). Returns (x, aux_loss, kv)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    q, k, v = _qkv(cfg, h, positions, lp)
+    attn, kv = attend(kv, q, k, v)
+    x = x + _attn_out(cfg, attn, lp)
+    mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp)
+    return x + mlp_out, aux, kv
 
 
 def _layer(cfg: TransformerConfig, mesh, x, positions, lp):
-    """One decoder block; lp = this layer's params (stack dim removed)."""
-    dt = cfg.dtype
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, h, positions, lp)
-    k, v = _repeat_kv(cfg, k, v)
-    attn = _attention(q, k, v, cfg, mesh)
-    x = x + jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
+    """The training block: nothing stored, attention over the block's own
+    K/V through the model's kernel."""
+    def attend(kv, q, k, v):
+        k, v = _repeat_kv(cfg, k, v)
+        return _attention(q, k, v, cfg, mesh), kv
 
-    mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp)
-    return x + mlp_out, aux
+    x, aux, _ = decoder_layer(cfg, x, positions, lp, attend)
+    return x, aux
 
 
 def apply_hidden(
