@@ -88,25 +88,30 @@ def test_flash_backward_compiles_for_v5e(one_chip, d, window):
     _compiled_text(jax.jit(bwd), q, k, v, q, lse, q)
 
 
-@pytest.mark.parametrize("variant", ("bf16", "int8", "layer_window", "ring"))
+@pytest.mark.parametrize("variant", ("bf16", "int8", "layer_window", "ring",
+                                     "ring_mha"))
 @pytest.mark.parametrize("d", HEAD_DIMS)
 def test_flash_decode_compiles_for_v5e(one_chip, d, variant):
     """``ring`` is the serving cell's call: the 8-layer stack of 16 slots x
     4096, per-row lengths, ring offsets and the active mask, the sliding
-    window of the published config."""
+    window of the published config. ``ring_mha`` is the hybrid cell's: 30
+    KV heads with one query head each, the two full-attention layers'
+    stack, no window."""
     from tony_tpu.ops.decode_attention import flash_decode
 
     b, kvh, rep, m, layers = 2, 8, 4, 4096, 2
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     q = sds((b, kvh, rep, d), jnp.bfloat16)
     length = sds((), jnp.int32)
-    if variant == "ring":
-        b, layers = 16, 8
+    if variant in ("ring", "ring_mha"):
+        b, layers, window = 16, 8, 4096
+        if variant == "ring_mha":
+            kvh, rep, layers, window = 30, 1, 2, 0
         cache = sds((layers, b, kvh, m, d), jnp.bfloat16)
         rows = sds((b,), jnp.int32)
         fn = jax.jit(lambda q, ck, cv, lengths, offsets, active: flash_decode(
             q, ck, cv, lengths, ring_offsets=offsets, active=active,
-            layer=7, window=4096))
+            layer=layers - 1, window=window))
         _compiled_text(fn, sds((b, kvh, rep, d), jnp.bfloat16), cache, cache,
                        rows, rows, sds((b,), jnp.bool_))
     elif variant == "int8":

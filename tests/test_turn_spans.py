@@ -40,6 +40,16 @@ TINY = transformer.TransformerConfig(
     vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
     d_ff=128, max_seq_len=128, dtype=jnp.float32,
 )
+# one period of a hybrid decoder: its linear layers keep a state a slot;
+# the rows whose state a block changed ride the bookkeep span
+HYBRID = transformer.TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=4,
+    d_ff=128, max_seq_len=128, dtype=jnp.float32, rope_theta=None,
+    qk_norm=True, norm_order="post",
+    layer_kinds=("linear", "linear", "linear", "full"),
+    lin_heads=4, lin_key_dim=8, lin_value_dim=16,
+)
+CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
 STEP_PHASES = (obs.PHASE_ADMIT, obs.PHASE_DISPATCH, obs.PHASE_SYNC,
                obs.PHASE_BOOKKEEP)
 NEW_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
@@ -82,6 +92,10 @@ def run(params, tmp_path_factory):
                                 **kw), _requests(7, seed=1))
         paged = _drain(SlotServer(params, TINY, paged=True, kv_block=8,
                                   **kw), _requests(4, seed=2))
+        hybrid_server = SlotServer(
+            transformer.init(jax.random.PRNGKey(1), HYBRID), HYBRID,
+            stop_tokens=(65,), pad_id=255, **kw)
+        hybrid = _drain(hybrid_server, _requests(3, seed=6))
         # (c) a predictive engine behind ServeApp: its drain syncs inside
         # the engine, the case that would nest under serve.loop.drain
         app = ServeApp(SlotServer(params, TINY, **kw))
@@ -104,7 +118,8 @@ def run(params, tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     return types.SimpleNamespace(
-        dir=trace_dir, engine=[eos, paged], served=served,
+        dir=trace_dir, engine=[eos, paged, hybrid], served=served,
+        hybrid_server=hybrid_server,
         spans=host_spans.spans("serve.", trace_dir))
 
 
@@ -149,14 +164,22 @@ def test_step_phases_carry_their_counts(run):
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
         assert set(counts) == {"tokens", "completions", "kv_blocks_read",
-                               "kv_blocks_ring"}
-    done = {**run.engine[0], **run.engine[1]}
-    assert len(done) == 11
-    assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 11
+                               "kv_blocks_ring", "state_rows"}
+        assert 0 <= counts["state_rows"] <= 3
+    done = {**run.engine[0], **run.engine[1], **run.engine[2]}
+    assert len(done) == 14
+    assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 14
+    # the recurrent state: the rows whose state the device says a block
+    # changed, at most one a token and at least one a request; none in an
+    # engine without linear layers
+    hybrid = run.engine[2]
+    steps = sum(len(comp.tokens) for comp in hybrid.values())
+    assert steps >= sum(c["state_rows"] for c in by_name[obs.PHASE_BOOKKEEP]) \
+        == run.hybrid_server.state_rows >= len(hybrid)
     assert sum(c["prefill_tokens"] for c in by_name[obs.PHASE_ADMIT]) > 0
     assert sum(c["tokens"] for c in by_name[obs.PHASE_BOOKKEEP]) == sum(
         len(comp.tokens) for comp in done.values())
-    assert sum(c["completions"] for c in by_name[obs.PHASE_BOOKKEEP]) == 11
+    assert sum(c["completions"] for c in by_name[obs.PHASE_BOOKKEEP]) == 14
     assert sum(c["blocks"] for c in by_name[obs.PHASE_SYNC]) == len(
         by_name[obs.PHASE_DISPATCH])
     assert any(comp.finish_reason == "stop" for comp in done.values())
@@ -320,7 +343,11 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     stem = entry["name"].partition(".")[0]
     assert (BENCH / "layer_metrics" / f"{stem}.py").is_file()
     assert entry["source"] == "program_span"
-    assert entry["workloads"] == ["mistral7b-v01.chat-open"]
+    # reason-open's traced 5 s hold no arrival in one run of fourteen
+    # (0.53 requests/s): a metric of the arrivals is not listed there
+    assert entry["workloads"] == {
+        "state_rows_advanced_pct": [REASON],
+        "submit_lock_wait_ms": [CHAT]}.get(stem, [CHAT, REASON])
     # a program without the spans (the chip fixture; the parent commit)
     shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
     assert _read(entry, tmp_path, monkeypatch) is None
@@ -331,11 +358,14 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
         assert value <= 100.0
     if entry["name"] == "decode_kv_read_pct":
         assert value == 100.0       # the CPU engine reads the whole ring
+    if stem == "state_rows_advanced_pct":
+        # of every engine's blocks x 3 slots, the hybrid engine's rows
+        assert 0.0 < value < 100.0
 
 
-def test_new_entries_are_the_nine_and_the_shares_are_disjoint(
+def test_new_entries_are_the_ten_and_the_shares_are_disjoint(
         run, monkeypatch):
-    assert len(NEW_ENTRIES) == 9
+    assert len(NEW_ENTRIES) == 10
     monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
     pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
     named = [name for names in pct.PHASES.values() for name in names]

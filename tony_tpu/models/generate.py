@@ -77,6 +77,24 @@ class KVCache(NamedTuple):
     # ([n_layers, B, n_kv_heads, max_len]); None when the cache is native
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
+    # a config with linear layers only (cfg.layer_kinds): what those layers
+    # carry a sequence instead of K/V, the recurrent state [n_linear, B,
+    # lin_heads, lin_key_dim, lin_value_dim] float32 and the convolution's
+    # last inputs [n_linear, B, lin_conv - 1, lin_channels]; k and v above
+    # then hold the full-attention layers only
+    state: jax.Array | None = None
+    conv: jax.Array | None = None
+
+
+def _rec_buffers(cfg: TransformerConfig, batch: int) -> dict:
+    """The zeroed ``state`` / ``conv`` fields of a cache of ``batch``
+    sequences ({} for a config without linear layers)."""
+    if not cfg.n_linear_layers:
+        return {}
+    state, tail = transformer.linear_state_zeros(cfg, batch)
+    n = cfg.n_linear_layers
+    return {"state": jnp.zeros((n,) + state.shape, state.dtype),
+            "conv": jnp.zeros((n,) + tail.shape, tail.dtype)}
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -94,7 +112,8 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     position outermost that read is strided by kvH*D — measured ~3x below
     streaming bandwidth on v5e. Head-major, each head's [M, D] block is
     contiguous."""
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
+    shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
+    rec = _rec_buffers(cfg, batch)
     if kv_dtype == "int8":
         return KVCache(
             k=jnp.zeros(shape, jnp.int8),
@@ -102,6 +121,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
             length=jnp.int32(0),
             k_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
             v_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
+            **rec,
         )
     if kv_dtype != "native":
         raise ValueError(f"kv_dtype must be 'native' or 'int8', got {kv_dtype!r}")
@@ -109,6 +129,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         k=jnp.zeros(shape, cfg.dtype),
         v=jnp.zeros(shape, cfg.dtype),
         length=jnp.int32(0),
+        **rec,
     )
 
 
@@ -387,8 +408,27 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
     attention+MLP weight bytes of extra peak residency. Servers sized
     tightly should build them ONCE with `prepare_decode` and drop the
     master params; then no per-call copies are made at all."""
-    L, d = cfg.n_layers, cfg.d_model
+    d = cfg.d_model
     dt = cfg.dtype
+    if cfg.layer_kinds is not None:
+        # one entry per kind, laid out as params["layers"]: the linear
+        # mixer's projections are one matrix already (transformer
+        # ._linear_stack), so a linear layer fuses its MLP only
+        if weight_dtype == "int8":
+            raise ValueError(
+                "weight_dtype='int8' is not implemented for a config with "
+                "layer_kinds (the int8 forms are the uniform stack's)")
+        out = {}
+        for kind, lp in params["layers"].items():
+            out[kind] = {
+                "w_gu": jnp.concatenate([lp["w_gate"], lp["w_up"]], axis=-1)}
+            if kind == "full":
+                n = lp["wq"].shape[0]
+                out[kind]["wqkv"] = jnp.concatenate(
+                    [lp[w].reshape(n, d, -1) for w in ("wq", "wk", "wv")],
+                    axis=-1)
+        return out
+    L = cfg.n_layers
     lp = params["layers"]
     wqkv = jnp.concatenate([
         lp["wq"].reshape(L, d, -1),
@@ -495,12 +535,13 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     fused_layers = {k: w for k, w in (fused or {}).items()
                     if not k.startswith("unembed")}
 
-    def attend(layer, kv, q, k, v):
+    def attend(layer, carry, q, k, v):
         """Store this layer's K/V at ONE shared scalar offset for every row
         (cache.length on the lockstep path, the ring cursor on the per-row
         path: that is the point of the ring layout, see the docstring),
         then read the whole stack — or, on the empty cache of a prefill,
-        the block itself."""
+        the block itself. ``layer`` counts the layers that hold K/V."""
+        kv, rec = carry
         offset = cache.length if ring_cursor is None else ring_cursor
 
         def put(buf, new):  # buf [Ly, B, kvH, M(, D)], new [B, kvH, L(, D)]
@@ -511,7 +552,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         kv = _store_kv(kv, k, v, put)
         if prefill:
             kr, vr = transformer._repeat_kv(cfg, k, v)
-            return transformer._attention(q, kr, vr, p_cfg, None), kv
+            return transformer._attention(q, kr, vr, p_cfg, None), (kv, rec)
         ck, cv, ks_buf, vs_buf = kv
         attn = _cached_attention(
             cfg, q, ck, cv, cache.length, l, ks_buf, vs_buf,
@@ -521,14 +562,29 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             allow_kernel=shardings is None,
             layer_idx=layer, active=ring_active,
         )
-        return attn, kv
+        return attn, (kv, rec)
 
-    kv = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+    def recur(layer, carry, h, lp):
+        """A linear layer from and to the cache's ``state`` / ``conv``
+        (``layer`` counts the linear layers). On the per-row path only a
+        row that is ``active`` advances: an idle row's garbage step, which
+        K/V beyond a length can take, would be wrong for a state."""
+        kv, (state, conv) = carry
+        out, new_state, new_tail = transformer.linear_mixer(
+            cfg, h, lp, state[layer], conv[layer],
+            None if ring_active is None else ring_active.astype(jnp.int32))
+        return out, (kv, (state.at[layer].set(new_state),
+                          conv.at[layer].set(new_tail)))
+
+    carry = ((cache.k, cache.v, cache.k_scale, cache.v_scale),
+             (cache.state, cache.conv))
     for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], {**params["layers"], **fused_layers})
-        x, _, kv = transformer.decoder_layer(
-            cfg, x, positions, lp, functools.partial(attend, i), kv)
-    ck, cv, ks_buf, vs_buf = kv
+        kind, j, lp = transformer.layer_at(cfg, params["layers"], i,
+                                           fused_layers)
+        x, _, carry = transformer.decoder_layer(
+            cfg, x, positions, lp, functools.partial(attend, j), carry,
+            functools.partial(recur, j) if kind == "linear" else None)
+    (ck, cv, ks_buf, vs_buf), (state, conv) = carry
 
     # all_logits=True projects EVERY position ([B, L, V]) — the speculative
     # verify forward needs the target's prediction after each drafted
@@ -556,7 +612,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             ks_buf = lax.with_sharding_constraint(ks_buf, shardings.scale)
             vs_buf = lax.with_sharding_constraint(vs_buf, shardings.scale)
     new_cache = KVCache(k=ck, v=cv, length=cache.length + l,
-                        k_scale=ks_buf, v_scale=vs_buf)
+                        k_scale=ks_buf, v_scale=vs_buf, state=state, conv=conv)
     return logits, new_cache
 
 

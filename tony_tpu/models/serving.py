@@ -104,6 +104,25 @@ Design — everything stays one compiled program over static shapes:
   are token-identical either way (tested; lookups within one admission
   burst see the trie as of the burst start, so two same-template
   requests admitted together both prefill — the second burst hits).
+- **A second kind of slot state: linear layers' recurrent state.** A
+  config with ``layer_kinds`` (models/transformer.py) mixes
+  full-attention layers with gated-delta-rule layers, which keep no K/V:
+  a slot holds for each a float32 state and the convolution's last
+  inputs (``KVCache.state`` / ``.conv``), and the K/V rings exist for
+  the full layers only. The two kinds of state part ways in two places.
+  Admission (`_rows_forward`) starts a row from zeros where its chunk
+  starts at position 0, so a reused slot needs no clearing, lets only a
+  chunk's valid positions advance state and tail, and carries them
+  between the rounds of a multi-chunk prompt. The decode block advances
+  a row's state only where the row is ``active``: K/V tolerates an idle
+  row's garbage write because it lands beyond the row's length, and a
+  state has no "beyond". Journal replay re-prefills from tokens and is
+  unchanged. What would need a state it cannot get yet (prefix trie,
+  paged pool, speculation, KV handoff, a mesh, an int8 ring) is refused
+  at construction. The decode block reports which rows' state it changed
+  (one more column of its packed result, read off the first linear
+  layer's state before and after the block): the counter ``state_rows``,
+  which rides the bookkeep span, is the device's own account of the mask.
 - **The device never waits on the host.** Per-slot state vectors
   (tokens/active/lengths) are DEVICE-carried: block N+1 consumes block
   N's output arrays without the host seeing them. Without stop tokens
@@ -412,7 +431,7 @@ def _constrain_pool(shardings, cache, *vecs):
     if shardings is None:
         return (cache, *vecs)
     c = lax.with_sharding_constraint
-    cache = KVCache(
+    cache = cache._replace(
         k=c(cache.k, shardings.cache), v=c(cache.v, shardings.cache),
         length=c(cache.length, shardings.act),
         k_scale=(None if cache.k_scale is None
@@ -737,9 +756,10 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     chunk round (`_prefill_batch`) or speculative round (`_spec_block`).
 
     ``bufs`` = the cache's (k, v, k_scale, v_scale) buffers [layers, S,
-    kvH, M(, D)]. A slot's buffer is a RING: logical position p lives at
-    index (p + offsets[r]) mod M, and each layer's K/V scatter there where
-    ``write_ok`` [K, L] holds. Every other position — a chunk's pad tail, a
+    kvH, M(, D)], followed by its (state, conv) buffers where the config
+    has linear layers (below). A slot's buffer is a RING: logical
+    position p lives at index (p + offsets[r]) mod M, and each layer's
+    K/V scatter there where ``write_ok`` [K, L] holds. Every other position — a chunk's pad tail, a
     row with nothing to write, a window overhanging the row's budget — gets
     a distinct OUT-OF-BOUNDS index and mode="drop": written nowhere at all.
     (Wrapping them with the mod would land them on the slot's own EARLIEST
@@ -752,12 +772,21 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     cache_len + ring_offsets branch of `_cached_attention`), so rows never
     disturb one another or a decoding slot.
 
+    A linear layer (``cfg.layer_kinds``) runs `transformer.linear_mixer`
+    a row from the slot's stored state and convolution tail, or from zeros
+    where ``starts[r] == 0`` (an admission's first chunk: a slot reused
+    after a completion starts clean); only the positions ``write_ok`` holds
+    (a prefix of the row) advance them, so a chunk's pad tail leaves no
+    trace and a multi-chunk prompt carries its state from round to round;
+    a padding row's state goes nowhere (mode="drop").
+
     No fused/quantized weights: prefill is MXU-bound (the fusions are
     decode, weight-streaming, optimizations) and the speculative verify
     must keep the raw-weight numerics. Returns (hidden states [K, L, d]
     before the final norm, bufs)."""
     dt = cfg.dtype
     k_rows, l = tokens.shape
+    bufs, rec = bufs[:4], bufs[4:]
     n_slots, m_cap = bufs[0].shape[1], bufs[0].shape[3]
     positions = starts[:, None] + jnp.arange(l)[None, :]        # [K, L]
     ring_idx = jnp.where(write_ok, (offsets[:, None] + positions) % m_cap,
@@ -767,7 +796,9 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     else:
         read_rows = jnp.minimum(rows, n_slots - 1)  # clamp padding rows
 
-    def attend(layer, bufs, q, k, v):
+    def attend(layer, carry, q, k, v):
+        bufs, rec = carry
+
         def put(buf, new):
             # advanced indices [K,1] x [K,L] around the kvH slice put the
             # broadcast dims first: the updates arrive [K, L, kvH(, D)]
@@ -786,14 +817,31 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
         # operand would be a copy
         attn = _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
                                  ring_offsets=offsets, allow_kernel=False)
-        return attn, bufs
+        return attn, (bufs, rec)
+
+    def recur(layer, carry, h, lp):
+        bufs, (state, conv) = carry
+
+        def of_rows(buf):       # the rows' own, zero at a sequence's start
+            mine = buf[layer] if read_rows is None else buf[layer][read_rows]
+            fresh = (starts == 0).reshape((-1,) + (1,) * (mine.ndim - 1))
+            return jnp.where(fresh, 0, mine)
+
+        out, new_state, new_tail = transformer.linear_mixer(
+            cfg, h, lp, of_rows(state), of_rows(conv),
+            jnp.sum(write_ok, axis=1, dtype=jnp.int32))
+        swr = dict(unique_indices=True, mode="drop")
+        return out, (bufs, (state.at[layer, rows].set(new_state, **swr),
+                            conv.at[layer, rows].set(new_tail, **swr)))
 
     x = params["embed"].astype(dt)[tokens]
+    carry = (bufs, rec)
     for i in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        x, _, bufs = transformer.decoder_layer(
-            cfg, x, positions, lp, functools.partial(attend, i), bufs)
-    return x, bufs
+        kind, j, lp = transformer.layer_at(cfg, params["layers"], i)
+        x, _, carry = transformer.decoder_layer(
+            cfg, x, positions, lp, functools.partial(attend, j), carry,
+            functools.partial(recur, j) if kind == "linear" else None)
+    return x, carry[0] + carry[1]
 
 
 @functools.partial(
@@ -834,15 +882,15 @@ def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
     k_rows, l = tokens.shape
     n_slots = cache.k.shape[1]
     write_ok = jnp.arange(l)[None, :] < n_valids[:, None]
-    _, (ck, cv, ks_buf, vs_buf) = _rows_forward(
+    rec = () if cache.state is None else (cache.state, cache.conv)
+    _, (ck, cv, ks_buf, vs_buf, *rec) = _rows_forward(
         params, cfg, tokens,
-        (cache.k, cache.v, cache.k_scale, cache.v_scale),
+        (cache.k, cache.v, cache.k_scale, cache.v_scale, *rec),
         slots, starts, offsets, write_ok)
     swr = dict(unique_indices=True, mode="drop")
     new_len = cache.length.at[slots].set(
         (starts + n_valids).astype(jnp.int32), **swr)
-    cache = KVCache(k=ck, v=cv, length=new_len,
-                    k_scale=ks_buf, v_scale=vs_buf)
+    cache = KVCache(ck, cv, new_len, ks_buf, vs_buf, *rec)
     # non-final rows' commit indices divert out of bounds; all indices
     # stay pairwise distinct (final rows hold distinct real slots < S,
     # the rest n_slots+row), so unique_indices holds
@@ -883,7 +931,8 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     matrix with the final lengths and active mask as its last two columns
     — ONE array so the host pays ONE device->host transfer per processed
     block (each transfer is a host sync whatever its size; three separate
-    fetches tripled the serving loop's wall time).
+    fetches tripled the serving loop's wall time). A cache with a recurrent
+    state adds one last column: 1 where the block changed the row's state.
     Emitted rows are pad past a slot's stop; the host slices by length
     delta instead of trusting pad.
 
@@ -928,7 +977,9 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         # only rows active this step advance (staying ring-aligned with
         # the cursor); a frozen row keeps taking the shared-cursor garbage
         # write, but its data is dead — completions are extracted from the
-        # emitted tokens, and re-admission rewrites the slot from scratch
+        # emitted tokens, and re-admission rewrites the slot from scratch.
+        # A linear layer's state and convolution tail take no such write:
+        # `_forward_with_cache` advances them where ``active`` only
         new_len = jnp.where(active, new_cache.length, cache.length)
         new_cache = new_cache._replace(length=new_len)
         hit_stop = (jnp.isin(nxt, stop_arr) if stop_arr is not None
@@ -939,6 +990,17 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
               if lp_k else emitted)
         return (new_cache, tokens, still, (cursor + 1) % m_cap, key), ys
 
+    def state_mark(cache):
+        """A row's mark of its recurrent state: the first linear layer's,
+        summed (None without one). Equal states give equal marks bit for
+        bit, and a step that changed a state moves its mark, so two marks
+        say whether a block changed a row's state at the cost of one read
+        of one layer's state each (holding the old state to compare it
+        whole would copy every layer's: the scan updates them in place)."""
+        return (None if cache.state is None
+                else jnp.sum(cache.state[0], axis=(1, 2, 3)))
+
+    mark_in = state_mark(cache)
     (cache, tokens, active, cursor, key), ys = lax.scan(
         step, (cache, tokens, active, cursor, key), None, length=block)
     if lp_k:
@@ -954,6 +1016,11 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         ]
     else:
         toks, extra = ys, []
+    if mark_in is not None:
+        # the rows whose recurrent state this block changed, as the device
+        # has it (a frozen row's is bit for bit what it was): the last column
+        moved = state_mark(cache) != mark_in
+        extra = extra + [moved.astype(jnp.int32)[:, None]]
     packed = jnp.concatenate(
         [toks.T, cache.length[:, None], active.astype(jnp.int32)[:, None]]
         + extra, axis=1)
@@ -1526,6 +1593,16 @@ class SlotServer:
     overrides per request. 0 (default) disables the cache entirely.
     ``stats()`` reports the counters.
 
+    A config with linear layers (``cfg.layer_kinds``) adds a recurrent
+    state and a convolution tail per slot beside the full layers' K/V
+    rings (module docstring; HBM = slots x n_linear x (4 x lin_heads x
+    lin_key_dim x lin_value_dim + (lin_conv - 1) x lin_channels x
+    activation bytes), ``stats()["recurrent_state"]``). Such a config
+    serves on the ring engine on one device with native dtypes; together
+    with ``prefix_cache_blocks > 0``, ``paged=True``, a ``draft`` /
+    ``spec_gamma``, ``kv_dtype="int8"``, a ``mesh`` or a ``role`` other
+    than "both" the constructor raises a ``ValueError`` naming which.
+
     Failure model (docs/serving.md "Failure model"):
 
     - ``max_queue=N`` bounds the wait queue: ``submit`` raises
@@ -1614,6 +1691,38 @@ class SlotServer:
             self.model = str(model)
         if not cfg.causal:
             raise ValueError("serving requires a causal model")
+        # ---- recurrent slot state (cfg.layer_kinds with "linear") ----
+        # a linear layer carries a state and a convolution tail a slot
+        # beside the full layers' K/V ring. The ring engine on one device
+        # holds them; what would need a state it cannot give yet is
+        # refused here, each by name, before anything is built
+        self._recurrent = cfg.n_linear_layers > 0
+        if self._recurrent:
+            no = "a config with linear layers (a recurrent slot state) "
+            if prefix_cache_blocks > 0:
+                raise ValueError(
+                    no + "cannot use prefix_cache_blocks: a cached prefix "
+                    "would need the state's snapshot at its chunk boundary")
+            if paged:
+                raise ValueError(
+                    no + "cannot use paged=True: the block pool holds K/V "
+                    "blocks only")
+            if draft is not None or spec_gamma:
+                raise ValueError(
+                    no + "cannot use a draft / spec_gamma: a rejected draft "
+                    "token would need the state rolled back")
+            if kv_dtype == "int8":
+                raise ValueError(
+                    no + "cannot use kv_dtype='int8': not measured beside a "
+                    "float32 state")
+            if mesh is not None or getattr(params, "mesh", None) is not None:
+                raise ValueError(
+                    no + "cannot use a mesh: the state's heads are not "
+                    "sharded")
+            if role != "both":
+                raise ValueError(
+                    no + f"cannot use role={role!r}: the KV handoff does "
+                    "not carry a state")
         if isinstance(params, DecodeWeights):
             if params.mesh is not None:
                 if mesh is not None and mesh != params.mesh:
@@ -1813,6 +1922,9 @@ class SlotServer:
         # a processed block's last step streamed / those its rings hold
         self.kv_blocks_read = 0
         self.kv_blocks_ring = 0
+        # rows whose recurrent state the processed decode blocks changed,
+        # by the device's own account (0 without linear layers)
+        self.state_rows = 0
         self.max_queue = int(max_queue)
         # ---- request durability (events/journal.py) ----
         # the journal records every accepted request's replay state
@@ -2844,6 +2956,23 @@ class SlotServer:
         the view lags by up to pipeline_depth blocks)."""
         return int(self._host_busy.sum())
 
+    def slot_states(self, layer: int = 0) -> dict:
+        """Host copies of what every slot holds of linear layer ``layer``
+        (counted among the linear layers): ``length`` [S] (tokens the slot
+        has consumed), ``state`` [S, H, d_k, d_v] float32 and ``conv`` [S,
+        taps - 1, channels]. A finished request's slot keeps them as its
+        last step left them until an admission reuses it
+        (``Completion.trace["attrs"]["slot"]`` names the slot). Call it
+        where nothing else steps the engine (the serving loop stopped, or
+        under its lock): a dispatch donates the buffers read here. Blocks
+        still in flight are waited for; they leave a frozen row as it is."""
+        if not self._recurrent:
+            raise ValueError("the config has no linear layers")
+        cache = self._cache
+        return {"length": np.asarray(cache.length),
+                "state": np.asarray(cache.state[layer]),
+                "conv": np.asarray(cache.conv[layer])}
+
     def stats(self) -> dict:
         """Serving-load + prefix-cache counters, one flat snapshot (the
         ServeApp /stats payload and MetricsAccumulator feed). Token
@@ -2909,6 +3038,15 @@ class SlotServer:
                 "acceptance": self.spec_accept_hist.snapshot(),
                 "verify_rounds_per_request":
                     self.spec_rounds_hist.snapshot(),
+            }
+        if self._recurrent:
+            c = self.cfg
+            out["recurrent_state"] = {
+                "bytes_resident": self.slots * c.n_linear_layers * (
+                    4 * c.lin_heads * c.lin_key_dim * c.lin_value_dim
+                    + (c.lin_conv - 1) * c.lin_channels
+                    * jnp.dtype(c.dtype).itemsize),
+                "rows_advanced": self.state_rows,
             }
         if self._journal is not None:
             out["journal"] = {
@@ -3098,6 +3236,7 @@ class SlotServer:
             if tr is not None:
                 tr.attrs["prompt_tokens"] = int(prompt.size)
                 tr.attrs["prefix_hit_blocks"] = len(path)
+                tr.attrs["slot"] = slot
                 tr.mark("admitted")
             admissions.append(_Admission(
                 slot=slot, req=req, body=body, offset=offset, target=target,
@@ -3939,7 +4078,7 @@ class SlotServer:
                                "offsets": self._np_offs.copy(),
                                "w": self.block_size + 2
                                + (self.block_size * (2 * lp_k + 1)
-                                  if lp_k else 0),
+                                  if lp_k else 0) + self._recurrent,
                                "lp_k": lp_k,
                                "spec_gamma": None})
         if self._predictive:            # exact: no EOS can surprise us
@@ -4051,7 +4190,7 @@ class SlotServer:
                                "offsets": self._np_offs.copy(),
                                "w": self.block_size + 2
                                + (self.block_size * (2 * lp_k + 1)
-                                  if lp_k else 0),
+                                  if lp_k else 0) + self._recurrent,
                                "lp_k": lp_k,
                                "spec_gamma": None})
         if self._predictive:            # exact: no EOS can surprise us
@@ -4210,11 +4349,13 @@ class SlotServer:
         with phase(PHASE_BOOKKEEP) as span:
             done = len(self._done)
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
+            rows = self.state_rows
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
                               completions=len(self._done) - done,
                               kv_blocks_read=self.kv_blocks_read - read,
-                              kv_blocks_ring=self.kv_blocks_ring - ring)
+                              kv_blocks_ring=self.kv_blocks_ring - ring,
+                              state_rows=self.state_rows - rows)
 
     def _sync(self, recs) -> tuple:
         """-> (the blocks' packed results on the host, each block's
@@ -4256,6 +4397,9 @@ class SlotServer:
             w = rec.get("w", self.block_size + 2)
             packed = flat[:, col:col + w]
             col += w
+            if self._recurrent:     # the decode block's last column
+                self.state_rows += int(packed[:, -1].sum())
+                packed = packed[:, :-1]
             lag = lags[i]
             gamma = rec.get("spec_gamma")
             lp_k = rec.get("lp_k", 0) or 0

@@ -31,6 +31,11 @@ from ..parallel.expert import load_balancing_loss, moe_ffn
 from ..parallel.ring_attention import reference_attention
 
 
+LAYER_KINDS = ("full", "linear")
+# under the root of the linear mixer's L2 normalisation of q and k
+LIN_L2_EPS = 1e-6
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -40,7 +45,8 @@ class TransformerConfig:
     n_kv_heads: int = 8           # < n_heads => GQA
     d_ff: int = 2048
     max_seq_len: int = 2048
-    rope_theta: float = 10000.0
+    # None = no rotary embedding at all (a NoPE layer stack)
+    rope_theta: float | None = 10000.0
     # ("llama3", factor, low_freq_factor, high_freq_factor, original_max
     # _position_embeddings) or None — Llama-3.x context-extension rope
     # (a tuple, not a dict: the config is a static jit argument)
@@ -83,10 +89,59 @@ class TransformerConfig:
     # nothing of size [N,V] is ever live; "auto" goes blockwise at vocab >=
     # 16384 unless the mesh has a tensor axis (vocab-sharded dense wins there)
     ce_impl: str = "auto"
+    # layer pattern: None = the uniform stack of full-attention blocks (one
+    # stacked tree, one lax.scan); else one of LAYER_KINDS per layer, the
+    # parameters one stack PER KIND under params["layers"][kind] and the
+    # forward a walk over the pattern. "linear" is the gated-delta-rule
+    # mixer (`linear_mixer`, ops/gated_delta.py): lin_heads heads of a
+    # float32 state [lin_key_dim, lin_value_dim] a sequence, fed through a
+    # depthwise causal convolution of lin_conv taps
+    layer_kinds: tuple | None = None
+    lin_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 4
+    # RMSNorm on q and k over the whole projected width, before the heads
+    # are split (full-attention layers)
+    qk_norm: bool = False
+    # "pre": x + Mixer(Norm(x)) (Llama); "post": x + Norm(Mixer(x))
+    norm_order: str = "pre"
+
+    def __post_init__(self):
+        kinds = self.layer_kinds
+        if kinds is not None and (len(kinds) != self.n_layers
+                                  or set(kinds) - set(LAYER_KINDS)):
+            raise ValueError(
+                f"layer_kinds must name one of {LAYER_KINDS} for each of "
+                f"the {self.n_layers} layers, got {kinds!r}")
+        if self.norm_order not in ("pre", "post"):
+            raise ValueError(f"norm_order must be 'pre' or 'post', got "
+                             f"{self.norm_order!r}")
+        if self.n_linear_layers and (
+                self.n_experts or not self.causal
+                or min(self.lin_heads, self.lin_key_dim, self.lin_value_dim,
+                       self.lin_conv - 1) < 1):
+            raise ValueError(
+                "linear layers need lin_heads, lin_key_dim, lin_value_dim, "
+                "lin_conv >= 2, a causal model and a dense MLP")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def n_linear_layers(self) -> int:
+        return (self.layer_kinds or ()).count("linear")
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold K/V: all of them but the linear ones."""
+        return self.n_layers - self.n_linear_layers
+
+    @property
+    def lin_channels(self) -> int:
+        """Channels of the linear mixer's convolution: q, k and v."""
+        return self.lin_heads * (2 * self.lin_key_dim + self.lin_value_dim)
 
 
 # ------------------------------------------------------------------ building
@@ -96,48 +151,86 @@ def _dense_init(key, shape, in_axis_size, dtype):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
-def init(key: jax.Array, cfg: TransformerConfig) -> dict:
-    """Build the parameter pytree. Layer params are stacked [n_layers, ...]."""
-    pd = cfg.param_dtype
-    hd = cfg.head_dim
-    keys = iter(jax.random.split(key, 16))
+def _attention_stack(keys, cfg: TransformerConfig, n: int) -> dict:
+    pd, hd, d = cfg.param_dtype, cfg.head_dim, cfg.d_model
+    shapes = (("wq", (d, cfg.n_heads, hd), d), ("wk", (d, cfg.n_kv_heads, hd), d),
+              ("wv", (d, cfg.n_kv_heads, hd), d),
+              ("wo", (cfg.n_heads, hd, d), cfg.n_heads * hd))
+    out = {"attn_norm": jnp.ones((n, d), pd), "mlp_norm": jnp.ones((n, d), pd)}
+    for name, shape, fan_in in shapes:
+        out[name] = _dense_init(next(keys), (n,) + shape, fan_in, pd)
+    if cfg.qk_norm:
+        out["q_norm"] = jnp.ones((n, cfg.n_heads * hd), pd)
+        out["k_norm"] = jnp.ones((n, cfg.n_kv_heads * hd), pd)
+    return out
 
-    def layer_stack(shape, in_size):
-        k = next(keys)
-        return _dense_init(k, (cfg.n_layers,) + shape, in_size, pd)
 
-    params: dict = {
-        "embed": _dense_init(next(keys), (cfg.vocab_size, cfg.d_model), cfg.d_model, pd),
-        "layers": {
-            "attn_norm": jnp.ones((cfg.n_layers, cfg.d_model), pd),
-            "wq": layer_stack((cfg.d_model, cfg.n_heads, hd), cfg.d_model),
-            "wk": layer_stack((cfg.d_model, cfg.n_kv_heads, hd), cfg.d_model),
-            "wv": layer_stack((cfg.d_model, cfg.n_kv_heads, hd), cfg.d_model),
-            "wo": layer_stack((cfg.n_heads, hd, cfg.d_model), cfg.n_heads * hd),
-            "mlp_norm": jnp.ones((cfg.n_layers, cfg.d_model), pd),
-        },
-        "final_norm": jnp.ones((cfg.d_model,), pd),
-        "unembed": _dense_init(next(keys), (cfg.d_model, cfg.vocab_size), cfg.d_model, pd),
-    }
+def _mlp_stack(keys, cfg: TransformerConfig, n: int) -> dict:
+    pd, d, f = cfg.param_dtype, cfg.d_model, cfg.d_ff
     if cfg.n_experts > 0:
-        params["layers"].update({
-            "router": layer_stack((cfg.d_model, cfg.n_experts), cfg.d_model),
-            "w_in": layer_stack((cfg.n_experts, cfg.d_model, cfg.d_ff), cfg.d_model),
-            "w_out": layer_stack((cfg.n_experts, cfg.d_ff, cfg.d_model), cfg.d_ff),
-        })
+        shapes = (("router", (d, cfg.n_experts), d),
+                  ("w_in", (cfg.n_experts, d, f), d),
+                  ("w_out", (cfg.n_experts, f, d), f))
     else:
-        params["layers"].update({
-            "w_gate": layer_stack((cfg.d_model, cfg.d_ff), cfg.d_model),
-            "w_up": layer_stack((cfg.d_model, cfg.d_ff), cfg.d_model),
-            "w_down": layer_stack((cfg.d_ff, cfg.d_model), cfg.d_ff),
-        })
-    return params
+        shapes = (("w_gate", (d, f), d), ("w_up", (d, f), d),
+                  ("w_down", (f, d), f))
+    return {name: _dense_init(next(keys), (n,) + shape, fan_in, pd)
+            for name, shape, fan_in in shapes}
+
+
+def _linear_stack(keys, cfg: TransformerConfig, n: int) -> dict:
+    """The gated-delta-rule mixer's parameters (`linear_mixer` reads them):
+    q, k and v projected by ONE matrix ``w_qkv`` [d, H (2 d_k + d_v)] (the
+    channels of the convolution ``conv_w`` [taps, channels], q then k then
+    v), the output gate ``w_g`` [d, H d_v], the decay and correction gates
+    ``w_ab`` [d, 2 H] (a then b) with ``A_log`` / ``dt_bias`` [H], the
+    gated output norm ``o_norm`` [d_v] and ``wo`` [H d_v, d]. One format
+    for training, prefill and decode: nothing to fuse later."""
+    pd, d, h = cfg.param_dtype, cfg.d_model, cfg.lin_heads
+    hv = h * cfg.lin_value_dim
+    out = {"attn_norm": jnp.ones((n, d), pd), "mlp_norm": jnp.ones((n, d), pd),
+           "o_norm": jnp.ones((n, cfg.lin_value_dim), pd)}
+    for name, shape, fan_in in (
+            ("w_qkv", (d, cfg.lin_channels), d), ("w_g", (d, hv), d),
+            ("w_ab", (d, 2 * h), d), ("wo", (hv, d), hv),
+            ("conv_w", (cfg.lin_conv, cfg.lin_channels), cfg.lin_conv)):
+        out[name] = _dense_init(next(keys), (n,) + shape, fan_in, pd)
+    # decay rates exp(A_log) in [1, 16) and step sizes log-uniform in
+    # [1e-3, 1e-1], stored through the inverse softplus (the published
+    # layer's initializer)
+    out["A_log"] = jnp.log(jax.random.uniform(
+        next(keys), (n, h), minval=1.0, maxval=16.0)).astype(pd)
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (n, h), minval=math.log(1e-3), maxval=math.log(1e-1)))
+    out["dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+    return out
+
+
+def init(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """Build the parameter pytree. Layer params are stacked [n_layers, ...];
+    with ``cfg.layer_kinds`` one such stack per kind, each over that
+    kind's layers in the model's order."""
+    pd = cfg.param_dtype
+    keys = iter(jax.random.split(key, 32 if cfg.layer_kinds else 16))
+    n_full, n_linear = cfg.n_attn_layers, cfg.n_linear_layers
+    embed = _dense_init(next(keys), (cfg.vocab_size, cfg.d_model), cfg.d_model, pd)
+    # attention, unembedding, MLP: the order the uniform tree draws its keys
+    attn = _attention_stack(keys, cfg, n_full)
+    unembed = _dense_init(next(keys), (cfg.d_model, cfg.vocab_size), cfg.d_model, pd)
+    layers = {**attn, **_mlp_stack(keys, cfg, n_full)}
+    if cfg.layer_kinds is not None:
+        layers = {"full": layers} if n_full else {}
+        if n_linear:
+            layers["linear"] = {**_linear_stack(keys, cfg, n_linear),
+                                **_mlp_stack(keys, cfg, n_linear)}
+    return {"embed": embed, "layers": layers,
+            "final_norm": jnp.ones((cfg.d_model,), pd), "unembed": unembed}
 
 
 def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Mirror of init()'s tree with logical-axis tuples for
     parallel/sharding.py rule tables."""
-    layers: dict = {
+    attn: dict = {
         "attn_norm": ("layers", None),
         "wq": ("layers", "embed", "heads", None),
         "wk": ("layers", "embed", "kv", None),
@@ -145,18 +238,36 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
         "wo": ("layers", "heads", None, "embed"),
         "mlp_norm": ("layers", None),
     }
+    if cfg.qk_norm:
+        attn.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
     if cfg.n_experts > 0:
-        layers.update({
+        mlp = {
             "router": ("layers", "embed", None),
             "w_in": ("layers", "expert", "embed", "mlp"),
             "w_out": ("layers", "expert", "mlp", "embed"),
-        })
+        }
     else:
-        layers.update({
+        mlp = {
             "w_gate": ("layers", "embed", "mlp"),
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
-        })
+        }
+    layers: dict = {**attn, **mlp}
+    if cfg.layer_kinds is not None:
+        # the linear mixer's heads are not split over a mesh yet (no
+        # caller shards a recurrent config): everything but the embed dim
+        # replicated
+        linear = {
+            "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+            "o_norm": ("layers", None), "A_log": ("layers", None),
+            "dt_bias": ("layers", None), "conv_w": ("layers", None, None),
+            "w_qkv": ("layers", "embed", None), "w_g": ("layers", "embed", None),
+            "w_ab": ("layers", "embed", None), "wo": ("layers", None, "embed"),
+            **mlp,
+        }
+        layers = {kind: tree for kind, tree in
+                  (("full", layers), ("linear", linear))
+                  if kind in cfg.layer_kinds}
     return {
         "embed": ("vocab", "embed"),
         "layers": layers,
@@ -327,8 +438,15 @@ def _qkv(cfg: TransformerConfig, h, positions, lp):
         q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
         k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
         v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
-    q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    if cfg.qk_norm:
+        def whole(x, w):    # over the projected width, heads not yet split
+            flat = x.reshape(x.shape[:2] + (-1,))
+            return rms_norm(flat, w, cfg.norm_eps).reshape(x.shape)
+
+        q, k = whole(q, lp["q_norm"]), whole(k, lp["k_norm"])
+    if cfg.rope_theta is not None:
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 
@@ -380,32 +498,143 @@ def _mlp(cfg: TransformerConfig, h, lp):
     return out, aux
 
 
-def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None):
-    """One decoder block, the only one: norm -> _qkv -> attend -> wo ->
-    norm -> _mlp. lp = this layer's params (stack dim removed).
+def linear_mixer(cfg: TransformerConfig, h, lp, state, tail, n_valid=None):
+    """The gated-delta-rule mixer over a block of positions: h [B, L, d] ->
+    (out [B, L, d], new state, new tail).
+
+    ``state`` [B, H, d_k, d_v] float32 and ``tail`` [B, taps - 1,
+    channels] (the convolution's last inputs) are what a sequence carries
+    from its earlier positions: zeros at its start. ``n_valid`` [B] int
+    (None = all L): only a row's first ``n_valid`` positions advance its
+    state and tail, which come back as they were for a row with none. The
+    rest of the block is a chunk's pad tail or an idle decode row, and its
+    ``out`` is unspecified.
+
+    z = h w_qkv through the depthwise causal convolution and SiLU gives q',
+    k', v by head; q and k are L2-normalised (q also scaled by d_k^-1/2);
+    beta = 2 sigmoid(h w_b), log alpha = -exp(A_log) softplus(h w_a +
+    dt_bias); the recurrence is ops/gated_delta.py's (one step for L = 1,
+    the chunkwise form otherwise); the output is RMSNorm_dv(o) * silu(h w_g)
+    by head, through wo. Convolution, normalisations, gates and the
+    recurrence are float32; the projections run in cfg.dtype."""
+    from ..ops.gated_delta import gated_delta_chunk, gated_delta_step
+
+    dt, f32 = cfg.dtype, jnp.float32
+    b, l, _ = h.shape
+    nh, dk, dv, taps = (cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim,
+                        cfg.lin_conv)
+    z = jnp.einsum("bld,dc->blc", h, lp["w_qkv"].astype(dt))
+    zz = jnp.concatenate([tail.astype(dt), z], axis=1)      # [B, taps-1+L, C]
+    w = lp["conv_w"].astype(f32)
+    c = jax.nn.silu(sum(w[j] * zz[:, j:j + l].astype(f32)
+                        for j in range(taps)))
+    q = c[..., :nh * dk].reshape(b, l, nh, dk)
+    k = c[..., nh * dk:2 * nh * dk].reshape(b, l, nh, dk)
+    v = c[..., 2 * nh * dk:].reshape(b, l, nh, dv)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + LIN_L2_EPS)
+
+    q, k = unit(q) * dk ** -0.5, unit(k)
+    ab = jnp.einsum("bld,dc->blc", h, lp["w_ab"].astype(dt)).astype(f32)
+    beta = 2.0 * jax.nn.sigmoid(ab[..., nh:])    # eigenvalues in (-1, 1)
+    log_alpha = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+        ab[..., :nh] + lp["dt_bias"].astype(f32))
+    if l == 1:
+        o, state = gated_delta_step(
+            q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], state,
+            None if n_valid is None else n_valid > 0)
+        o = o[:, None]
+    else:
+        valid = (None if n_valid is None
+                 else jnp.arange(l)[None, :] < n_valid[:, None])
+        o, state = gated_delta_chunk(q, k, v, log_alpha, beta, state, valid)
+    if n_valid is None:
+        tail = zz[:, l:]
+    else:       # the taps - 1 inputs before position n_valid of the block
+        idx = n_valid[:, None] + jnp.arange(taps - 1)[None, :]
+        tail = jnp.take_along_axis(zz, idx[..., None], axis=1)
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    o = o * jax.lax.rsqrt(var + cfg.norm_eps) * lp["o_norm"].astype(f32)
+    gate = jnp.einsum("bld,de->ble", h, lp["w_g"].astype(dt))
+    o = o * jax.nn.silu(gate.astype(f32).reshape(b, l, nh, dv))
+    out = jnp.einsum("ble,ed->bld", o.reshape(b, l, nh * dv).astype(dt),
+                     lp["wo"].astype(dt))
+    return out, state, tail
+
+
+def linear_state_zeros(cfg: TransformerConfig, batch: int):
+    """(state, tail) of ``batch`` sequences at their start."""
+    return (jnp.zeros((batch, cfg.lin_heads, cfg.lin_key_dim,
+                       cfg.lin_value_dim), jnp.float32),
+            jnp.zeros((batch, cfg.lin_conv - 1, cfg.lin_channels), cfg.dtype))
+
+
+def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
+    """Layer ``i`` of the model -> (its kind, its index among the layers of
+    that kind, its params with the stack dim removed). ``extra`` is a second
+    tree of per-layer leaves laid out as ``layers`` (the fused decode forms),
+    merged over it."""
+    kinds = cfg.layer_kinds
+    if kinds is None:
+        kind, j, stack = "full", i, {**layers, **(extra or {})}
+    else:
+        kind = kinds[i]
+        j = kinds[:i].count(kind)
+        stack = {**layers[kind], **(extra or {}).get(kind, {})}
+    return kind, j, jax.tree.map(lambda a: a[j], stack)
+
+
+def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
+                  recur=None):
+    """One decoder block, the only one: norm -> mixer -> norm -> _mlp
+    (``norm_order`` "pre"; "post" norms each branch's OUTPUT instead). lp =
+    this layer's params (stack dim removed). The caller names the mixer by
+    what it hands in: a full-attention layer (``recur`` None) is _qkv ->
+    attend -> wo, a linear layer is ``recur``.
 
     ``attend(kv, q, k, v) -> (attn [B, L, H, D], kv)`` is the one decision
     the block's callers differ in: how this layer's K/V are stored and what
     the attention then reads. It gets q roped [B, L, H, D] and k, v roped
     and UN-repeated [B, L, kvH, D]; ``kv`` is whatever state the caller
     threads through the layers (None in training; the cache buffers when
-    decoding). Returns (x, aux_loss, kv)."""
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, h, positions, lp)
-    attn, kv = attend(kv, q, k, v)
-    x = x + _attn_out(cfg, attn, lp)
-    mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp)
+    decoding). ``recur(kv, h, lp) -> (out [B, L, d], kv)`` is the same
+    decision for a linear layer: where its state and convolution tail come
+    from and go to around `linear_mixer`. Returns (x, aux_loss, kv)."""
+    pre = cfg.norm_order == "pre"
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
+    if recur is not None:
+        mixed, kv = recur(kv, h, lp)
+    else:
+        q, k, v = _qkv(cfg, h, positions, lp)
+        attn, kv = attend(kv, q, k, v)
+        mixed = _attn_out(cfg, attn, lp)
+    if not pre:
+        mixed = rms_norm(mixed, lp["attn_norm"], cfg.norm_eps)
+    x = x + mixed
+    if pre:
+        mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp)
+    else:
+        mlp_out, aux = _mlp(cfg, x, lp)
+        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.norm_eps)
     return x + mlp_out, aux, kv
 
 
-def _layer(cfg: TransformerConfig, mesh, x, positions, lp):
+def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
     """The training block: nothing stored, attention over the block's own
-    K/V through the model's kernel."""
+    K/V through the model's kernel, a linear layer from a zero state."""
     def attend(kv, q, k, v):
         k, v = _repeat_kv(cfg, k, v)
         return _attention(q, k, v, cfg, mesh), kv
 
-    x, aux, _ = decoder_layer(cfg, x, positions, lp, attend)
+    def recur(kv, h, lp):
+        out, _, _ = linear_mixer(cfg, h, lp,
+                                 *linear_state_zeros(cfg, h.shape[0]))
+        return out, kv
+
+    x, aux, _ = decoder_layer(cfg, x, positions, lp, attend,
+                              recur=recur if kind == "linear" else None)
     return x, aux
 
 
@@ -423,7 +652,8 @@ def apply_hidden(
     positions = jnp.broadcast_to(jnp.arange(l), (b, l))
     x = params["embed"].astype(dt)[tokens]
 
-    layer_fn = functools.partial(_layer, cfg, mesh)
+    layer_fns = {kind: functools.partial(_layer, cfg, mesh, kind=kind)
+                 for kind in LAYER_KINDS}
     if cfg.remat:
         if cfg.remat_policy == "full":
             policy = None
@@ -444,14 +674,21 @@ def apply_hidden(
                 f"remat_policy must be 'full', 'dots', or 'attn', got "
                 f"{cfg.remat_policy!r}"
             )
-        layer_fn = jax.checkpoint(layer_fn, policy=policy)
+        layer_fns = {kind: jax.checkpoint(fn, policy=policy)
+                     for kind, fn in layer_fns.items()}
 
     def scan_body(carry, lp):
         x = carry
-        x, aux = layer_fn(x, positions, lp)
+        x, aux = layer_fns["full"](x, positions, lp)
         return x, aux
 
-    x, auxes = jax.lax.scan(scan_body, x, params["layers"])
+    if cfg.layer_kinds is None:
+        x, auxes = jax.lax.scan(scan_body, x, params["layers"])
+    else:       # a walk over the pattern: the kinds' stacks differ in shape
+        auxes = jnp.float32(0)
+        for i in range(cfg.n_layers):
+            kind, _, lp = layer_at(cfg, params["layers"], i)
+            x, _ = layer_fns[kind](x, positions, lp)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, jnp.sum(auxes) * cfg.aux_loss_weight
 
