@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,3 +148,53 @@ def test_flash_under_a_four_chip_mesh_compiles_for_v5e(topo):
 
     fn = jax.jit(_partition_over_batch_and_heads(attn, mesh, q.shape))
     _compiled_text(fn, q, q, q)
+
+
+STATE = (6, 16, 30, 96, 192)    # the hybrid cell's: layers, slots, H, d_k, d_v
+
+
+def _delta_args(sds):
+    _, b, h, dk, dv = STATE
+    f32 = jnp.float32
+    return (sds((b, h, dk), f32), sds((b, h, dk), f32), sds((b, h, dv), f32),
+            sds((b, h), f32), sds((b, h), f32), sds(STATE, f32),
+            sds((b,), jnp.bool_))
+
+
+@pytest.mark.parametrize("head_block", (None, 30))
+def test_gated_delta_decode_compiles_for_v5e(one_chip, head_block):
+    """The hybrid cell's call: the whole stack of six layers' states as
+    the operand, 16 slots, a live list that is a runtime value."""
+    from tony_tpu.ops.gated_delta import gated_delta_decode
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = jax.jit(functools.partial(gated_delta_decode, layer=5,
+                                   head_block=head_block))
+    _compiled_text(fn, *_delta_args(sds))
+
+
+def test_gated_delta_decode_stays_in_place_under_a_scan(one_chip):
+    """Two decode steps of six calls each on a scan-carried, donated
+    stack: nothing in the compiled program copies, slices or sets an
+    array of the stack's shape (one copy is 283 MB, more than the kernel
+    saves a step)."""
+    from tony_tpu.ops.gated_delta import gated_delta_decode
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    @functools.partial(jax.jit, donate_argnums=(5,))
+    def two_steps(q, k, v, log_alpha, beta, state, active):
+        def step(state, _):
+            total = 0.0
+            for layer in range(STATE[0]):
+                o, state = gated_delta_decode(q, k, v, log_alpha, beta,
+                                              state, active, layer=layer)
+                total += jnp.sum(o)
+            return state, total
+        return jax.lax.scan(step, state, None, length=2)
+
+    text = _compiled_text(two_steps, *_delta_args(sds))
+    assert text.count('custom_call_target="tpu_custom_call"') == STATE[0]
+    made = re.findall(r" = f32\[%s\]\{[^}]*\} ([\w-]+)\(" % ",".join(
+        map(str, STATE)), text)
+    assert made and set(made) <= {"parameter", "get-tuple-element"}, made

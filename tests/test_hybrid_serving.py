@@ -245,6 +245,49 @@ def test_a_frozen_row_keeps_its_state_while_others_decode(prepared, tcfg):
         assert (a == b).all()
 
 
+def test_host_count_of_streamed_states_is_the_devices_live_list(
+        prepared, tcfg, monkeypatch):
+    """``state_rows_read`` on a block in which row 1 stops at its budget
+    two steps in: the host's rule (the rows that took every step of the
+    block, off the packed lengths) names the rows that the device's
+    ``active`` holds going into the block's last step, which is the list
+    ``gated_delta_decode`` walks there."""
+    from tony_tpu.ops.gated_delta import live_state_rows
+
+    monkeypatch.setattr(serving, "state_kernel_engages", lambda *a: True)
+    block = ENGINE["block_size"]
+    server = SlotServer(prepared, tcfg, stop_tokens=(), **ENGINE)
+    rng = np.random.default_rng(3)
+    for n, new in ((6, 40), (11, 3), (9, 40)):
+        server.submit(_request(rng, n, max_new=new))
+    server._admit()
+    assert list(np.asarray(server._d_active)) == [True, True, True, False]
+    before = np.asarray(server._cache.length)
+
+    def run(steps):
+        return serving._decode_block(
+            server._params, server._fused,
+            jax.tree.map(jnp.copy, server._cache), server._d_tokens,
+            server._d_active, server._d_target, server._d_offsets,
+            jnp.int32(server._cursor), server._d_temps, server._d_topks,
+            jax.random.PRNGKey(0), cfg=server.cfg, block=steps,
+            stop_tokens=(), pad_id=255, top_k=0, per_row_topk=False,
+            weight_dtype="native", build_fused=False, all_greedy=True)
+
+    # (pad_id 255: programs of this test's own, so that the next test's
+    # block of 3 is still traced under its planted fault)
+    _, _, at_last, _ = run(block - 1)       # ``active`` into the last step
+    rows, count = live_state_rows(at_last, xp=jnp)
+    assert int(count) == 2 and list(np.asarray(rows)[:2]) == [0, 2]
+    *_, packed = run(block)
+    packed = np.asarray(packed)
+    assert list(packed[:, -1]) == [1, 1, 1, 0]      # row 1's state did move
+    server._expect_len = before
+    server._count_state_rows(packed[:, block])
+    assert (server.state_rows_read, server.state_rows_held) == (
+        int(count), ENGINE["slots"])
+
+
 def test_a_mask_left_out_shows_in_the_devices_count(prepared, tcfg,
                                                     monkeypatch):
     """``state_rows`` is read off the state itself, so a decode step that

@@ -164,8 +164,14 @@ def test_step_phases_carry_their_counts(run):
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
         assert set(counts) == {"tokens", "completions", "kv_blocks_read",
-                               "kv_blocks_ring", "state_rows"}
+                               "kv_blocks_ring", "state_rows",
+                               "state_rows_read", "state_rows_held"}
         assert 0 <= counts["state_rows"] <= 3
+        # the CPU's recurrence is ``gated_delta_step`` over every row
+        # (``state_kernel_engages`` is false here); an engine without
+        # linear layers holds no state
+        assert counts["state_rows_read"] == counts["state_rows_held"]
+        assert counts["state_rows_held"] % 3 == 0
     done = {**run.engine[0], **run.engine[1], **run.engine[2]}
     assert len(done) == 14
     assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 14
@@ -176,6 +182,10 @@ def test_step_phases_carry_their_counts(run):
     steps = sum(len(comp.tokens) for comp in hybrid.values())
     assert steps >= sum(c["state_rows"] for c in by_name[obs.PHASE_BOOKKEEP]) \
         == run.hybrid_server.state_rows >= len(hybrid)
+    held = sum(c["state_rows_held"] for c in by_name[obs.PHASE_BOOKKEEP])
+    assert held == run.hybrid_server.state_rows_held \
+        == run.hybrid_server.state_rows_read > 0
+    assert run.hybrid_server.stats()["recurrent_state"]["rows_held"] == held
     assert sum(c["prefill_tokens"] for c in by_name[obs.PHASE_ADMIT]) > 0
     assert sum(c["tokens"] for c in by_name[obs.PHASE_BOOKKEEP]) == sum(
         len(comp.tokens) for comp in done.values())
@@ -291,6 +301,24 @@ def test_kv_block_count_where_the_kernel_engages(params, monkeypatch):
     assert blocks <= server.kv_blocks_read < server.kv_blocks_ring * 3 // 4
 
 
+def test_state_row_count_where_the_kernel_engages(monkeypatch):
+    """With the gate forced open the engine counts by the kernel's rule:
+    the rows live at a block's last step, not the slots."""
+    from tony_tpu.models import serving
+
+    monkeypatch.setattr(serving, "state_kernel_engages", lambda *a: True)
+    server = SlotServer(transformer.init(jax.random.PRNGKey(1), HYBRID),
+                        HYBRID, slots=3, max_len=64, block_size=4,
+                        prefill_chunk=8, stop_tokens=(65,), pad_id=255)
+    _drain(server, _requests(5, seed=6, max_new=11))
+    blocks = server.state_rows_held // 3
+    assert blocks >= 3 and server.state_rows_held == blocks * 3
+    # a row that stops inside a block is not live at its last step, so the
+    # count stays under the rows whose state the blocks changed
+    assert 0 < server.state_rows_read <= server.state_rows
+    assert server.state_rows_read < server.state_rows_held
+
+
 @pytest.mark.parametrize("window", (0, 5, 40))
 @pytest.mark.parametrize("block_k, m_cap", [(16, 64), (16, 56), (64, 64)])
 def test_live_kv_blocks_against_a_brute_force_mask(block_k, m_cap, window):
@@ -346,7 +374,7 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     # reason-open's traced 5 s hold no arrival in one run of fourteen
     # (0.53 requests/s): a metric of the arrivals is not listed there
     assert entry["workloads"] == {
-        "state_rows_advanced_pct": [REASON],
+        "state_rows_advanced_pct": [REASON], "state_read_pct": [REASON],
         "submit_lock_wait_ms": [CHAT]}.get(stem, [CHAT, REASON])
     # a program without the spans (the chip fixture; the parent commit)
     shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
@@ -356,16 +384,17 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     assert isinstance(value, float) and value >= 0.0
     if entry["unit"] == "%" and "occupancy" in entry["name"]:
         assert value <= 100.0
-    if entry["name"] == "decode_kv_read_pct":
-        assert value == 100.0       # the CPU engine reads the whole ring
+    if entry["name"] in ("decode_kv_read_pct", "state_read_pct"):
+        assert value == 100.0       # the CPU engine reads the whole ring,
+        #                             and every slot's state
     if stem == "state_rows_advanced_pct":
         # of every engine's blocks x 3 slots, the hybrid engine's rows
         assert 0.0 < value < 100.0
 
 
-def test_new_entries_are_the_ten_and_the_shares_are_disjoint(
+def test_new_entries_are_the_eleven_and_the_shares_are_disjoint(
         run, monkeypatch):
-    assert len(NEW_ENTRIES) == 10
+    assert len(NEW_ENTRIES) == 11
     monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
     pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
     named = [name for names in pct.PHASES.values() for name in names]

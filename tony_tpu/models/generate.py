@@ -224,6 +224,19 @@ def decode_kernel_engages(cfg, m_cap: int) -> bool:
             and jax.default_backend() == "tpu")
 
 
+def state_kernel_engages(l_new: int, sharded: bool) -> bool:
+    """Whether a linear layer's recurrence over ``l_new`` new positions a
+    row may run the Pallas kernel (ops/gated_delta.py
+    ``gated_delta_decode``: the live rows' state tiles read and written
+    once, in place in the cache's stack) instead of ``gated_delta_step``
+    on a slice of it: the one gate on what the code observes, which the
+    serving engine's ``state_rows_read`` count asks too. One new token a
+    row (a block of positions is the chunkwise form's), on the TPU (the
+    CPU keeps the ``jax.numpy`` statement the kernel is tested against),
+    and not under a mesh (as ``decode_kernel_engages``: a shard_map away)."""
+    return l_new == 1 and not sharded and jax.default_backend() == "tpu"
+
+
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
                       allow_kernel=True, layer_idx=None, active=None):
@@ -496,6 +509,18 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     zero-copy across decode steps); measured ~1.7x decode throughput on the
     flagship model at batch 8.
 
+    A linear layer (``cfg.layer_kinds``) goes from and to ``cache.state`` /
+    ``.conv`` (`recur` below). Its recurrence runs one of three ways, by
+    what the code observes and by no option: a block of positions (L > 1)
+    is the chunkwise form on the layer's slice; one new token a row on the
+    TPU outside a mesh (``state_kernel_engages``) is the Pallas kernel
+    ``gated_delta_decode`` on the WHOLE state stack, which reads and writes
+    the live rows' tiles of that layer once, in place (the slot pool's
+    decode block: a quarter of its rows live, and six such layers a step);
+    elsewhere (the CPU, a mesh) ``gated_delta_step`` on the slice, set
+    back with ``.at[layer].set``, the statement the kernel is tested
+    against.
+
     ``prefill=True`` asserts the cache is EMPTY (generate's first call):
     attention over (cache + new) then reduces to causal attention within
     the block itself and runs through the model's own _attention (the
@@ -568,13 +593,23 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         """A linear layer from and to the cache's ``state`` / ``conv``
         (``layer`` counts the linear layers). On the per-row path only a
         row that is ``active`` advances: an idle row's garbage step, which
-        K/V beyond a length can take, would be wrong for a state."""
+        K/V beyond a length can take, would be wrong for a state. Where
+        ``state_kernel_engages`` the recurrence gets the whole stack and
+        no slice of it is made or set back (the docstring above)."""
         kv, (state, conv) = carry
-        out, new_state, new_tail = transformer.linear_mixer(
-            cfg, h, lp, state[layer], conv[layer],
-            None if ring_active is None else ring_active.astype(jnp.int32))
-        return out, (kv, (state.at[layer].set(new_state),
-                          conv.at[layer].set(new_tail)))
+        n_valid = (None if ring_active is None
+                   else ring_active.astype(jnp.int32))
+        if state_kernel_engages(l, shardings is not None):
+            from ..ops.gated_delta import gated_delta_decode
+
+            out, state, new_tail = transformer.linear_mixer(
+                cfg, h, lp, state, conv[layer], n_valid,
+                functools.partial(gated_delta_decode, layer=layer))
+        else:
+            out, new_state, new_tail = transformer.linear_mixer(
+                cfg, h, lp, state[layer], conv[layer], n_valid)
+            state = state.at[layer].set(new_state)
+        return out, (kv, (state, conv.at[layer].set(new_tail)))
 
     carry = ((cache.k, cache.v, cache.k_scale, cache.v_scale),
              (cache.state, cache.conv))

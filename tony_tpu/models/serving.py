@@ -123,6 +123,11 @@ Design — everything stays one compiled program over static shapes:
   (one more column of its packed result, read off the first linear
   layer's state before and after the block): the counter ``state_rows``,
   which rides the bookkeep span, is the device's own account of the mask.
+  Where ``state_kernel_engages`` (one chip, the TPU) a decode step's
+  recurrence is ``gated_delta_decode`` on the cache's whole stack: the
+  live rows' tiles read and written once, in place, a frozen row's not
+  touched; ``state_rows_read`` / ``state_rows_held`` (same span) say how
+  many of the slots' states a processed block's last step streams.
 - **The device never waits on the host.** Per-slot state vectors
   (tokens/active/lengths) are DEVICE-carried: block N+1 consumes block
   N's output arrays without the host seeing them. Without stop tokens
@@ -282,8 +287,10 @@ from .generate import (
     moe_dropfree,
     prepare_decode,
     sample_token,
+    state_kernel_engages,
 )
 from ..ops.decode_attention import kv_block_k, live_kv_blocks
+from ..ops.gated_delta import live_state_rows
 from .transformer import TransformerConfig, rms_norm
 from . import transformer
 
@@ -1925,6 +1932,10 @@ class SlotServer:
         # rows whose recurrent state the processed decode blocks changed,
         # by the device's own account (0 without linear layers)
         self.state_rows = 0
+        # how far the recurrence's live-row reads engage: rows whose state
+        # a processed block's last step streamed / the slots (_recurrent)
+        self.state_rows_read = 0
+        self.state_rows_held = 0
         self.max_queue = int(max_queue)
         # ---- request durability (events/journal.py) ----
         # the journal records every accepted request's replay state
@@ -2007,6 +2018,9 @@ class SlotServer:
             1 if kv_dtype == "int8" else jnp.dtype(self.cfg.dtype).itemsize)
         self._decode_kernel = (self._shardings is None
                                and decode_kernel_engages(self.cfg, max_len))
+        # whether the decode block's recurrence reads the live rows only
+        self._state_kernel = state_kernel_engages(
+            1, self._shardings is not None)
         self.weight_dtype = weight_dtype
         self.temperature = temperature
         self.top_k = top_k
@@ -3047,6 +3061,8 @@ class SlotServer:
                     + (c.lin_conv - 1) * c.lin_channels
                     * jnp.dtype(c.dtype).itemsize),
                 "rows_advanced": self.state_rows,
+                "rows_read": self.state_rows_read,
+                "rows_held": self.state_rows_held,
             }
         if self._journal is not None:
             out["journal"] = {
@@ -4350,12 +4366,15 @@ class SlotServer:
             done = len(self._done)
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
             rows = self.state_rows
+            s_read, s_held = self.state_rows_read, self.state_rows_held
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
                               completions=len(self._done) - done,
                               kv_blocks_read=self.kv_blocks_read - read,
                               kv_blocks_ring=self.kv_blocks_ring - ring,
-                              state_rows=self.state_rows - rows)
+                              state_rows=self.state_rows - rows,
+                              state_rows_read=self.state_rows_read - s_read,
+                              state_rows_held=self.state_rows_held - s_held)
 
     def _sync(self, recs) -> tuple:
         """-> (the blocks' packed results on the host, each block's
@@ -4430,6 +4449,8 @@ class SlotServer:
                     packed[:, :-2], None, packed[:, -2],
                     packed[:, -1].astype(bool))
             self._count_kv_blocks(rec, lengths, active)
+            if self._recurrent:
+                self._count_state_rows(lengths)
             for slot in np.nonzero(self._expect_active)[0]:
                 if slot in self._stop_cancelled:
                     continue
@@ -4562,6 +4583,20 @@ class SlotServer:
             )[1].sum())
         self.kv_blocks_read += read
         self.kv_blocks_ring += ring
+
+    def _count_state_rows(self, lengths) -> None:
+        """Add one processed block to ``state_rows_read`` / ``_held``:
+        the rows whose state tiles its last decode step streams, a linear
+        layer, by the kernel's own rule (``live_state_rows`` on the rows
+        live at that step: those that took every one of the block's
+        steps) against the slots. Where the step runs ``gated_delta_step``
+        on the layer's slice (a mesh, the CPU) it reads them all."""
+        read = self.slots
+        if self._state_kernel:
+            at_last = (lengths - self._expect_len) == self.block_size
+            read = int(live_state_rows(at_last)[1])
+        self.state_rows_read += read
+        self.state_rows_held += self.slots
 
     def _complete_slot(self, slot: int, req: Request, reason: str,
                        lag: float | None) -> None:

@@ -498,7 +498,8 @@ def _mlp(cfg: TransformerConfig, h, lp):
     return out, aux
 
 
-def linear_mixer(cfg: TransformerConfig, h, lp, state, tail, n_valid=None):
+def linear_mixer(cfg: TransformerConfig, h, lp, state, tail, n_valid=None,
+                 step=None):
     """The gated-delta-rule mixer over a block of positions: h [B, L, d] ->
     (out [B, L, d], new state, new tail).
 
@@ -508,7 +509,11 @@ def linear_mixer(cfg: TransformerConfig, h, lp, state, tail, n_valid=None):
     (None = all L): only a row's first ``n_valid`` positions advance its
     state and tail, which come back as they were for a row with none. The
     rest of the block is a chunk's pad tail or an idle decode row, and its
-    ``out`` is unspecified.
+    ``out`` is unspecified. ``step`` (L = 1 only) is the caller's own
+    `gated_delta_step` where it holds the state some other way than as this
+    layer's rows: it is called as that is, and what it returns as the state
+    is handed back as it is (`generate._forward_with_cache`'s kernel on the
+    cache's whole stack).
 
     z = h w_qkv through the depthwise causal convolution and SiLU gives q',
     k', v by head; q and k are L2-normalised (q also scaled by d_k^-1/2);
@@ -542,7 +547,7 @@ def linear_mixer(cfg: TransformerConfig, h, lp, state, tail, n_valid=None):
     log_alpha = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
         ab[..., :nh] + lp["dt_bias"].astype(f32))
     if l == 1:
-        o, state = gated_delta_step(
+        o, state = (step or gated_delta_step)(
             q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], state,
             None if n_valid is None else n_valid > 0)
         o = o[:, None]
