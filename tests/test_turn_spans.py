@@ -49,7 +49,18 @@ HYBRID = transformer.TransformerConfig(
     layer_kinds=("linear", "linear", "linear", "full"),
     lin_heads=4, lin_key_dim=8, lin_value_dim=16,
 )
+# a latent-attention layer under a dense MLP and one under routed experts:
+# the experts a block's rows touched ride the bookkeep span
+ROUTED = transformer.TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
+    d_ff=128, max_seq_len=128, dtype=jnp.float32,
+    layer_kinds=("latent", "latent"), lat_q_rank=48, lat_kv_rank=32,
+    lat_nope_dim=16, lat_rope_dim=8, lat_v_dim=16, rope_interleave=True,
+    mlp_kinds=("dense", "routed"), moe_experts=16, moe_top_k=4, moe_ff=24,
+    moe_shared=1, moe_scale=2.5,
+)
 CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
+ROLLOUT = "joyai-llm-flash.rollout-closed"
 STEP_PHASES = (obs.PHASE_ADMIT, obs.PHASE_DISPATCH, obs.PHASE_SYNC,
                obs.PHASE_BOOKKEEP)
 NEW_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
@@ -96,6 +107,10 @@ def run(params, tmp_path_factory):
             transformer.init(jax.random.PRNGKey(1), HYBRID), HYBRID,
             stop_tokens=(65,), pad_id=255, **kw)
         hybrid = _drain(hybrid_server, _requests(3, seed=6))
+        routed_server = SlotServer(
+            transformer.init(jax.random.PRNGKey(2), ROUTED), ROUTED,
+            stop_tokens=(65,), pad_id=255, **kw)
+        routed = _drain(routed_server, _requests(3, seed=7))
         # (c) a predictive engine behind ServeApp: its drain syncs inside
         # the engine, the case that would nest under serve.loop.drain
         app = ServeApp(SlotServer(params, TINY, **kw))
@@ -118,8 +133,8 @@ def run(params, tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     return types.SimpleNamespace(
-        dir=trace_dir, engine=[eos, paged, hybrid], served=served,
-        hybrid_server=hybrid_server,
+        dir=trace_dir, engine=[eos, paged, hybrid, routed], served=served,
+        hybrid_server=hybrid_server, routed_server=routed_server,
         spans=host_spans.spans("serve.", trace_dir))
 
 
@@ -163,18 +178,23 @@ def test_step_phases_carry_their_counts(run):
     for counts in by_name[obs.PHASE_SYNC]:
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
-        assert set(counts) == {"tokens", "completions", "kv_blocks_read",
-                               "kv_blocks_ring", "state_rows",
-                               "state_rows_read", "state_rows_held"}
+        experts = {"experts_touched", "experts_read", "experts_held",
+                   "expert_tokens_max", "expert_tokens_mean"}
+        assert set(counts) - experts == {
+            "tokens", "completions", "kv_blocks_read", "kv_blocks_ring",
+            "state_rows", "state_rows_read", "state_rows_held"}
+        # (an engine with routed expert layers adds all five or none)
+        assert set(counts) & experts in (set(), experts)
         assert 0 <= counts["state_rows"] <= 3
         # the CPU's recurrence is ``gated_delta_step`` over every row
         # (``state_kernel_engages`` is false here); an engine without
         # linear layers holds no state
         assert counts["state_rows_read"] == counts["state_rows_held"]
         assert counts["state_rows_held"] % 3 == 0
-    done = {**run.engine[0], **run.engine[1], **run.engine[2]}
-    assert len(done) == 14
-    assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 14
+    done = {**run.engine[0], **run.engine[1], **run.engine[2],
+            **run.engine[3]}
+    assert len(done) == 17
+    assert sum(c["admitted"] for c in by_name[obs.PHASE_ADMIT]) == 17
     # the recurrent state: the rows whose state the device says a block
     # changed, at most one a token and at least one a request; none in an
     # engine without linear layers
@@ -189,7 +209,19 @@ def test_step_phases_carry_their_counts(run):
     assert sum(c["prefill_tokens"] for c in by_name[obs.PHASE_ADMIT]) > 0
     assert sum(c["tokens"] for c in by_name[obs.PHASE_BOOKKEEP]) == sum(
         len(comp.tokens) for comp in done.values())
-    assert sum(c["completions"] for c in by_name[obs.PHASE_BOOKKEEP]) == 14
+    assert sum(c["completions"] for c in by_name[obs.PHASE_BOOKKEEP]) == 17
+    # the routed experts: the device's counts of a block, on the spans of
+    # the one engine that has such layers and on none of the others'
+    spans = [c for c in by_name[obs.PHASE_BOOKKEEP] if "experts_held" in c]
+    server = run.routed_server
+    assert 0 < len(spans) < len(by_name[obs.PHASE_BOOKKEEP])
+    for name in ("experts_touched", "experts_read", "experts_held"):
+        assert sum(c[name] for c in spans) == getattr(server, name) > 0
+    assert all(c["experts_touched"] <= c["experts_read"] <= c["experts_held"]
+               and c["experts_held"] % (16 * 4) == 0 for c in spans)
+    assert max(c["expert_tokens_max"] for c in spans) \
+        == server.expert_tokens_max <= 3
+    assert server.stats()["experts"]["touched"] == server.experts_touched
     assert sum(c["blocks"] for c in by_name[obs.PHASE_SYNC]) == len(
         by_name[obs.PHASE_DISPATCH])
     assert any(comp.finish_reason == "stop" for comp in done.values())
@@ -372,10 +404,15 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     assert (BENCH / "layer_metrics" / f"{stem}.py").is_file()
     assert entry["source"] == "program_span"
     # reason-open's traced 5 s hold no arrival in one run of fourteen
-    # (0.53 requests/s): a metric of the arrivals is not listed there
+    # (0.53 requests/s), and rollout-closed's lie 13-18 s after its 32
+    # clients began answers of 768 tokens and more, so in some runs none
+    # has finished and sent again: a metric of the arrivals is listed in
+    # neither
     assert entry["workloads"] == {
         "state_rows_advanced_pct": [REASON], "state_read_pct": [REASON],
-        "submit_lock_wait_ms": [CHAT]}.get(stem, [CHAT, REASON])
+        "expert_weights_read_pct": [ROLLOUT], "experts_touched_pct": [ROLLOUT],
+        "submit_lock_wait_ms": [CHAT]}.get(
+            stem, [CHAT, REASON, ROLLOUT])
     # a program without the spans (the chip fixture; the parent commit)
     shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
     assert _read(entry, tmp_path, monkeypatch) is None
@@ -390,11 +427,14 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     if stem == "state_rows_advanced_pct":
         # of every engine's blocks x 3 slots, the hybrid engine's rows
         assert 0.0 < value < 100.0
+    if stem in ("expert_weights_read_pct", "experts_touched_pct"):
+        # of the routed engine's 16 experts a step: up to 3 rows x 4
+        assert 0.0 < value <= 75.0
 
 
-def test_new_entries_are_the_eleven_and_the_shares_are_disjoint(
+def test_new_entries_are_the_thirteen_and_the_shares_are_disjoint(
         run, monkeypatch):
-    assert len(NEW_ENTRIES) == 11
+    assert len(NEW_ENTRIES) == 13
     monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
     pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
     named = [name for names in pct.PHASES.values() for name in names]

@@ -790,7 +790,8 @@ class ServeApp:
                      stop: list | None = None,
                      logprobs: int = 0,
                      priority: str = "interactive",
-                     trace=None):
+                     trace=None,
+                     routes: bool = False):
         """Admission half of generate(): returns (request_id, event). The
         request carries ``timeout`` as its queue deadline — if it is
         still queued when the waiter would have given up, admission skips
@@ -804,7 +805,8 @@ class ServeApp:
         delivery — attachment is atomic with the submit, so no emitted
         token can slip between them; ``priority`` is the admission
         tier ("interactive" | "batch" — docs/serving.md "Paged KV &
-        admission tiers")."""
+        admission tiers"); ``routes`` asks a model with routed expert
+        layers for the experts it chose (``Completion.routes``)."""
         from ..models.serving import Request
 
         engine = self._engine_for(model)
@@ -815,7 +817,7 @@ class ServeApp:
                       deadline=time.monotonic() + timeout,
                       stop=stop, logprobs=int(logprobs or 0),
                       priority=str(priority or "interactive"),
-                      trace=trace,
+                      trace=trace, routes=bool(routes),
                       model=getattr(engine, "model", None)
                       if model is not None else None)
         ev = threading.Event()

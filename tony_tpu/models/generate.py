@@ -84,17 +84,28 @@ class KVCache(NamedTuple):
     # then hold the full-attention layers only
     state: jax.Array | None = None
     conv: jax.Array | None = None
+    # a config with latent layers only: what those layers keep of a
+    # position instead of per-head K and V, the row [c_kv | k_r] after its
+    # norm and rotation (`transformer.latent_project`), position-major
+    # (one row serves every head, so there is no head axis to put outside
+    # it): [n_latent, B, max_len, lat_kv_rank + lat_rope_dim]
+    latent: jax.Array | None = None
 
 
-def _rec_buffers(cfg: TransformerConfig, batch: int) -> dict:
+def _rec_buffers(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     """The zeroed ``state`` / ``conv`` fields of a cache of ``batch``
-    sequences ({} for a config without linear layers)."""
-    if not cfg.n_linear_layers:
-        return {}
-    state, tail = transformer.linear_state_zeros(cfg, batch)
-    n = cfg.n_linear_layers
-    return {"state": jnp.zeros((n,) + state.shape, state.dtype),
-            "conv": jnp.zeros((n,) + tail.shape, tail.dtype)}
+    sequences (none for a config without linear layers) and its ``latent``
+    field (none without latent layers)."""
+    out = {}
+    if cfg.n_linear_layers:
+        state, tail = transformer.linear_state_zeros(cfg, batch)
+        n = cfg.n_linear_layers
+        out = {"state": jnp.zeros((n,) + state.shape, state.dtype),
+               "conv": jnp.zeros((n,) + tail.shape, tail.dtype)}
+    if cfg.n_latent_layers:
+        out["latent"] = jnp.zeros(
+            (cfg.n_latent_layers, batch, max_len, cfg.lat_row_dim), cfg.dtype)
+    return out
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -113,8 +124,12 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     streaming bandwidth on v5e. Head-major, each head's [M, D] block is
     contiguous."""
     shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
-    rec = _rec_buffers(cfg, batch)
+    rec = _rec_buffers(cfg, batch, max_len)
     if kv_dtype == "int8":
+        if cfg.n_latent_layers:
+            raise ValueError(
+                "kv_dtype='int8' is not implemented for latent layers (the "
+                "cached rows are stored in the activation dtype)")
         return KVCache(
             k=jnp.zeros(shape, jnp.int8),
             v=jnp.zeros(shape, jnp.int8),
@@ -239,7 +254,8 @@ def state_kernel_engages(l_new: int, sharded: bool) -> bool:
 
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
-                      allow_kernel=True, layer_idx=None, active=None):
+                      allow_kernel=True, layer_idx=None, active=None,
+                      scale=None):
     """q: [B, L, H, D] for the L new positions (absolute offsets cache_len..
     cache_len+L-1); ck/cv: [B, kvH, max_len, D] full cache buffers (already
     containing the new keys). Scores run against the whole static buffer;
@@ -272,7 +288,9 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
     offset) contract, but only the KV blocks that hold a visible position
     are read, and a row that is not ``active`` ([B] bool, None = all)
     reads nothing and returns zeros. The einsum ignores ``active`` (an
-    idle row attends over its stale positions; its output is dropped)."""
+    idle row attends over its stale positions; its output is dropped).
+    ``scale`` (None = head_dim ** -0.5) is the scores' factor where q and
+    k are not head_dim wide (`_latent_attention`)."""
     b, l, h, d = q.shape
     kvh = ck.shape[1 if layer_idx is None else 2]
     rep = h // kvh
@@ -292,7 +310,8 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
         if k_scale is not None:
             k_scale, v_scale = k_scale[layer_idx], v_scale[layer_idx]
     q5 = q.reshape(b, l, kvh, rep, d)
-    scale = cfg.head_dim ** -0.5
+    if scale is None:
+        scale = cfg.head_dim ** -0.5
     s = jnp.einsum(
         "blgrd,bgmd->bgrlm", q5, ck.astype(cfg.dtype),
         preferred_element_type=jnp.float32,
@@ -332,6 +351,20 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
         "bgrlm,bgmd->blgrd", p.astype(cfg.dtype), cv.astype(cfg.dtype)
     )
     return out.reshape(b, l, h, d)
+
+
+def _latent_attention(cfg, q_lat, rows, cache_len, l_new, ring_offsets=None):
+    """The absorbed form's attention: q_lat [B, L, H, R] (`transformer
+    .latent_absorb`) against a latent layer's cached rows [B, M, R], which
+    already hold the new positions -> sum p row [B, L, H, R]. To
+    `_cached_attention` this is attention with ONE K/V head R wide that
+    every query head shares and whose values are its keys: the same
+    masks, lengths and ring offsets, the einsum always (the decode kernel
+    is built for per-head K and V of head_dim)."""
+    kv = rows[:, None]
+    return _cached_attention(
+        cfg, q_lat, kv, kv, cache_len, l_new, ring_offsets=ring_offsets,
+        allow_kernel=False, scale=transformer.latent_scale(cfg))
 
 
 def _prefill_cfg(cfg: TransformerConfig) -> TransformerConfig:
@@ -377,12 +410,17 @@ def _cast_decode_params(params, cfg: TransformerConfig):
     if cfg.dtype == jnp.float32:
         return params
     router = params["layers"].get("router") if cfg.n_experts > 0 else None
+    routed = params["layers"].get("routed") if cfg.n_routed_layers else None
     params = jax.tree.map(
         lambda a: a.astype(cfg.dtype) if a.dtype == jnp.float32 else a,
         params,
     )
     if router is not None:
         params["layers"]["router"] = router
+    if routed is not None:      # a routed layer's router and selection bias
+        params["layers"]["routed"] = [
+            {**cast, "router": lp["router"], "router_bias": lp["router_bias"]}
+            for cast, lp in zip(params["layers"]["routed"], routed)]
     return params
 
 
@@ -431,6 +469,11 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
             raise ValueError(
                 "weight_dtype='int8' is not implemented for a config with "
                 "layer_kinds (the int8 forms are the uniform stack's)")
+        if cfg.mlp_kinds is not None:
+            # the MLPs lie a layer in a list and are read in the one format
+            # they are made in: a fused copy of a routed layer's experts
+            # would be a second residency of nearly the whole model
+            return {}
         out = {}
         for kind, lp in params["layers"].items():
             out[kind] = {
@@ -473,7 +516,8 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
 def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
                         fused: dict | None = None, prefill: bool = False,
                         shardings: "DecodeShardings | None" = None,
-                        all_logits: bool = False, ring: tuple | None = None):
+                        all_logits: bool = False, ring: tuple | None = None,
+                        routes: bool = False):
     """Run L new tokens (absolute positions cache.length..+L-1) through the
     stack, reading/writing the cache -> (last-position logits [B, V] f32,
     new cache) — or ([B, L, V], new cache) with ``all_logits=True`` (the
@@ -521,6 +565,13 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     back with ``.at[layer].set``, the statement the kernel is tested
     against.
 
+    A latent layer (``cfg.layer_kinds``) stores its positions' rows [c_kv
+    | k_r] in ``cache.latent`` at the same shared offset and attends in the
+    absorbed form (`_latent_attention`: one read of a row for all heads),
+    over the block's own rows on a prefill's empty cache. ``routes=True``
+    adds a third result: the experts each of the L positions chose in each
+    routed layer (``cfg.mlp_kinds``), [routed layers, B, L, k] int32.
+
     ``prefill=True`` asserts the cache is EMPTY (generate's first call):
     attention over (cache + new) then reduces to causal attention within
     the block itself and runs through the model's own _attention (the
@@ -566,7 +617,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         path: that is the point of the ring layout, see the docstring),
         then read the whole stack — or, on the empty cache of a prefill,
         the block itself. ``layer`` counts the layers that hold K/V."""
-        kv, rec = carry
+        kv, rec, lat = carry
         offset = cache.length if ring_cursor is None else ring_cursor
 
         def put(buf, new):  # buf [Ly, B, kvH, M(, D)], new [B, kvH, L(, D)]
@@ -577,7 +628,8 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         kv = _store_kv(kv, k, v, put)
         if prefill:
             kr, vr = transformer._repeat_kv(cfg, k, v)
-            return transformer._attention(q, kr, vr, p_cfg, None), (kv, rec)
+            return (transformer._attention(q, kr, vr, p_cfg, None),
+                    (kv, rec, lat))
         ck, cv, ks_buf, vs_buf = kv
         attn = _cached_attention(
             cfg, q, ck, cv, cache.length, l, ks_buf, vs_buf,
@@ -587,7 +639,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             allow_kernel=shardings is None,
             layer_idx=layer, active=ring_active,
         )
-        return attn, (kv, rec)
+        return attn, (kv, rec, lat)
 
     def recur(layer, carry, h, lp):
         """A linear layer from and to the cache's ``state`` / ``conv``
@@ -596,7 +648,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         K/V beyond a length can take, would be wrong for a state. Where
         ``state_kernel_engages`` the recurrence gets the whole stack and
         no slice of it is made or set back (the docstring above)."""
-        kv, (state, conv) = carry
+        kv, (state, conv), lat = carry
         n_valid = (None if ring_active is None
                    else ring_active.astype(jnp.int32))
         if state_kernel_engages(l, shardings is not None):
@@ -609,17 +661,39 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             out, new_state, new_tail = transformer.linear_mixer(
                 cfg, h, lp, state[layer], conv[layer], n_valid)
             state = state.at[layer].set(new_state)
-        return out, (kv, (state, conv.at[layer].set(new_tail)))
+        return out, (kv, (state, conv.at[layer].set(new_tail)), lat)
 
+    def latent(layer, carry, h, lp):
+        """A latent layer (``layer`` counts them): the block's rows into
+        ``cache.latent`` at the shared offset, then the absorbed form over
+        the layer's rows (on a prefill's empty cache: over the block's)."""
+        kv, rec, lat = carry
+        q_nope, q_rope, row = transformer.latent_project(cfg, h, positions, lp)
+        offset = cache.length if ring_cursor is None else ring_cursor
+        lat = lax.dynamic_update_slice(
+            lat, row[None].astype(lat.dtype),
+            (jnp.int32(layer), zero, offset, zero))
+        q_lat = transformer.latent_absorb(cfg, q_nope, q_rope, lp)
+        if prefill:
+            o = _latent_attention(cfg, q_lat, row, zero, l)
+        else:
+            o = _latent_attention(cfg, q_lat, lat[layer], cache.length, l,
+                                  ring_offsets)
+        return transformer.latent_out(cfg, o, lp), (kv, rec, lat)
+
+    mixers = {"linear": recur, "latent": latent}
     carry = ((cache.k, cache.v, cache.k_scale, cache.v_scale),
-             (cache.state, cache.conv))
+             (cache.state, cache.conv), cache.latent)
+    picks = []
     for i in range(cfg.n_layers):
         kind, j, lp = transformer.layer_at(cfg, params["layers"], i,
                                            fused_layers)
-        x, _, carry = transformer.decoder_layer(
+        x, aux, carry = transformer.decoder_layer(
             cfg, x, positions, lp, functools.partial(attend, j), carry,
-            functools.partial(recur, j) if kind == "linear" else None)
-    (ck, cv, ks_buf, vs_buf), (state, conv) = carry
+            functools.partial(mixers[kind], j) if kind in mixers else None)
+        if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
+            picks.append(aux)
+    (ck, cv, ks_buf, vs_buf), (state, conv), lat = carry
 
     # all_logits=True projects EVERY position ([B, L, V]) — the speculative
     # verify forward needs the target's prediction after each drafted
@@ -647,7 +721,10 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             ks_buf = lax.with_sharding_constraint(ks_buf, shardings.scale)
             vs_buf = lax.with_sharding_constraint(vs_buf, shardings.scale)
     new_cache = KVCache(k=ck, v=cv, length=cache.length + l,
-                        k_scale=ks_buf, v_scale=vs_buf, state=state, conv=conv)
+                        k_scale=ks_buf, v_scale=vs_buf, state=state, conv=conv,
+                        latent=lat)
+    if routes:
+        return logits, new_cache, jnp.stack(picks)
     return logits, new_cache
 
 

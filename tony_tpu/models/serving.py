@@ -278,6 +278,7 @@ from .generate import (
     _decode_shardings,
     _forward_with_cache,
     _fuse_decode_weights,
+    _latent_attention,
     _rule_size,
     _store_kv,
     _validate_decode_mesh,
@@ -291,6 +292,7 @@ from .generate import (
 )
 from ..ops.decode_attention import kv_block_k, live_kv_blocks
 from ..ops.gated_delta import live_state_rows
+from ..parallel.routed_experts import expert_load
 from .transformer import TransformerConfig, rms_norm
 from . import transformer
 
@@ -345,7 +347,14 @@ class Request:
     speculative serving (rejected drafts never existed host-side, so
     per-token logits rows don't either). A replayed request's
     teacher-forced prefix carries ``None`` placeholders — those
-    positions were prefilled, not decoded, by this process."""
+    positions were prefilled, not decoded, by this process.
+
+    ``routes`` (a config with routed expert layers only) asks for the
+    experts every consumed position chose in every routed layer, from the
+    prefill and the decode steps alike: ``Completion.routes``. What a
+    checker needs to redo the forward with the server's own selection
+    (a near-tie between the k-th and the next expert flips on rounding,
+    and a reference left to its own routing then computes another layer)."""
     prompt: Any
     max_new_tokens: int
     temperature: float | None = None
@@ -355,6 +364,7 @@ class Request:
     resume_tokens: list | None = None
     stop: list | None = None
     logprobs: int = 0
+    routes: bool = False
     # multi-model serving: which registry entry should serve this
     # request. The engine itself is single-model (the ServeApp routes
     # by name to the right engine); the field rides the Request so the
@@ -394,6 +404,10 @@ class Completion:
     # {"token", "logprob", "top": [[ids], [logprobs]]} per token, in
     # stream order; teacher-forced resume positions carry logprob=None
     logprobs: list | None = None
+    # the experts chosen (Request.routes): int32 [positions, routed
+    # layers, k] for the positions the request consumed, in order: its
+    # prompt and every emitted token but the last (never fed)
+    routes: Any = None
 
 
 class QueueFullError(RuntimeError):
@@ -421,6 +435,15 @@ class _Admission:
     last: int = 0               # the first fed token: full context's last
     prefix_len: int = 0
     hit_path: list = field(default_factory=list)
+
+
+def routed_columns(cfg: TransformerConfig, block: int) -> int:
+    """Columns a config's routed expert layers add to a decode block's
+    packed result (`_decode_block`): the experts chosen, then three
+    counts. 0 without such layers."""
+    if not cfg.n_routed_layers:
+        return 0
+    return block * cfg.n_routed_layers * cfg.moe_top_k + 3
 
 
 def _pow2_rows(n: int) -> int:
@@ -754,6 +777,10 @@ def _insert_prefix_blocks(pool, cache, slots, blocks, chunk_idx, offsets,
     return pool, fence
 
 
+# rows of a chunk round whose latent attention is taken at once
+LATENT_ROWS_AT_ONCE = 8
+
+
 def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     """The one multi-token, per-row-position forward of the serving
     programs: ``tokens`` [K, L] run through the stack at logical positions
@@ -764,7 +791,8 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
 
     ``bufs`` = the cache's (k, v, k_scale, v_scale) buffers [layers, S,
     kvH, M(, D)], followed by its (state, conv) buffers where the config
-    has linear layers (below). A slot's buffer is a RING: logical
+    has linear layers and by its latent buffer [layers, S, M, R] where it
+    has latent ones (below). A slot's buffer is a RING: logical
     position p lives at index (p + offsets[r]) mod M, and each layer's
     K/V scatter there where ``write_ok`` [K, L] holds. Every other position — a chunk's pad tail, a
     row with nothing to write, a window overhanging the row's budget — gets
@@ -787,13 +815,22 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     trace and a multi-chunk prompt carries its state from round to round;
     a padding row's state goes nowhere (mode="drop").
 
+    A latent layer scatters its positions' rows [c_kv | k_r] into the
+    slot's ring under the same ``write_ok`` / out-of-bounds rule as K/V and
+    attends in the ABSORBED form over the row's own slot
+    (`_latent_attention`), the form the decode step uses: one statement of
+    the cached attention for both programs, and no [K, M, H, nope + v]
+    expansion of a ring that is mostly not the chunk's.
+
     No fused/quantized weights: prefill is MXU-bound (the fusions are
     decode, weight-streaming, optimizations) and the speculative verify
     must keep the raw-weight numerics. Returns (hidden states [K, L, d]
-    before the final norm, bufs)."""
+    before the final norm, bufs, the experts the positions chose in the
+    routed layers [routed layers, K, L, k], None without such layers)."""
     dt = cfg.dtype
     k_rows, l = tokens.shape
-    bufs, rec = bufs[:4], bufs[4:]
+    n_rec = 2 if cfg.n_linear_layers else 0
+    bufs, rec, lat = bufs[:4], bufs[4:4 + n_rec], bufs[4 + n_rec:]
     n_slots, m_cap = bufs[0].shape[1], bufs[0].shape[3]
     positions = starts[:, None] + jnp.arange(l)[None, :]        # [K, L]
     ring_idx = jnp.where(write_ok, (offsets[:, None] + positions) % m_cap,
@@ -804,7 +841,7 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
         read_rows = jnp.minimum(rows, n_slots - 1)  # clamp padding rows
 
     def attend(layer, carry, q, k, v):
-        bufs, rec = carry
+        bufs, rec, lat = carry
 
         def put(buf, new):
             # advanced indices [K,1] x [K,L] around the kvH slice put the
@@ -824,10 +861,10 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
         # operand would be a copy
         attn = _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
                                  ring_offsets=offsets, allow_kernel=False)
-        return attn, (bufs, rec)
+        return attn, (bufs, rec, lat)
 
     def recur(layer, carry, h, lp):
-        bufs, (state, conv) = carry
+        bufs, (state, conv), lat = carry
 
         def of_rows(buf):       # the rows' own, zero at a sequence's start
             mine = buf[layer] if read_rows is None else buf[layer][read_rows]
@@ -839,16 +876,43 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
             jnp.sum(write_ok, axis=1, dtype=jnp.int32))
         swr = dict(unique_indices=True, mode="drop")
         return out, (bufs, (state.at[layer, rows].set(new_state, **swr),
-                            conv.at[layer, rows].set(new_tail, **swr)))
+                            conv.at[layer, rows].set(new_tail, **swr)), lat)
 
+    def latent(layer, carry, h, lp):
+        bufs, rec, (lat,) = carry
+        q_nope, q_rope, row = transformer.latent_project(cfg, h, positions, lp)
+        lat = lat.at[layer, rows[:, None], ring_idx].set(
+            row.astype(lat.dtype), unique_indices=True, mode="drop")
+        mine = lat[layer] if read_rows is None else lat[layer][read_rows]
+        q_lat = transformer.latent_absorb(cfg, q_nope, q_rope, lp)
+        if k_rows > LATENT_ROWS_AT_ONCE:
+            # a wide burst's scores [K, H, L, M] float32 would be
+            # gigabytes beside weights that fill the chip: a few rows at
+            # a time (K is a power of two)
+            def some(t):
+                return _latent_attention(cfg, t[0], t[1], t[2], l, t[3])
+
+            o = lax.map(some, jax.tree.map(
+                lambda a: a.reshape((-1, LATENT_ROWS_AT_ONCE) + a.shape[1:]),
+                (q_lat, mine, starts, offsets)))
+            o = o.reshape((k_rows,) + o.shape[2:])
+        else:
+            o = _latent_attention(cfg, q_lat, mine, starts, l, offsets)
+        return transformer.latent_out(cfg, o, lp), (bufs, rec, (lat,))
+
+    mixers = {"linear": recur, "latent": latent}
     x = params["embed"].astype(dt)[tokens]
-    carry = (bufs, rec)
+    carry = (bufs, rec, lat)
+    picks = []
     for i in range(cfg.n_layers):
         kind, j, lp = transformer.layer_at(cfg, params["layers"], i)
-        x, _, carry = transformer.decoder_layer(
+        x, aux, carry = transformer.decoder_layer(
             cfg, x, positions, lp, functools.partial(attend, j), carry,
-            functools.partial(recur, j) if kind == "linear" else None)
-    return x, carry[0] + carry[1]
+            functools.partial(mixers[kind], j) if kind in mixers else None)
+        if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
+            picks.append(aux)
+    return (x, carry[0] + carry[1] + carry[2],
+            jnp.stack(picks) if picks else None)
 
 
 @functools.partial(
@@ -884,20 +948,26 @@ def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
     stay pairwise distinct, so the scatters keep unique_indices). An
     admission is then exactly one dispatch per chunk round: separate
     .at[].set pokes measured ~8ms of host dispatch work per admission, a
-    third of the whole serving loop's host cost."""
+    third of the whole serving loop's host cost.
+
+    A config with routed expert layers adds one result after the fence:
+    the experts the chunk's positions chose, [K, C, routed layers, k]
+    int32 (a request that asked for its routes reads its row of it)."""
     params = _cast_decode_params(params, cfg)
     k_rows, l = tokens.shape
     n_slots = cache.k.shape[1]
     write_ok = jnp.arange(l)[None, :] < n_valids[:, None]
-    rec = () if cache.state is None else (cache.state, cache.conv)
-    _, (ck, cv, ks_buf, vs_buf, *rec) = _rows_forward(
+    more = [f for f in ("state", "conv", "latent")
+            if getattr(cache, f) is not None]
+    _, (ck, cv, ks_buf, vs_buf, *bufs), picks = _rows_forward(
         params, cfg, tokens,
-        (cache.k, cache.v, cache.k_scale, cache.v_scale, *rec),
+        (cache.k, cache.v, cache.k_scale, cache.v_scale,
+         *(getattr(cache, f) for f in more)),
         slots, starts, offsets, write_ok)
     swr = dict(unique_indices=True, mode="drop")
     new_len = cache.length.at[slots].set(
         (starts + n_valids).astype(jnp.int32), **swr)
-    cache = KVCache(ck, cv, new_len, ks_buf, vs_buf, *rec)
+    cache = KVCache(ck, cv, new_len, ks_buf, vs_buf, **dict(zip(more, bufs)))
     # non-final rows' commit indices divert out of bounds; all indices
     # stay pairwise distinct (final rows hold distinct real slots < S,
     # the rest n_slots+row), so unique_indices holds
@@ -911,8 +981,11 @@ def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
     # dispatch-tracker fence (see _copy_prefix_blocks): chunk rounds
     # dispatch back-to-back, each donating the previous round's outputs
     fence = jnp.sum(new_len).astype(jnp.int32)
-    return (*_constrain_pool(shardings, cache, d_tokens, d_active, d_target,
-                             d_offsets, d_temps, d_topks), fence)
+    out = (*_constrain_pool(shardings, cache, d_tokens, d_active, d_target,
+                            d_offsets, d_temps, d_topks), fence)
+    if picks is not None:
+        out += (jnp.transpose(picks, (1, 2, 0, 3)),)
+    return out
 
 
 @functools.partial(
@@ -948,7 +1021,19 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     length/active columns come each step's CHOSEN-token logprob (f32
     bitcast to int32), the top-``lp_k`` token ids, and their logprobs
     (bitcast) — read off the SAME log-softmax row the token was sampled
-    from, still one transfer."""
+    from, still one transfer.
+
+    A config with routed expert layers (``cfg.mlp_kinds``) widens it by
+    `routed_columns`: the experts each row's fed token chose at each step
+    in each routed layer ([S, block * layers * k], step-major), then three
+    columns whose FIRST row carries a count of the whole block, summed
+    over its steps and routed layers: the distinct experts the rows live
+    at a step routed to, the distinct experts ANY row routed to (an idle
+    row computes on its stale token and routes it like any other: those
+    are the experts whose weights the grouped matmul streams), and the
+    most assignments of live rows that one expert got at one step of one
+    layer (a maximum, not a sum). They come before the recurrent state's
+    column."""
     params = _cast_decode_params(params, cfg)
     if build_fused:
         fused = _fuse_decode_weights(params, cfg, weight_dtype)
@@ -957,11 +1042,14 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
 
     m_cap = cache.k.shape[3]
 
+    routed = cfg.n_routed_layers > 0
+
     def step(carry, _):
         cache, tokens, active, cursor, key = carry
-        logits, new_cache = _forward_with_cache(
+        logits, new_cache, *picks = _forward_with_cache(
             params, cfg, tokens[:, None], cache, fused,
-            ring=(cursor, offsets, active), shardings=shardings)
+            ring=(cursor, offsets, active), shardings=shardings,
+            routes=routed)
         key, sub = jax.random.split(key)
         # per-ROW sampling: each slot decodes at its own request's
         # temperature (0 = greedy) and top_k, so mixed traffic shares one
@@ -995,6 +1083,13 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         tokens = jnp.where(still, nxt, tokens)
         ys = ((emitted, chosen, top_ids.astype(jnp.int32), top_vals)
               if lp_k else emitted)
+        if routed:
+            picks = picks[0][:, :, 0]               # [layers, S, k]
+            load = functools.partial(expert_load, n_experts=cfg.moe_experts)
+            live = jax.vmap(lambda c: load(c, active))(picks)
+            every = jax.vmap(load)(picks)
+            ys = (ys, (jnp.moveaxis(picks, 0, 1), jnp.sum(live > 0),
+                       jnp.sum(every > 0), jnp.max(live)))
         return (new_cache, tokens, still, (cursor + 1) % m_cap, key), ys
 
     def state_mark(cache):
@@ -1010,6 +1105,8 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     mark_in = state_mark(cache)
     (cache, tokens, active, cursor, key), ys = lax.scan(
         step, (cache, tokens, active, cursor, key), None, length=block)
+    if routed:
+        ys, (picks, touched, read, busiest) = ys
     if lp_k:
         toks, chosen, ids, vals = ys
         s = toks.shape[1]
@@ -1023,6 +1120,13 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         ]
     else:
         toks, extra = ys, []
+    if routed:
+        n_slots = picks.shape[1]                    # picks [block, S, layers, k]
+        counts = jnp.stack([jnp.sum(touched), jnp.sum(read),
+                            jnp.max(busiest)]).astype(jnp.int32)
+        extra = extra + [
+            jnp.moveaxis(picks, 0, 1).reshape(n_slots, -1),
+            jnp.zeros((n_slots, 3), jnp.int32).at[0].set(counts)]
     if mark_in is not None:
         # the rows whose recurrent state this block changed, as the device
         # has it (a frozen row's is bit for bit what it was): the last column
@@ -1118,8 +1222,8 @@ def _spec_block(params, draft_params, cache, draft_cache, d_tokens,
         there), so dropping those writes is exact, not lossy."""
         positions = lens[:, None] + jnp.arange(toks.shape[1])[None, :]
         write_ok = active[:, None] & (positions < d_target[:, None])
-        x, bufs = _rows_forward(p, p_cfg, toks, bufs, None, lens,
-                                d_offsets, write_ok)
+        x, bufs, _ = _rows_forward(p, p_cfg, toks, bufs, None, lens,
+                                   d_offsets, write_ok)
         x = rms_norm(x, p["final_norm"], p_cfg.norm_eps)
         logits = jnp.einsum("bld,dv->blv", x,
                             p["unembed"].astype(p_cfg.dtype))
@@ -1730,6 +1834,48 @@ class SlotServer:
                 raise ValueError(
                     no + f"cannot use role={role!r}: the KV handoff does "
                     "not carry a state")
+        # ---- a latent slot cache / routed expert layers ----
+        # a latent layer keeps a row [c_kv | k_r] a position where a full
+        # layer keeps K and V by head, and a routed layer's experts lie a
+        # layer in a list: the ring engine on one device with native
+        # dtypes holds and reads both; what is not built for them is
+        # refused here, each by name, as above
+        self._routed = cfg.n_routed_layers > 0
+        for what, no in (
+                (cfg.n_latent_layers > 0,
+                 "a config with latent layers (a latent slot cache) "),
+                (self._routed,
+                 "a config with routed expert layers ")):
+            if not what:
+                continue
+            if prefix_cache_blocks > 0:
+                raise ValueError(
+                    no + "cannot use prefix_cache_blocks: the prefix pool "
+                    "holds blocks of per-head K and V only")
+            if paged:
+                raise ValueError(
+                    no + "cannot use paged=True: the block pool holds "
+                    "blocks of per-head K and V only")
+            if draft is not None or spec_gamma:
+                raise ValueError(
+                    no + "cannot use a draft / spec_gamma: the verify "
+                    "forward is not built for it")
+            if kv_dtype == "int8":
+                raise ValueError(
+                    no + "cannot use kv_dtype='int8': the cached rows are "
+                    "stored in the activation dtype")
+            if weight_dtype == "int8":
+                raise ValueError(
+                    no + "cannot use weight_dtype='int8': the int8 forms "
+                    "are the uniform stack's")
+            if mesh is not None or getattr(params, "mesh", None) is not None:
+                raise ValueError(
+                    no + "cannot use a mesh: neither the latent rows nor "
+                    "the experts are sharded")
+            if role != "both":
+                raise ValueError(
+                    no + f"cannot use role={role!r}: the KV handoff "
+                    "carries blocks of per-head K and V only")
         if isinstance(params, DecodeWeights):
             if params.mesh is not None:
                 if mesh is not None and mesh != params.mesh:
@@ -1936,6 +2082,20 @@ class SlotServer:
         # a processed block's last step streamed / the slots (_recurrent)
         self.state_rows_read = 0
         self.state_rows_held = 0
+        # routed expert layers (_routed), over the processed decode blocks
+        # by the device's own account (`_decode_block`): the distinct
+        # experts the live rows routed to, summed over steps and routed
+        # layers; those whose weights the steps streamed (the grouped
+        # matmul visits the experts SOME row routed to, an idle row's
+        # stale token included); the experts held x routed layers x steps;
+        # the most assignments one expert got at one step of one layer,
+        # and all assignments of live rows (their mean an expert is
+        # stats()["experts"]["tokens_mean"])
+        self.experts_touched = 0
+        self.experts_read = 0
+        self.experts_held = 0
+        self.expert_tokens_max = 0
+        self.expert_assignments = 0
         self.max_queue = int(max_queue)
         # ---- request durability (events/journal.py) ----
         # the journal records every accepted request's replay state
@@ -2013,10 +2173,14 @@ class SlotServer:
         self.kv_dtype = kv_dtype
         # the unit of the kv_blocks_read / kv_blocks_ring counts, and
         # whether the decode block's attention reads by it at all
+        # (a latent layer's ring is one "head" of its row's width, and
+        # its attention is the einsum over the whole ring)
+        latent = self.cfg.n_latent_layers > 0
         self._kv_block_k = kv_block_k(
-            max_len, self.cfg.n_kv_heads, self.cfg.head_dim,
+            max_len, 1 if latent else self.cfg.n_kv_heads,
+            self.cfg.lat_row_dim if latent else self.cfg.head_dim,
             1 if kv_dtype == "int8" else jnp.dtype(self.cfg.dtype).itemsize)
-        self._decode_kernel = (self._shardings is None
+        self._decode_kernel = (self._shardings is None and not latent
                                and decode_kernel_engages(self.cfg, max_len))
         # whether the decode block's recurrence reads the live rows only
         self._state_kernel = state_kernel_engages(
@@ -2293,6 +2457,12 @@ class SlotServer:
         # per-slot accumulated logprob entries, in lockstep with
         # _emitted (only populated while the slot's request asked)
         self._lp_acc: list[list] = [[] for _ in range(slots)]
+        # the experts chosen, for a request that asked (Request.routes):
+        # per slot the decode steps' [n, layers, k] pieces in order; per
+        # request id the prefill's (device array, row, valid positions)
+        # pieces, read only when the completion is built
+        self._route_acc: list[list] = [[] for _ in range(slots)]
+        self._route_prefill: dict[int, list] = {}
         # slots completed by a per-request STOP match whose device-side
         # deactivation hasn't been observed yet: blocks dispatched
         # before the cancel program still show the row active, and the
@@ -2344,6 +2514,11 @@ class SlotServer:
             raise ValueError(
                 "logprobs are unavailable under speculative serving "
                 "(rejected drafts have no per-token logits rows)")
+        request.routes = bool(request.routes)
+        if request.routes and not self._routed:
+            raise ValueError(
+                "routes were asked of a config without routed expert "
+                "layers: there are none to report")
         resume = request.resume_tokens
         if resume is not None:
             resume = [int(t) for t in np.asarray(resume, np.int32)]
@@ -2869,6 +3044,7 @@ class SlotServer:
         terminal)."""
         slot = self._slot_of.pop(request_id, None)
         self._inflight.discard(request_id)
+        self._route_prefill.pop(request_id, None)
         if self._paged and slot is not None:
             # the id still OWNED the slot: a predictive re-admission
             # would have superseded the _slot_of mapping (and freed the
@@ -2987,6 +3163,22 @@ class SlotServer:
                 "state": np.asarray(cache.state[layer]),
                 "conv": np.asarray(cache.conv[layer])}
 
+    def slot_latent_rows(self, layer: int = 0) -> dict:
+        """Host copies of what every slot holds of latent layer ``layer``
+        (counted among the latent layers), in LOGICAL order: ``length``
+        [S] and ``rows`` [S, max_len, R], row p of a slot its position p's
+        [c_kv | k_r] (the ring turned back by the slot's offset; rows at
+        and past the length are whatever the ring held). As `slot_states`:
+        call it where nothing else steps the engine."""
+        if not self.cfg.n_latent_layers:
+            raise ValueError("the config has no latent layers")
+        cache = self._cache
+        idx = (np.arange(self.max_len)[None, :]
+               + np.asarray(self._d_offsets)[:, None]) % self.max_len
+        rows = np.asarray(cache.latent[layer])
+        return {"length": np.asarray(cache.length),
+                "rows": np.take_along_axis(rows, idx[..., None], axis=1)}
+
     def stats(self) -> dict:
         """Serving-load + prefix-cache counters, one flat snapshot (the
         ServeApp /stats payload and MetricsAccumulator feed). Token
@@ -3063,6 +3255,18 @@ class SlotServer:
                 "rows_advanced": self.state_rows,
                 "rows_read": self.state_rows_read,
                 "rows_held": self.state_rows_held,
+            }
+        if self._routed:
+            c = self.cfg
+            out["experts"] = {
+                "held": c.experts_held[1], "of": c.moe_experts,
+                "routed_layers": c.n_routed_layers,
+                "touched": self.experts_touched,
+                "read": self.experts_read,
+                "held_steps": self.experts_held,
+                "tokens_max": self.expert_tokens_max,
+                "tokens_mean": self._expert_tokens_mean(
+                    self.expert_assignments, self.experts_held),
             }
         if self._journal is not None:
             out["journal"] = {
@@ -3418,7 +3622,7 @@ class SlotServer:
         dispatch (donated), the target's and the draft's alike."""
         (cache, self._d_tokens, self._d_active, self._d_target,
          self._d_offsets, self._d_temps, self._d_topks,
-         fence) = _prefill_batch(
+         fence, *picks) = _prefill_batch(
             self._draft_params if draft else self._params, cache,
             self._d_tokens, self._d_active, self._d_target,
             self._d_offsets, self._d_temps, self._d_topks, *rows,
@@ -3427,6 +3631,9 @@ class SlotServer:
         self.admission_dispatches += 1
         self.dispatch_tracker.track(
             "draft_prefill" if draft else "prefill", fence)
+        # the experts the chunk's positions chose (routed layers only):
+        # left on the device for the requests that asked (_prefill_burst)
+        self._prefill_picks = picks[0] if picks else None
         return cache
 
     def _prefill_burst(self, admissions) -> None:
@@ -3436,6 +3643,17 @@ class SlotServer:
             rows, n_tokens = self._pack_rows(admissions, r)
             self._cache = self._dispatch_prefill(self._cache, rows)
             self.prefill_tokens_computed += n_tokens
+            asked = [(row, adm) for row, adm in enumerate(admissions)
+                     if adm.req.routes and r < len(adm.chunk_starts)]
+            if asked:
+                # on its way to the host now, read at the completion: a
+                # transfer asked for then would wait for the blocks in flight
+                self._prefill_picks.copy_to_host_async()
+            for row, adm in asked:
+                nv = min(self.prefill_chunk,
+                         adm.body.size - adm.chunk_starts[r])
+                self._route_prefill.setdefault(adm.req.id, []).append(
+                    (self._prefill_picks, row, max(0, nv)))
 
     def _prefill_draft(self, admissions) -> None:
         """Speculative serving: the draft model needs the same context
@@ -4126,6 +4344,7 @@ class SlotServer:
                                 "top": None}
                                for t in (req.resume_tokens or ())]
                               if req.logprobs else [])
+        self._route_acc[slot] = []
         # re-arm busy at the replay position: when this slot was
         # re-admitted before its PREDECESSOR's completion was processed,
         # that processing (replayed just before this admit) cleared
@@ -4159,6 +4378,7 @@ class SlotServer:
         self._requests[slot] = None
         self._emitted[slot] = []
         self._lp_acc[slot] = []
+        self._route_acc[slot] = []
         self._host_busy[slot] = False
         self._expect_active[slot] = False
         self._release_request(rid)
@@ -4206,7 +4426,9 @@ class SlotServer:
                                "offsets": self._np_offs.copy(),
                                "w": self.block_size + 2
                                + (self.block_size * (2 * lp_k + 1)
-                                  if lp_k else 0) + self._recurrent,
+                                  if lp_k else 0)
+                               + routed_columns(self.cfg, self.block_size)
+                               + self._recurrent,
                                "lp_k": lp_k,
                                "spec_gamma": None})
         if self._predictive:            # exact: no EOS can surprise us
@@ -4367,6 +4589,10 @@ class SlotServer:
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
+            e_touched, e_read, e_held, e_asg = (
+                self.experts_touched, self.experts_read, self.experts_held,
+                self.expert_assignments)
+            self._span_tokens_max = 0
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
                               completions=len(self._done) - done,
@@ -4375,6 +4601,22 @@ class SlotServer:
                               state_rows=self.state_rows - rows,
                               state_rows_read=self.state_rows_read - s_read,
                               state_rows_held=self.state_rows_held - s_held)
+            if self._routed:
+                held = self.experts_held - e_held
+                span.set_metadata(
+                    experts_touched=self.experts_touched - e_touched,
+                    experts_read=self.experts_read - e_read,
+                    experts_held=held,
+                    expert_tokens_max=self._span_tokens_max,
+                    expert_tokens_mean=self._expert_tokens_mean(
+                        self.expert_assignments - e_asg, held))
+
+    def _expert_tokens_mean(self, assignments: int, held_steps: int) -> float:
+        """An expert's mean load: ``assignments`` over the (expert, layer,
+        step) places of ALL the experts, held here or not, that
+        ``held_steps`` (held x routed layers x steps) stands for."""
+        places = held_steps * self.cfg.moe_experts // self.cfg.experts_held[1]
+        return assignments / places if places else 0.0
 
     def _sync(self, recs) -> tuple:
         """-> (the blocks' packed results on the host, each block's
@@ -4419,6 +4661,23 @@ class SlotServer:
             if self._recurrent:     # the decode block's last column
                 self.state_rows += int(packed[:, -1].sum())
                 packed = packed[:, :-1]
+            picks = None
+            if self._routed:
+                # the routed layers' columns (`_decode_block`): the experts
+                # chosen, then the block's three counts in their first row
+                n = routed_columns(self.cfg, self.block_size)
+                touched, streamed, busiest = (int(v) for v in packed[0, -3:])
+                picks = packed[:, -n:-3].reshape(
+                    self.slots, self.block_size, self.cfg.n_routed_layers,
+                    self.cfg.moe_top_k)
+                packed = packed[:, :-n]
+                self.experts_touched += touched
+                self.experts_read += streamed
+                self.experts_held += (self.cfg.experts_held[1]
+                                      * self.cfg.n_routed_layers
+                                      * self.block_size)
+                self.expert_tokens_max = max(self.expert_tokens_max, busiest)
+                self._span_tokens_max = max(self._span_tokens_max, busiest)
             lag = lags[i]
             gamma = rec.get("spec_gamma")
             lp_k = rec.get("lp_k", 0) or 0
@@ -4489,6 +4748,14 @@ class SlotServer:
                 n_new = len(new)
                 fed += n_new
                 self._emitted[slot].extend(new)
+                if picks is not None:
+                    # a row that emitted n tokens took the block's first n
+                    # steps (a stop match may have cut ``new`` shorter)
+                    self.expert_assignments += (
+                        n * self.cfg.n_routed_layers * self.cfg.moe_top_k)
+                    if req is not None and req.routes:
+                        self._route_acc[slot].append(
+                            picks[slot, :n].copy())
                 if (n_new and lp_chosen is not None and req is not None
                         and req.logprobs):
                     k = req.logprobs
@@ -4623,15 +4890,30 @@ class SlotServer:
         lps = self._lp_acc[slot] if req.logprobs else None
         if lps is not None and len(lps) > len(out):
             lps = lps[:len(out)]
+        routes = None
+        if req.routes:
+            # the prefill's pieces come off the device only now (each an
+            # array some earlier dispatch left there), then the decode
+            # steps' in order: one row a position the request consumed
+            routes = np.concatenate(
+                [np.asarray(dev)[row, :nv] for dev, row, nv
+                 in self._route_prefill.get(req.id, ())]
+                + self._route_acc[slot]
+                + [np.zeros((0, self.cfg.n_routed_layers,
+                             self.cfg.moe_top_k), np.int32)])
+            # a per-request stop match ends the answer short of what the
+            # device had consumed
+            routes = routes[:len(req.prompt) + len(out) - 1]
         self._done[req.id] = Completion(
             req.id, out, reason,
             trace=self._finish_trace(
                 req.id, "finished", n_tokens=len(out), reason=reason),
-            logprobs=lps)
+            logprobs=lps, routes=routes)
         self._finish_stream(req.id)
         self._requests[slot] = None
         self._emitted[slot] = []
         self._lp_acc[slot] = []
+        self._route_acc[slot] = []
         self._host_busy[slot] = False
         self._release_request(req.id)
 

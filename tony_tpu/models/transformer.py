@@ -31,7 +31,9 @@ from ..parallel.expert import load_balancing_loss, moe_ffn
 from ..parallel.ring_attention import reference_attention
 
 
-LAYER_KINDS = ("full", "linear")
+LAYER_KINDS = ("full", "linear", "latent")
+# the MLP's form a layer (cfg.mlp_kinds): SwiGLU at d_ff, or routed experts
+MLP_KINDS = ("dense", "routed")
 # under the root of the linear mixer's L2 normalisation of q and k
 LIN_L2_EPS = 1e-6
 
@@ -106,6 +108,33 @@ class TransformerConfig:
     qk_norm: bool = False
     # "pre": x + Mixer(Norm(x)) (Llama); "post": x + Norm(Mixer(x))
     norm_order: str = "pre"
+    # "latent" layers (multi-head latent attention, `latent_mixer`): q
+    # through a bottleneck of lat_q_rank, a head's q and k [lat_nope_dim |
+    # lat_rope_dim] and its v lat_v_dim wide; what a position keeps is
+    # [c_kv | k_r], lat_kv_rank + lat_rope_dim values for all heads, from
+    # which a per-head matrix expands k_nope and v
+    lat_q_rank: int = 0
+    lat_kv_rank: int = 0
+    lat_nope_dim: int = 0
+    lat_rope_dim: int = 0
+    lat_v_dim: int = 0
+    # rotate the adjacent pairs (2i, 2i+1) in place, not the halves
+    rope_interleave: bool = False
+    # the MLP's form a layer: None = d_ff SwiGLU (or the n_experts toy)
+    # everywhere; else one of MLP_KINDS per layer (needs layer_kinds), the
+    # MLPs' parameters then a LIST a layer under params["layers"][form].
+    # "routed" is parallel/routed_experts.py: moe_experts sigmoid-scored
+    # experts of width moe_ff, moe_top_k a token chosen on score + bias and
+    # weighted by the scores alone times moe_scale, moe_shared shared
+    # experts beside them; moe_held = (first, count) is the range of the
+    # experts this chip holds (None = all)
+    mlp_kinds: tuple | None = None
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_ff: int = 0
+    moe_shared: int = 0
+    moe_scale: float = 1.0
+    moe_held: tuple | None = None
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -114,6 +143,29 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_kinds must name one of {LAYER_KINDS} for each of "
                 f"the {self.n_layers} layers, got {kinds!r}")
+        forms = self.mlp_kinds
+        if forms is not None and (kinds is None or len(forms) != self.n_layers
+                                  or set(forms) - set(MLP_KINDS)):
+            raise ValueError(
+                f"mlp_kinds must name one of {MLP_KINDS} for each of the "
+                f"{self.n_layers} layers of a config with layer_kinds, got "
+                f"{forms!r}")
+        if self.n_routed_layers and (
+                self.n_experts or min(self.moe_experts, self.moe_top_k,
+                                      self.moe_ff) < 1
+                or self.moe_top_k > self.moe_experts):
+            raise ValueError(
+                "routed layers need moe_experts >= moe_top_k >= 1, moe_ff "
+                "and n_experts == 0")
+        if self.n_latent_layers and (
+                not self.causal or self.rope_theta is None
+                or self.lat_rope_dim % 2
+                or min(self.lat_q_rank, self.lat_kv_rank, self.lat_nope_dim,
+                       self.lat_rope_dim, self.lat_v_dim) < 1):
+            raise ValueError(
+                "latent layers need lat_q_rank, lat_kv_rank, lat_nope_dim, "
+                "an even lat_rope_dim, lat_v_dim, a rope_theta and a causal "
+                "model")
         if self.norm_order not in ("pre", "post"):
             raise ValueError(f"norm_order must be 'pre' or 'post', got "
                              f"{self.norm_order!r}")
@@ -134,9 +186,27 @@ class TransformerConfig:
         return (self.layer_kinds or ()).count("linear")
 
     @property
+    def n_latent_layers(self) -> int:
+        return (self.layer_kinds or ()).count("latent")
+
+    @property
+    def n_routed_layers(self) -> int:
+        return (self.mlp_kinds or ()).count("routed")
+
+    @property
     def n_attn_layers(self) -> int:
-        """Layers that hold K/V: all of them but the linear ones."""
-        return self.n_layers - self.n_linear_layers
+        """Layers that hold K/V: the full-attention ones."""
+        return self.n_layers - self.n_linear_layers - self.n_latent_layers
+
+    @property
+    def lat_row_dim(self) -> int:
+        """What a position keeps for a latent layer: [c_kv | k_r]."""
+        return self.lat_kv_rank + self.lat_rope_dim
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first, count) of the routed experts this chip holds."""
+        return self.moe_held or (0, self.moe_experts)
 
     @property
     def lin_channels(self) -> int:
@@ -206,10 +276,72 @@ def _linear_stack(keys, cfg: TransformerConfig, n: int) -> dict:
     return out
 
 
+def _latent_stack(keys, cfg: TransformerConfig, n: int) -> dict:
+    """The latent-attention mixer's parameters (`latent_project` and its
+    neighbours read them): ``wq_a`` [d, q_rank] and ``wq_b`` [q_rank, H,
+    nope + rope] around the norm ``q_a_norm``; ``wkv_a`` [d, kv_rank +
+    rope] (the latent, then the one rotary key all heads share) with
+    ``kv_a_norm`` [kv_rank]; ``wkv_b`` [kv_rank, H, nope + v] (a head's
+    k_nope then its v: the absorbed form contracts q and the output with
+    its two halves, the expanded form the latent with all of it); ``wo``
+    [H, v, d]."""
+    pd, d, h = cfg.param_dtype, cfg.d_model, cfg.n_heads
+    qr, kr = cfg.lat_q_rank, cfg.lat_kv_rank
+    nope, rp, v = cfg.lat_nope_dim, cfg.lat_rope_dim, cfg.lat_v_dim
+    out = {"attn_norm": jnp.ones((n, d), pd), "mlp_norm": jnp.ones((n, d), pd),
+           "q_a_norm": jnp.ones((n, qr), pd),
+           "kv_a_norm": jnp.ones((n, kr), pd)}
+    for name, shape, fan_in in (
+            ("wq_a", (d, qr), d), ("wq_b", (qr, h, nope + rp), qr),
+            ("wkv_a", (d, kr + rp), d), ("wkv_b", (kr, h, nope + v), kr),
+            ("wo", (h, v, d), h * v)):
+        out[name] = _dense_init(next(keys), (n,) + shape, fan_in, pd)
+    return out
+
+
+def _routed_mlp(keys, cfg: TransformerConfig) -> dict:
+    """ONE routed layer's MLP (parallel/routed_experts.py reads it): the
+    float32 ``router`` [d, E] and selection bias ``router_bias`` [E] (a
+    zero buffer that training moves), the experts held ``we_gu`` [count,
+    d, 2 f] (gate then up) and ``we_down`` [count, f, d], the shared
+    experts as one SwiGLU ``ws_gu`` [d, 2 S f] / ``ws_down`` [S f, d]."""
+    pd, d, f = cfg.param_dtype, cfg.d_model, cfg.moe_ff
+    count = cfg.experts_held[1]
+    out = {"router": _dense_init(next(keys), (d, cfg.moe_experts), d,
+                                 jnp.float32),
+           "router_bias": jnp.zeros((cfg.moe_experts,), jnp.float32),
+           "we_gu": _dense_init(next(keys), (count, d, 2 * f), d, pd),
+           "we_down": _dense_init(next(keys), (count, f, d), f, pd)}
+    if cfg.moe_shared:
+        sf = cfg.moe_shared * f
+        out["ws_gu"] = _dense_init(next(keys), (d, 2 * sf), d, pd)
+        out["ws_down"] = _dense_init(next(keys), (sf, d), sf, pd)
+    return out
+
+
+def _mlp_lists(key, cfg: TransformerConfig) -> dict:
+    """The MLPs of a config with ``mlp_kinds``: {form: [a layer's params,
+    ...]} over that form's layers in the model's order. A LIST a layer,
+    not a stack: nothing scans over them, and a routed layer's experts
+    are the operand of a grouped-matmul kernel, to which a slice of a
+    stack would be a copy of the layer a step."""
+    out: dict = {}
+    for i, form in enumerate(cfg.mlp_kinds):
+        keys = iter(jax.random.split(jax.random.fold_in(key, i), 8))
+        if form == "routed":
+            one = _routed_mlp(keys, cfg)
+        else:
+            one = jax.tree.map(lambda a: a[0], _mlp_stack(keys, cfg, 1))
+        out.setdefault(form, []).append(one)
+    return out
+
+
 def init(key: jax.Array, cfg: TransformerConfig) -> dict:
     """Build the parameter pytree. Layer params are stacked [n_layers, ...];
     with ``cfg.layer_kinds`` one such stack per kind, each over that
-    kind's layers in the model's order."""
+    kind's layers in the model's order; with ``cfg.mlp_kinds`` the stacks
+    hold the mixers and the norms only and the MLPs lie beside them
+    (`_mlp_lists`)."""
     pd = cfg.param_dtype
     keys = iter(jax.random.split(key, 32 if cfg.layer_kinds else 16))
     n_full, n_linear = cfg.n_attn_layers, cfg.n_linear_layers
@@ -217,12 +349,23 @@ def init(key: jax.Array, cfg: TransformerConfig) -> dict:
     # attention, unembedding, MLP: the order the uniform tree draws its keys
     attn = _attention_stack(keys, cfg, n_full)
     unembed = _dense_init(next(keys), (cfg.d_model, cfg.vocab_size), cfg.d_model, pd)
-    layers = {**attn, **_mlp_stack(keys, cfg, n_full)}
+    by_layer = cfg.mlp_kinds is not None
+    mlp = (lambda n: {}) if by_layer else (
+        lambda n: _mlp_stack(keys, cfg, n))
+    layers = {**attn, **mlp(n_full)}
     if cfg.layer_kinds is not None:
         layers = {"full": layers} if n_full else {}
         if n_linear:
             layers["linear"] = {**_linear_stack(keys, cfg, n_linear),
-                                **_mlp_stack(keys, cfg, n_linear)}
+                                **mlp(n_linear)}
+        if cfg.n_latent_layers:
+            # keys of its own: the other kinds draw what they always drew
+            lkeys = iter(jax.random.split(jax.random.fold_in(key, 1), 8))
+            layers["latent"] = {
+                **_latent_stack(lkeys, cfg, cfg.n_latent_layers),
+                **mlp(cfg.n_latent_layers)}
+        if by_layer:
+            layers.update(_mlp_lists(jax.random.fold_in(key, 2), cfg))
     return {"embed": embed, "layers": layers,
             "final_norm": jnp.ones((cfg.d_model,), pd), "unembed": unembed}
 
@@ -252,6 +395,9 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         }
+    by_layer = cfg.mlp_kinds is not None
+    if by_layer:        # the MLPs lie beside the stacks, a list a layer
+        dense, mlp = {k: v[1:] for k, v in mlp.items()}, {}
     layers: dict = {**attn, **mlp}
     if cfg.layer_kinds is not None:
         # the linear mixer's heads are not split over a mesh yet (no
@@ -265,9 +411,32 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
             "w_ab": ("layers", "embed", None), "wo": ("layers", None, "embed"),
             **mlp,
         }
+        # the latent mixer's heads and the routed experts are not split
+        # over a mesh either (the serving engine refuses one)
+        latent = {
+            "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+            "q_a_norm": ("layers", None), "kv_a_norm": ("layers", None),
+            "wq_a": ("layers", "embed", None),
+            "wq_b": ("layers", None, None, None),
+            "wkv_a": ("layers", "embed", None),
+            "wkv_b": ("layers", None, None, None),
+            "wo": ("layers", None, None, "embed"),
+            **mlp,
+        }
         layers = {kind: tree for kind, tree in
-                  (("full", layers), ("linear", linear))
+                  (("full", layers), ("linear", linear), ("latent", latent))
                   if kind in cfg.layer_kinds}
+        if by_layer:
+            routed = {"router": ("embed", None), "router_bias": (None,),
+                      "we_gu": (None, "embed", None),
+                      "we_down": (None, None, "embed")}
+            if cfg.moe_shared:
+                routed.update({"ws_gu": ("embed", None),
+                               "ws_down": (None, "embed")})
+            for form, one in (("dense", dense), ("routed", routed)):
+                n = cfg.mlp_kinds.count(form)
+                if n:
+                    layers[form] = [dict(one) for _ in range(n)]
     return {
         "embed": ("vocab", "embed"),
         "layers": layers,
@@ -284,8 +453,10 @@ def rms_norm(x, weight, eps=1e-6):
     return (x32 * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
 
 
-def rope(x, positions, theta, scaling=None):
-    """Rotary position embedding; x: [B, L, H, D].
+def rope(x, positions, theta, scaling=None, interleave=False):
+    """Rotary position embedding; x: [B, L, H, D]. ``interleave`` rotates
+    the adjacent pairs (2i, 2i+1) in place instead of pairing dim i with
+    dim i + D/2 (the frequencies are the same).
 
     ``scaling`` — ("llama3", factor, low_freq_factor, high_freq_factor,
     original_max_position_embeddings) — applies Llama-3.x's context
@@ -313,6 +484,11 @@ def rope(x, positions, theta, scaling=None):
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, L, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -469,9 +645,29 @@ def _attn_out(cfg: TransformerConfig, attn, lp):
 
 
 def _mlp(cfg: TransformerConfig, h, lp):
-    """Post-attention MLP (dense SwiGLU or MoE) -> (out, aux_loss)."""
+    """Post-attention MLP (dense SwiGLU or MoE) -> (out, aux): the toy
+    MoE's load-balancing loss, 0 for a dense MLP, and for a routed layer
+    (``lp`` carries ``router_bias``: cfg.mlp_kinds) the experts its tokens
+    chose [B, L, k] int32, which the serving programs hand on to whoever
+    asked for them."""
     dt = cfg.dtype
     aux = jnp.float32(0)
+    if "router_bias" in lp:
+        from ..parallel.routed_experts import route, routed_ffn
+
+        b, l, d = h.shape
+        flat = h.reshape(b * l, d)
+        chosen, w = route(flat, lp["router"], lp["router_bias"],
+                          top_k=cfg.moe_top_k, scale=cfg.moe_scale)
+        out = routed_ffn(flat, chosen, w, lp["we_gu"].astype(dt),
+                         lp["we_down"].astype(dt), held=cfg.experts_held)
+        if cfg.moe_shared:      # what every chip computes alike
+            sf = cfg.moe_shared * cfg.moe_ff
+            gu = jnp.einsum("td,de->te", flat, lp["ws_gu"].astype(dt))
+            out = out + jnp.einsum(
+                "tf,fd->td", jax.nn.silu(gu[:, :sf]) * gu[:, sf:],
+                lp["ws_down"].astype(dt))
+        return out.reshape(b, l, d), chosen.reshape(b, l, -1)
     if cfg.n_experts > 0:
         b, l, d = h.shape
         flat = h.reshape(b * l, d)
@@ -576,11 +772,79 @@ def linear_state_zeros(cfg: TransformerConfig, batch: int):
             jnp.zeros((batch, cfg.lin_conv - 1, cfg.lin_channels), cfg.dtype))
 
 
+def latent_project(cfg: TransformerConfig, h, positions, lp):
+    """A latent layer's projections of a block h [B, L, d] -> (q_nope [B,
+    L, H, nope], q_rope [B, L, H, rope] rotated, row [B, L, kv_rank +
+    rope]). ``row`` = [c_kv | k_r] is ALL a position keeps for the layer:
+    the latent after its norm and the one rotary key every head shares
+    after its rotation: what the serving cache stores, and what both
+    forms of the attention below read.
+
+    c_q = RMSNorm(h W_qa), q = c_q W_qb by head; [c_kv | k_r] = h W_kva,
+    c_kv = RMSNorm(c_kv). The expanded form (`latent_expand`: training,
+    and the statement the absorbed one is tested against) makes a head's
+    k_nope and v from c_kv with ``wkv_b`` and attends as any attention
+    does, q and k at nope + rope wide, v at lat_v_dim. The absorbed form
+    (`latent_absorb`, `latent_out`: every serving program) never makes
+    them: q_nope goes through W_kvb^K^T to the latent's width, scores and
+    the weighted sum are taken against the rows themselves, one read of a
+    row for all heads, and the sum goes through W_kvb^V. The same numbers
+    but for rounding."""
+    dt, eps = cfg.dtype, cfg.norm_eps
+    nope, kr = cfg.lat_nope_dim, cfg.lat_kv_rank
+    turn = functools.partial(rope, positions=positions, theta=cfg.rope_theta,
+                             scaling=cfg.rope_scaling,
+                             interleave=cfg.rope_interleave)
+    c_q = rms_norm(jnp.einsum("bld,dr->blr", h, lp["wq_a"].astype(dt)),
+                   lp["q_a_norm"], eps)
+    q = jnp.einsum("blr,rhk->blhk", c_q, lp["wq_b"].astype(dt))
+    kv = jnp.einsum("bld,dc->blc", h, lp["wkv_a"].astype(dt))
+    c_kv = rms_norm(kv[..., :kr], lp["kv_a_norm"], eps)
+    k_r = turn(kv[:, :, None, kr:])[:, :, 0]
+    return (q[..., :nope], turn(q[..., nope:]),
+            jnp.concatenate([c_kv, k_r], axis=-1))
+
+
+def latent_scale(cfg: TransformerConfig) -> float:
+    return (cfg.lat_nope_dim + cfg.lat_rope_dim) ** -0.5
+
+
+def latent_expand(cfg: TransformerConfig, q_nope, q_rope, row, lp):
+    """The expanded form's q, k [B, L, H, nope + rope] and v [B, L, H, v]
+    of a block, from its own rows."""
+    nope, kr = cfg.lat_nope_dim, cfg.lat_kv_rank
+    kvb = jnp.einsum("blc,chk->blhk", row[..., :kr],
+                     lp["wkv_b"].astype(cfg.dtype))
+    k_r = jnp.broadcast_to(row[:, :, None, kr:],
+                           q_rope.shape[:2] + (cfg.n_heads, cfg.lat_rope_dim))
+    return (jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([kvb[..., :nope], k_r], axis=-1),
+            kvb[..., nope:])
+
+
+def latent_absorb(cfg: TransformerConfig, q_nope, q_rope, lp):
+    """The absorbed form's query, against the rows themselves: [q_nope
+    W_kvb^K^T | q_rope] [B, L, H, kv_rank + rope]."""
+    w_k = lp["wkv_b"].astype(cfg.dtype)[..., :cfg.lat_nope_dim]
+    return jnp.concatenate(
+        [jnp.einsum("blhn,chn->blhc", q_nope, w_k), q_rope], axis=-1)
+
+
+def latent_out(cfg: TransformerConfig, o_lat, lp):
+    """The absorbed form's end: o_lat [B, L, H, >= kv_rank] = sum p row
+    (its first kv_rank values are sum p c_kv; a caller that weighed whole
+    rows hands the rotary part along, unread) through W_kvb^V and wo."""
+    w_v = lp["wkv_b"].astype(cfg.dtype)[..., cfg.lat_nope_dim:]
+    o = jnp.einsum("blhc,chv->blhv", o_lat[..., :cfg.lat_kv_rank], w_v)
+    return jnp.einsum("blhv,hvd->bld", o, lp["wo"].astype(cfg.dtype))
+
+
 def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
     """Layer ``i`` of the model -> (its kind, its index among the layers of
     that kind, its params with the stack dim removed). ``extra`` is a second
     tree of per-layer leaves laid out as ``layers`` (the fused decode forms),
-    merged over it."""
+    merged over it. With ``cfg.mlp_kinds`` the layer's MLP comes from its
+    form's list (`_mlp_lists`)."""
     kinds = cfg.layer_kinds
     if kinds is None:
         kind, j, stack = "full", i, {**layers, **(extra or {})}
@@ -588,7 +852,11 @@ def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
         kind = kinds[i]
         j = kinds[:i].count(kind)
         stack = {**layers[kind], **(extra or {}).get(kind, {})}
-    return kind, j, jax.tree.map(lambda a: a[j], stack)
+    lp = jax.tree.map(lambda a: a[j], stack)
+    if cfg.mlp_kinds is not None:
+        form = cfg.mlp_kinds[i]
+        lp = {**lp, **layers[form][cfg.mlp_kinds[:i].count(form)]}
+    return kind, j, lp
 
 
 def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
@@ -606,7 +874,9 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
     threads through the layers (None in training; the cache buffers when
     decoding). ``recur(kv, h, lp) -> (out [B, L, d], kv)`` is the same
     decision for a linear layer: where its state and convolution tail come
-    from and go to around `linear_mixer`. Returns (x, aux_loss, kv)."""
+    from and go to around `linear_mixer`; a latent layer's mixer is handed
+    in the same way (`latent_project` and its neighbours, around the
+    caller's rows). Returns (x, aux, kv), ``aux`` as `_mlp` has it."""
     pre = cfg.norm_order == "pre"
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
     if recur is not None:
@@ -638,8 +908,19 @@ def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
                                  *linear_state_zeros(cfg, h.shape[0]))
         return out, kv
 
-    x, aux, _ = decoder_layer(cfg, x, positions, lp, attend,
-                              recur=recur if kind == "linear" else None)
+    def latent(kv, h, lp):
+        # the expanded form through XLA: q and k are wider than v, which
+        # the flash kernels do not take (train/step.py refuses the kind)
+        q, k, v = latent_expand(
+            cfg, *latent_project(cfg, h, positions, lp), lp)
+        attn = reference_attention(q, k, v, causal=True,
+                                   scale=latent_scale(cfg))
+        return jnp.einsum("blhv,hvd->bld", attn,
+                          lp["wo"].astype(cfg.dtype)), kv
+
+    x, aux, _ = decoder_layer(
+        cfg, x, positions, lp, attend,
+        recur={"linear": recur, "latent": latent}.get(kind))
     return x, aux
 
 

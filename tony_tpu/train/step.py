@@ -76,6 +76,11 @@ def create_train_step(
     snapshots to host synchronously before overlapping the write, never
     by handing live device arrays to a background saver.
     """
+    if cfg.n_latent_layers:
+        raise ValueError(
+            "'latent' layers cannot be trained here: a head's q and k are "
+            "wider than its v, which the flash kernels (forward and "
+            "backward) do not take; the kind is served only")
     rules = dict(rules if rules is not None else shlib.FSDP_TP_RULES)
     if sp_impl is None:
         want_sp = (
