@@ -200,26 +200,38 @@ def test_gated_delta_decode_stays_in_place_under_a_scan(one_chip):
     assert made and set(made) <= {"parameter", "get-tuple-element"}, made
 
 
-@pytest.mark.parametrize("tokens", [32, 1024])
-def test_routed_expert_layer_compiles_for_v5e(one_chip, tokens):
+@pytest.mark.parametrize("tokens, kernel", [
+    (32, False), (1024, False), (32, True)])
+def test_routed_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
+                                              kernel):
     """The routed expert layer (parallel/routed_experts.py) at the widths
     the benchmark serves, a decode step's 32 tokens and a prefill chunk
     round's 1024: XLA lowers ``jax.lax.ragged_dot`` to its own grouped
-    matmul (a ``tpu_custom_call``), with no ``[experts, capacity, d]``
-    buffer and no copy of an expert matrix among the temporaries."""
+    matmul (a ``tpu_custom_call``), and a decode step's ``kernel`` path
+    is two Mosaic kernels (whole-K, whole-N weight blocks in VMEM), each
+    with no ``[experts, capacity, d]`` buffer and no copy of an expert
+    matrix among the temporaries."""
     from tony_tpu.parallel.routed_experts import route, routed_ffn
 
     e, d, f, k = 256, 2048, 768, 8
+    # the kernel asks the backend whether to interpret: a TPU's answer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def layer(x, router, bias, w_gu, w_down):
         chosen, w = route(x, router, bias, top_k=k, scale=2.5)
-        return routed_ffn(x, chosen, w, w_gu, w_down, held=(0, e))
+        return routed_ffn(x, chosen, w, w_gu, w_down, held=(0, e),
+                          kernel=kernel)
 
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     compiled = jax.jit(layer).lower(
         sds((tokens, d), jnp.bfloat16), sds((d, e), jnp.float32),
         sds((e,), jnp.float32), sds((e, d, 2 * f), jnp.bfloat16),
         sds((e, f, d), jnp.bfloat16)).compile()
-    assert compiled.as_text().count("ragged-dot") >= 2
+    text = compiled.as_text()
+    if kernel:
+        assert "ragged-dot" not in text
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+    else:
+        assert text.count("ragged-dot") >= 2
     # the sorted tokens, their gate-and-up and the combine, not the experts
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * tokens * k * d

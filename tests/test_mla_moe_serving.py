@@ -14,10 +14,12 @@ is not built for a latent cache or an expert layer, and the uniform and
 hybrid programs' StableHLO against the parent's.
 """
 
+import contextlib
 import dataclasses
 import hashlib
 import json
 import sys
+import types
 from pathlib import Path
 
 import jax
@@ -389,6 +391,55 @@ def test_the_counters_of_the_experts(prepared, tcfg):
     assert 4 * 2 * 8 <= e["touched"] <= 8 * 2 * steps
     assert 1 <= e["tokens_max"] <= 2
     assert e["tokens_mean"] == pytest.approx(20 * 2 * 4 / (16 * 2 * steps))
+
+
+def _recording_phase(seen):
+    """``serving.phase`` stood in for: each span's name and counts."""
+    @contextlib.contextmanager
+    def phase(name, **counts):
+        span = types.SimpleNamespace(counts=dict(counts))
+        span.set_metadata = span.counts.update
+        seen.append((name, span.counts))
+        yield span
+    return phase
+
+
+@pytest.mark.parametrize("engages", [False, True])
+def test_the_kernels_tile_count_reaches_the_server(prepared, tcfg,
+                                                   monkeypatch, engages):
+    """The decode block's ``expert_tiles`` column on the bookkeep spans, on
+    ``SlotServer.expert_tiles`` and in ``stats()["experts"]["tiles"]``: 0
+    where ``ragged_dot`` runs (the CPU's gate); with the gate forced open
+    (the kernel interpreted) the kernel's visits. The served tokens are
+    the reference's either way."""
+    import importlib
+
+    generate = importlib.import_module("tony_tpu.models.generate")
+    for module in (generate, serving):
+        monkeypatch.setattr(module, "expert_kernel_engages",
+                            lambda *a: engages)
+    seen = []
+    monkeypatch.setattr(serving, "phase", _recording_phase(seen))
+    # the gate is read when `_decode_block` is traced: the forced one takes
+    # a block size no other test compiles
+    server = SlotServer(prepared, tcfg,
+                        **dict(ENGINE, block_size=3 if engages else 4))
+    rng = np.random.default_rng(9)
+    reqs = [_request(rng, n, m) for n, m in ((4, 12), (11, 8))]
+    for r in reqs:
+        server.submit(r)
+    done = server.run_until_drained()
+    for r in reqs:
+        _check(r, done[r.id])
+    e = server.stats()["experts"]
+    spans = [c for name, c in seen
+             if name == "serve.step.bookkeep" and "expert_tiles" in c]
+    assert spans and sum(c["expert_tiles"] for c in spans) \
+        == server.expert_tiles == e["tiles"]
+    assert sum(c["experts_read"] for c in spans) == e["read"] > 0
+    # 4 rows x 4 experts a step fill one 16-row tile at most: the kernel
+    # visits each expert read once
+    assert e["tiles"] == (e["read"] if engages else 0)
 
 
 # ------------------------------------------------------------- refusals

@@ -179,11 +179,11 @@ def test_step_phases_carry_their_counts(run):
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
         experts = {"experts_touched", "experts_read", "experts_held",
-                   "expert_tokens_max", "expert_tokens_mean"}
+                   "expert_tiles", "expert_tokens_max", "expert_tokens_mean"}
         assert set(counts) - experts == {
             "tokens", "completions", "kv_blocks_read", "kv_blocks_ring",
             "state_rows", "state_rows_read", "state_rows_held"}
-        # (an engine with routed expert layers adds all five or none)
+        # (an engine with routed expert layers adds all six or none)
         assert set(counts) & experts in (set(), experts)
         assert 0 <= counts["state_rows"] <= 3
         # the CPU's recurrence is ``gated_delta_step`` over every row
@@ -221,6 +221,9 @@ def test_step_phases_carry_their_counts(run):
                and c["experts_held"] % (16 * 4) == 0 for c in spans)
     assert max(c["expert_tokens_max"] for c in spans) \
         == server.expert_tokens_max <= 3
+    # the CPU's grouped matmul is ``ragged_dot`` (``expert_kernel_engages``
+    # is false here): no tile of the kernel's was visited
+    assert sum(c["expert_tiles"] for c in spans) == server.expert_tiles == 0
     assert server.stats()["experts"]["touched"] == server.experts_touched
     assert sum(c["blocks"] for c in by_name[obs.PHASE_SYNC]) == len(
         by_name[obs.PHASE_DISPATCH])

@@ -252,6 +252,19 @@ def state_kernel_engages(l_new: int, sharded: bool) -> bool:
     return l_new == 1 and not sharded and jax.default_backend() == "tpu"
 
 
+def expert_kernel_engages(l_new: int, sharded: bool) -> bool:
+    """Whether a routed layer's grouped matmuls over ``l_new`` new
+    positions a row may run the Pallas kernel (parallel/routed_experts.py
+    ``grouped_matmul``: a 16-row tile, each touched expert's weights read
+    once) instead of ``jax.lax.ragged_dot`` and its 256-row tile: the one
+    gate on what the code observes, which the serving engine's
+    ``expert_tiles`` count asks too. One new token a row (a decode step
+    gives an expert 0-3 rows; a prefill's 4-32 keep ``ragged_dot``), on
+    the TPU (the CPU keeps ``ragged_dot``, which the kernel is tested
+    against), and not under a mesh."""
+    return l_new == 1 and not sharded and jax.default_backend() == "tpu"
+
+
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
                       allow_kernel=True, layer_idx=None, active=None,
@@ -570,7 +583,10 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     absorbed form (`_latent_attention`: one read of a row for all heads),
     over the block's own rows on a prefill's empty cache. ``routes=True``
     adds a third result: the experts each of the L positions chose in each
-    routed layer (``cfg.mlp_kinds``), [routed layers, B, L, k] int32.
+    routed layer (``cfg.mlp_kinds``), [routed layers, B, L, k] int32. A
+    routed layer's grouped matmuls run the Pallas kernel where
+    ``expert_kernel_engages`` (one new token a row, TPU, no mesh), else
+    ``jax.lax.ragged_dot`` (parallel/routed_experts.py).
 
     ``prefill=True`` asserts the cache is EMPTY (generate's first call):
     attention over (cache + new) then reduces to causal attention within
@@ -682,6 +698,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         return transformer.latent_out(cfg, o, lp), (kv, rec, lat)
 
     mixers = {"linear": recur, "latent": latent}
+    expert_kernel = expert_kernel_engages(l, shardings is not None)
     carry = ((cache.k, cache.v, cache.k_scale, cache.v_scale),
              (cache.state, cache.conv), cache.latent)
     picks = []
@@ -690,7 +707,8 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
                                            fused_layers)
         x, aux, carry = transformer.decoder_layer(
             cfg, x, positions, lp, functools.partial(attend, j), carry,
-            functools.partial(mixers[kind], j) if kind in mixers else None)
+            functools.partial(mixers[kind], j) if kind in mixers else None,
+            expert_kernel)
         if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
             picks.append(aux)
     (ck, cv, ks_buf, vs_buf), (state, conv), lat = carry

@@ -289,10 +289,11 @@ from .generate import (
     prepare_decode,
     sample_token,
     state_kernel_engages,
+    expert_kernel_engages,
 )
 from ..ops.decode_attention import kv_block_k, live_kv_blocks
 from ..ops.gated_delta import live_state_rows
-from ..parallel.routed_experts import expert_load
+from ..parallel.routed_experts import expert_load, tile_visits
 from .transformer import TransformerConfig, rms_norm
 from . import transformer
 
@@ -439,11 +440,11 @@ class _Admission:
 
 def routed_columns(cfg: TransformerConfig, block: int) -> int:
     """Columns a config's routed expert layers add to a decode block's
-    packed result (`_decode_block`): the experts chosen, then three
+    packed result (`_decode_block`): the experts chosen, then four
     counts. 0 without such layers."""
     if not cfg.n_routed_layers:
         return 0
-    return block * cfg.n_routed_layers * cfg.moe_top_k + 3
+    return block * cfg.n_routed_layers * cfg.moe_top_k + 4
 
 
 def _pow2_rows(n: int) -> int:
@@ -1025,14 +1026,17 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
 
     A config with routed expert layers (``cfg.mlp_kinds``) widens it by
     `routed_columns`: the experts each row's fed token chose at each step
-    in each routed layer ([S, block * layers * k], step-major), then three
+    in each routed layer ([S, block * layers * k], step-major), then four
     columns whose FIRST row carries a count of the whole block, summed
     over its steps and routed layers: the distinct experts the rows live
     at a step routed to, the distinct experts ANY row routed to (an idle
     row computes on its stale token and routes it like any other: those
-    are the experts whose weights the grouped matmul streams), and the
-    most assignments of live rows that one expert got at one step of one
-    layer (a maximum, not a sum). They come before the recurrent state's
+    are the experts whose weights the grouped matmul streams), the most
+    assignments of live rows that one expert got at one step of one layer
+    (a maximum, not a sum), and, where ``expert_kernel_engages``, the
+    (row tile, expert) pairs the Pallas grouped matmul visits
+    (`tile_visits` of the held experts' groups; 0 where
+    ``jax.lax.ragged_dot`` runs). They come before the recurrent state's
     column."""
     params = _cast_decode_params(params, cfg)
     if build_fused:
@@ -1043,6 +1047,7 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     m_cap = cache.k.shape[3]
 
     routed = cfg.n_routed_layers > 0
+    expert_kernel = expert_kernel_engages(1, shardings is not None)
 
     def step(carry, _):
         cache, tokens, active, cursor, key = carry
@@ -1088,8 +1093,11 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
             load = functools.partial(expert_load, n_experts=cfg.moe_experts)
             live = jax.vmap(lambda c: load(c, active))(picks)
             every = jax.vmap(load)(picks)
+            first, count = cfg.experts_held
+            tiles = (tile_visits(every[:, first:first + count])
+                     if expert_kernel else jnp.int32(0))
             ys = (ys, (jnp.moveaxis(picks, 0, 1), jnp.sum(live > 0),
-                       jnp.sum(every > 0), jnp.max(live)))
+                       jnp.sum(every > 0), jnp.max(live), tiles))
         return (new_cache, tokens, still, (cursor + 1) % m_cap, key), ys
 
     def state_mark(cache):
@@ -1106,7 +1114,7 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     (cache, tokens, active, cursor, key), ys = lax.scan(
         step, (cache, tokens, active, cursor, key), None, length=block)
     if routed:
-        ys, (picks, touched, read, busiest) = ys
+        ys, (picks, touched, read, busiest, tiles) = ys
     if lp_k:
         toks, chosen, ids, vals = ys
         s = toks.shape[1]
@@ -1123,10 +1131,10 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     if routed:
         n_slots = picks.shape[1]                    # picks [block, S, layers, k]
         counts = jnp.stack([jnp.sum(touched), jnp.sum(read),
-                            jnp.max(busiest)]).astype(jnp.int32)
+                            jnp.max(busiest), jnp.sum(tiles)]).astype(jnp.int32)
         extra = extra + [
             jnp.moveaxis(picks, 0, 1).reshape(n_slots, -1),
-            jnp.zeros((n_slots, 3), jnp.int32).at[0].set(counts)]
+            jnp.zeros((n_slots, 4), jnp.int32).at[0].set(counts)]
     if mark_in is not None:
         # the rows whose recurrent state this block changed, as the device
         # has it (a frozen row's is bit for bit what it was): the last column
@@ -2094,6 +2102,10 @@ class SlotServer:
         self.experts_touched = 0
         self.experts_read = 0
         self.experts_held = 0
+        # the (row tile, expert) pairs the decode steps' Pallas grouped
+        # matmul visited (0 where ``jax.lax.ragged_dot`` runs): over
+        # experts_read, what experts straddling two row tiles cost
+        self.expert_tiles = 0
         self.expert_tokens_max = 0
         self.expert_assignments = 0
         self.max_queue = int(max_queue)
@@ -3263,6 +3275,7 @@ class SlotServer:
                 "routed_layers": c.n_routed_layers,
                 "touched": self.experts_touched,
                 "read": self.experts_read,
+                "tiles": self.expert_tiles,
                 "held_steps": self.experts_held,
                 "tokens_max": self.expert_tokens_max,
                 "tokens_mean": self._expert_tokens_mean(
@@ -4589,9 +4602,9 @@ class SlotServer:
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
-            e_touched, e_read, e_held, e_asg = (
+            e_touched, e_read, e_held, e_asg, e_tiles = (
                 self.experts_touched, self.experts_read, self.experts_held,
-                self.expert_assignments)
+                self.expert_assignments, self.expert_tiles)
             self._span_tokens_max = 0
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
@@ -4607,6 +4620,7 @@ class SlotServer:
                     experts_touched=self.experts_touched - e_touched,
                     experts_read=self.experts_read - e_read,
                     experts_held=held,
+                    expert_tiles=self.expert_tiles - e_tiles,
                     expert_tokens_max=self._span_tokens_max,
                     expert_tokens_mean=self._expert_tokens_mean(
                         self.expert_assignments - e_asg, held))
@@ -4664,15 +4678,17 @@ class SlotServer:
             picks = None
             if self._routed:
                 # the routed layers' columns (`_decode_block`): the experts
-                # chosen, then the block's three counts in their first row
+                # chosen, then the block's four counts in their first row
                 n = routed_columns(self.cfg, self.block_size)
-                touched, streamed, busiest = (int(v) for v in packed[0, -3:])
-                picks = packed[:, -n:-3].reshape(
+                touched, streamed, busiest, tiles = (
+                    int(v) for v in packed[0, -4:])
+                picks = packed[:, -n:-4].reshape(
                     self.slots, self.block_size, self.cfg.n_routed_layers,
                     self.cfg.moe_top_k)
                 packed = packed[:, :-n]
                 self.experts_touched += touched
                 self.experts_read += streamed
+                self.expert_tiles += tiles
                 self.experts_held += (self.cfg.experts_held[1]
                                       * self.cfg.n_routed_layers
                                       * self.block_size)

@@ -644,12 +644,12 @@ def _attn_out(cfg: TransformerConfig, attn, lp):
     return jnp.einsum("blhk,hkd->bld", attn, lp["wo"].astype(dt))
 
 
-def _mlp(cfg: TransformerConfig, h, lp):
+def _mlp(cfg: TransformerConfig, h, lp, expert_kernel: bool = False):
     """Post-attention MLP (dense SwiGLU or MoE) -> (out, aux): the toy
     MoE's load-balancing loss, 0 for a dense MLP, and for a routed layer
     (``lp`` carries ``router_bias``: cfg.mlp_kinds) the experts its tokens
     chose [B, L, k] int32, which the serving programs hand on to whoever
-    asked for them."""
+    asked for them. ``expert_kernel``: `routed_ffn`'s ``kernel``."""
     dt = cfg.dtype
     aux = jnp.float32(0)
     if "router_bias" in lp:
@@ -660,7 +660,8 @@ def _mlp(cfg: TransformerConfig, h, lp):
         chosen, w = route(flat, lp["router"], lp["router_bias"],
                           top_k=cfg.moe_top_k, scale=cfg.moe_scale)
         out = routed_ffn(flat, chosen, w, lp["we_gu"].astype(dt),
-                         lp["we_down"].astype(dt), held=cfg.experts_held)
+                         lp["we_down"].astype(dt), held=cfg.experts_held,
+                         kernel=expert_kernel)
         if cfg.moe_shared:      # what every chip computes alike
             sf = cfg.moe_shared * cfg.moe_ff
             gu = jnp.einsum("td,de->te", flat, lp["ws_gu"].astype(dt))
@@ -860,7 +861,7 @@ def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
 
 
 def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
-                  recur=None):
+                  recur=None, expert_kernel: bool = False):
     """One decoder block, the only one: norm -> mixer -> norm -> _mlp
     (``norm_order`` "pre"; "post" norms each branch's OUTPUT instead). lp =
     this layer's params (stack dim removed). The caller names the mixer by
@@ -876,7 +877,9 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
     decision for a linear layer: where its state and convolution tail come
     from and go to around `linear_mixer`; a latent layer's mixer is handed
     in the same way (`latent_project` and its neighbours, around the
-    caller's rows). Returns (x, aux, kv), ``aux`` as `_mlp` has it."""
+    caller's rows). ``expert_kernel`` is handed to `_mlp` (a routed layer's
+    decode-step grouped matmul). Returns (x, aux, kv), ``aux`` as `_mlp`
+    has it."""
     pre = cfg.norm_order == "pre"
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
     if recur is not None:
@@ -889,9 +892,10 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
         mixed = rms_norm(mixed, lp["attn_norm"], cfg.norm_eps)
     x = x + mixed
     if pre:
-        mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp)
+        mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp,
+                            expert_kernel)
     else:
-        mlp_out, aux = _mlp(cfg, x, lp)
+        mlp_out, aux = _mlp(cfg, x, lp, expert_kernel)
         mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.norm_eps)
     return x + mlp_out, aux, kv
 

@@ -13,13 +13,22 @@ layer (models/transformer.py):
   (sum_sel s + 1e-20) * scale`` taken from the scores WITHOUT the bias.
 - **Dispatch** is a sort, not capacity slots: the ``T k`` (token, expert)
   assignments are ordered by expert (a stable argsort), the tokens
-  gathered in that order, and one grouped matmul (``jax.lax.ragged_dot``)
-  runs each expert over its own contiguous rows. Every assignment has a
-  row, whatever the load: if all ``T`` tokens pick one expert its group is
-  ``T`` rows and the others' are empty. Nothing is dropped, and no
-  ``[E, capacity, d]`` buffer exists. A prefill of 4096 tokens (about 128
-  an expert at E = 256, k = 8) and a decode step of 32 (0-3 an expert) go
-  the same way.
+  gathered in that order, and one grouped matmul runs each expert over its
+  own contiguous rows. Every assignment has a row, whatever the load: if
+  all ``T`` tokens pick one expert its group is ``T`` rows and the others'
+  are empty. Nothing is dropped, and no ``[E, capacity, d]`` buffer
+  exists. The grouped matmul takes one of two paths, by what the caller
+  observes (``generate.expert_kernel_engages``): a decode step (one new
+  token a row, 0-3 assignments an expert at 32 rows) on the TPU outside a
+  mesh runs `grouped_matmul`, the Pallas grouped matmul of
+  ``jax.experimental.pallas.ops.tpu.megablox`` at a row tile of
+  ``TILE_ROWS`` and an expert's whole matrix as its weight block, so a
+  touched expert's weights cross HBM once and a visit multiplies 16 rows,
+  not 256; a prefill
+  (4-32 an expert), the CPU and a mesh keep ``jax.lax.ragged_dot``,
+  which XLA lowers to its own grouped matmul at a 256-row tile. Both
+  visit only the (row tile, expert) pairs that hold rows; `tile_visits`
+  counts the kernel's.
 - **The experts held** are an argument: ``held = (first, count)`` says
   which contiguous range of the ``E`` experts ``w_gu`` / ``w_down`` carry
   (a chip's share under expert parallelism). Routing is over all ``E``
@@ -39,6 +48,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+# the decode kernel's row tile: a decode step gives an expert 0-3 rows,
+# and bfloat16's native tile is 16 sublanes
+TILE_ROWS = 16
 
 
 def route(x, router, router_bias, *, top_k: int, scale: float):
@@ -63,10 +77,43 @@ def expert_load(chosen, live=None, *, n_experts: int):
         ones.reshape(-1))
 
 
-def routed_ffn(x, chosen, weights, w_gu, w_down, *, held: tuple):
+def tile_visits(sizes, tm: int = TILE_ROWS):
+    """The (row tile, group) pairs `grouped_matmul` visits for groups of
+    ``sizes`` [..., G] rows laid one after another (megablox's
+    ``make_group_metadata`` without empty groups): a non-empty group
+    spans the ``tm``-row tiles from the one its first row lies in to the
+    one its last row lies in. Summed over every leading axis."""
+    ends = jnp.cumsum(sizes, axis=-1)
+    span = -(-ends // tm) - (ends - sizes) // tm
+    return jnp.sum(jnp.where(sizes > 0, span, 0))
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """``jax.lax.ragged_dot``'s statement for rows sorted by group: lhs
+    [m, K], rhs [G, K, N], sizes [G] -> [m, N] in lhs's dtype, float32
+    accumulation; rows past ``sum(sizes)`` are left undefined (the
+    caller masks them). The megablox kernel at a ``TILE_ROWS`` row tile,
+    its K and N tiles the whole of K and N: a weight block's index is then
+    the group's alone, so a group that straddles two row tiles keeps its
+    resident block and each touched group's weights are read once (an N
+    tile of 256-768 ran as fast on a v5e and only adds grid steps: PERF.md
+    §6, PR 37). Interpreted off the TPU (the tests)."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    pad = -m % TILE_ROWS
+    out = gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, sizes,
+              preferred_element_type=lhs.dtype, tiling=(TILE_ROWS, k, n),
+              interpret=jax.default_backend() != "tpu")
+    return out[:m]
+
+
+def routed_ffn(x, chosen, weights, w_gu, w_down, *, held: tuple,
+               kernel: bool = False):
     """sum over a token's chosen experts of w_e SwiGLU_e(x), for the
     experts ``held`` = (first, count) -> [T, d] in x's dtype. x [T, d],
-    chosen / weights [T, k] from `route`."""
+    chosen / weights [T, k] from `route`. ``kernel`` runs the grouped
+    matmuls through `grouped_matmul` (a decode step's few rows an expert:
+    ``generate.expert_kernel_engages``), else ``jax.lax.ragged_dot``."""
     t, d = x.shape
     k = chosen.shape[1]
     first, count = held
@@ -78,9 +125,10 @@ def routed_ffn(x, chosen, weights, w_gu, w_down, *, held: tuple):
     order = jnp.argsort(group, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
     xs = x[order // k]                                      # [T k, d]
-    gu = jax.lax.ragged_dot(xs, w_gu.astype(x.dtype), sizes)
+    matmul = grouped_matmul if kernel else jax.lax.ragged_dot
+    gu = matmul(xs, w_gu.astype(x.dtype), sizes)
     act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-    ys = jax.lax.ragged_dot(act, w_down.astype(x.dtype), sizes)
+    ys = matmul(act, w_down.astype(x.dtype), sizes)
     # back to token order; rows past the last group hold nothing defined
     w = jnp.where(mine, weights.reshape(-1), 0.0)
     inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
