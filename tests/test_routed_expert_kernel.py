@@ -1,10 +1,8 @@
 """The routed layer's decode-step grouped matmul (parallel/routed_experts.py
 ``grouped_matmul``: megablox's Pallas kernel at a 16-row tile and whole-K
 weight blocks) in interpret mode against ``jax.lax.ragged_dot``, the path
-it replaces in a decode step and the one the CPU and a prefill keep; the
-count of its grid visits (``tile_visits``) against the kernel's own
-metadata; and the gate that says where it runs
-(``generate.expert_kernel_engages``).
+it replaces in a decode step and the one the CPU and a prefill keep; and
+the gate that says where it runs (``generate.expert_kernel_engages``).
 
 bfloat16 operands as the benchmark serves them: both sides accumulate in
 float32 and round once to bfloat16, so they agree to that rounding.
@@ -14,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
 from tony_tpu.models import transformer
 from tony_tpu.parallel import routed_experts as rx
@@ -71,24 +68,6 @@ def test_grouped_matmul_is_ragged_dots_statement(operands, pattern):
         got = rx.grouped_matmul(x, w, sizes)
         assert got.shape == (m, w.shape[2]) and got.dtype == jnp.bfloat16
         _bf16_close(got, jax.lax.ragged_dot(x, w, sizes))
-
-
-@pytest.mark.parametrize("pattern", sorted(_patterns()))
-def test_tile_visits_is_the_kernels_own_count(pattern):
-    """``tile_visits`` (the engine's ``expert_tiles``) is the number of
-    active tiles megablox's metadata gives the kernel's grid, and lies
-    between the groups that hold rows and that plus the tiles' seams."""
-    sizes = jnp.asarray(_patterns()[pattern])
-    m = int(sizes.sum())
-    m += -m % rx.TILE_ROWS
-    _, active = make_group_metadata(
-        group_sizes=sizes, m=m, tm=rx.TILE_ROWS, start_group=jnp.int32(0),
-        num_nonzero_groups=E, visit_empty_groups=False)
-    groups = int((sizes > 0).sum())
-    assert int(rx.tile_visits(sizes)) == int(active)
-    assert groups <= int(active) <= groups + m // rx.TILE_ROWS - 1
-    # summed over a leading axis, as the decode block sums its layers
-    assert int(rx.tile_visits(jnp.stack([sizes, sizes]))) == 2 * int(active)
 
 
 @pytest.mark.parametrize("rows, held", [

@@ -179,11 +179,11 @@ def test_step_phases_carry_their_counts(run):
         assert set(counts) == {"blocks"} and counts["blocks"] >= 1
     for counts in by_name[obs.PHASE_BOOKKEEP]:
         experts = {"experts_touched", "experts_read", "experts_held",
-                   "expert_tiles", "expert_tokens_max", "expert_tokens_mean"}
+                   "expert_tokens_max", "expert_tokens_mean"}
         assert set(counts) - experts == {
             "tokens", "completions", "kv_blocks_read", "kv_blocks_ring",
             "state_rows", "state_rows_read", "state_rows_held"}
-        # (an engine with routed expert layers adds all six or none)
+        # (an engine with routed expert layers adds all five or none)
         assert set(counts) & experts in (set(), experts)
         assert 0 <= counts["state_rows"] <= 3
         # the CPU's recurrence is ``gated_delta_step`` over every row
@@ -221,9 +221,6 @@ def test_step_phases_carry_their_counts(run):
                and c["experts_held"] % (16 * 4) == 0 for c in spans)
     assert max(c["expert_tokens_max"] for c in spans) \
         == server.expert_tokens_max <= 3
-    # the CPU's grouped matmul is ``ragged_dot`` (``expert_kernel_engages``
-    # is false here): no tile of the kernel's was visited
-    assert sum(c["expert_tiles"] for c in spans) == server.expert_tiles == 0
     assert server.stats()["experts"]["touched"] == server.experts_touched
     assert sum(c["blocks"] for c in by_name[obs.PHASE_SYNC]) == len(
         by_name[obs.PHASE_DISPATCH])
@@ -433,6 +430,27 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     if stem in ("expert_weights_read_pct", "experts_touched_pct"):
         # of the routed engine's 16 experts a step: up to 3 rows x 4
         assert 0.0 < value <= 75.0
+
+
+PART_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
+                ["per_layer"] if m["name"].startswith(
+                    ("decode_step_part_ms.", "train_step_part_ms."))]
+
+
+@pytest.mark.parametrize("entry", PART_ENTRIES, ids=lambda m: m["name"])
+def test_part_readers_find_no_device_run_in_the_cpu_session(entry, run,
+                                                            monkeypatch):
+    """The session's programs are registered, but its trace holds no TPU
+    plane: no whole run of a device program to sum by part."""
+    monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
+    stem, _, suffix = entry["name"].partition(".")
+    reader = lib.load(f"layer_metrics/{stem}.py")
+    facts = {"programs": {"decode": ["_decode_block"],
+                          "train_step": ["jit_step"]},
+             "chips": 1, "engine": {"block_size": 4}, "trace_window_s": 5.0}
+    assert any(p.module == "jit__decode_block"
+               for p in obs.PROGRAMS._programs.values())
+    assert reader.read(facts, suffix) is None
 
 
 def test_new_entries_are_the_thirteen_and_the_shares_are_disjoint(

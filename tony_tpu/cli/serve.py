@@ -1521,10 +1521,17 @@ class ServeApp:
         Runs on the HTTP handler thread — the serving loop keeps
         dispatching, which is the point: the capture sees live traffic.
         One capture at a time (jax's trace machinery is process-global);
-        a concurrent request gets a busy error."""
+        a concurrent request gets a busy error.
+
+        Beside the capture goes ``step_parts.json``, {module: {instruction:
+        part}} of the device programs the engine has dispatched
+        (``observability.PROGRAMS``), so a capture can be summed by part
+        of the step (docs/observability.md); the programs are lowered for
+        it once the capture has ended."""
         from pathlib import Path
 
         from .. import constants as c
+        from ..observability import PROGRAMS
         from ..train.profiling import trace
 
         if not self.trace_dir:
@@ -1540,6 +1547,8 @@ class ServeApp:
                        / f"serve_{int(time.time())}_{seconds:g}s")
             with trace(out_dir):
                 time.sleep(seconds)
+            (out_dir / "step_parts.json").write_text(
+                json.dumps(PROGRAMS.maps()))
             files = sorted(str(p.relative_to(out_dir))
                            for p in out_dir.rglob("*") if p.is_file())
             return {"dir": str(out_dir), "seconds": seconds,

@@ -257,8 +257,7 @@ def expert_kernel_engages(l_new: int, sharded: bool) -> bool:
     positions a row may run the Pallas kernel (parallel/routed_experts.py
     ``grouped_matmul``: a 16-row tile, each touched expert's weights read
     once) instead of ``jax.lax.ragged_dot`` and its 256-row tile: the one
-    gate on what the code observes, which the serving engine's
-    ``expert_tiles`` count asks too. One new token a row (a decode step
+    gate on what the code observes. One new token a row (a decode step
     gives an expert 0-3 rows; a prefill's 4-32 keep ``ragged_dot``), on
     the TPU (the CPU keeps ``ragged_dot``, which the kernel is tested
     against), and not under a mesh."""
@@ -719,18 +718,20 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     # tiny. Default projects only the last position (generation never
     # needs earlier logits; a full [B, L, V] prefill projection would be
     # a pure HBM bonfire at long prompts / large vocab).
-    x_out = rms_norm(x if all_logits else x[:, -1], params["final_norm"],
-                     cfg.norm_eps)
-    eq = "bld,dv->blv" if all_logits else "bd,dv->bv"
-    if w8:
-        logits = (
-            jnp.einsum(eq, x_out, fused["unembed"].astype(dt))
-            * fused["unembed_s"][0]
-        ).astype(jnp.float32)
-    else:
-        logits = jnp.einsum(
-            eq, x_out, params["unembed"].astype(dt)
-        ).astype(jnp.float32)
+    # the final norm and the unembedding: the step's part "unembed"
+    with jax.named_scope("unembed"):
+        x_out = rms_norm(x if all_logits else x[:, -1], params["final_norm"],
+                         cfg.norm_eps)
+        eq = "bld,dv->blv" if all_logits else "bd,dv->bv"
+        if w8:
+            logits = (
+                jnp.einsum(eq, x_out, fused["unembed"].astype(dt))
+                * fused["unembed_s"][0]
+            ).astype(jnp.float32)
+        else:
+            logits = jnp.einsum(
+                eq, x_out, params["unembed"].astype(dt)
+            ).astype(jnp.float32)
     if shardings is not None:
         logits = lax.with_sharding_constraint(logits, shardings.act)
         ck = lax.with_sharding_constraint(ck, shardings.cache)

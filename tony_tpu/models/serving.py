@@ -188,6 +188,7 @@ from ..observability import (
     ServingTelemetry,
     TraceContext,
     phase,
+    register_program,
 )
 from .registry import ModelEntry, ModelRegistry
 
@@ -289,11 +290,10 @@ from .generate import (
     prepare_decode,
     sample_token,
     state_kernel_engages,
-    expert_kernel_engages,
 )
 from ..ops.decode_attention import kv_block_k, live_kv_blocks
 from ..ops.gated_delta import live_state_rows
-from ..parallel.routed_experts import expert_load, tile_visits
+from ..parallel.routed_experts import expert_load
 from .transformer import TransformerConfig, rms_norm
 from . import transformer
 
@@ -440,11 +440,11 @@ class _Admission:
 
 def routed_columns(cfg: TransformerConfig, block: int) -> int:
     """Columns a config's routed expert layers add to a decode block's
-    packed result (`_decode_block`): the experts chosen, then four
+    packed result (`_decode_block`): the experts chosen, then three
     counts. 0 without such layers."""
     if not cfg.n_routed_layers:
         return 0
-    return block * cfg.n_routed_layers * cfg.moe_top_k + 4
+    return block * cfg.n_routed_layers * cfg.moe_top_k + 3
 
 
 def _pow2_rows(n: int) -> int:
@@ -1026,18 +1026,20 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
 
     A config with routed expert layers (``cfg.mlp_kinds``) widens it by
     `routed_columns`: the experts each row's fed token chose at each step
-    in each routed layer ([S, block * layers * k], step-major), then four
+    in each routed layer ([S, block * layers * k], step-major), then three
     columns whose FIRST row carries a count of the whole block, summed
     over its steps and routed layers: the distinct experts the rows live
     at a step routed to, the distinct experts ANY row routed to (an idle
     row computes on its stale token and routes it like any other: those
-    are the experts whose weights the grouped matmul streams), the most
-    assignments of live rows that one expert got at one step of one layer
-    (a maximum, not a sum), and, where ``expert_kernel_engages``, the
-    (row tile, expert) pairs the Pallas grouped matmul visits
-    (`tile_visits` of the held experts' groups; 0 where
-    ``jax.lax.ragged_dot`` runs). They come before the recurrent state's
-    column."""
+    are the experts whose weights the grouped matmul streams), and the
+    most assignments of live rows that one expert got at one step of one
+    layer (a maximum, not a sum). They come before the recurrent state's
+    column.
+
+    The sample (and the logprobs) are the step's part "unembed", and the
+    count columns, routed and recurrent, its part "counts"
+    (``observability.STEP_PARTS``): what the instrumentation costs the
+    device."""
     params = _cast_decode_params(params, cfg)
     if build_fused:
         fused = _fuse_decode_weights(params, cfg, weight_dtype)
@@ -1047,7 +1049,6 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     m_cap = cache.k.shape[3]
 
     routed = cfg.n_routed_layers > 0
-    expert_kernel = expert_kernel_engages(1, shardings is not None)
 
     def step(carry, _):
         cache, tokens, active, cursor, key = carry
@@ -1061,19 +1062,21 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         # pool; all_greedy / per_row_topk (static, host-known) compile
         # the argmax-only / static-threshold programs whenever no busy
         # row actually needs the costlier variant
-        nxt = sample_token(logits, sub,
-                           0.0 if all_greedy else temps,
-                           topks if per_row_topk else top_k)
+        with jax.named_scope("unembed"):
+            nxt = sample_token(logits, sub,
+                               0.0 if all_greedy else temps,
+                               topks if per_row_topk else top_k)
         emitted = jnp.where(active, nxt, pad_id).astype(jnp.int32)
         if lp_k:
             # the raw-distribution logprobs of the row the sample came
             # from (pre temperature/top-k filtering — the model's own
             # distribution, the OpenAI convention)
-            lp_full = jax.nn.log_softmax(logits.astype(jnp.float32),
-                                         axis=-1)
-            top_vals, top_ids = lax.top_k(lp_full, lp_k)
-            chosen = jnp.take_along_axis(
-                lp_full, nxt[:, None].astype(jnp.int32), axis=-1)[:, 0]
+            with jax.named_scope("unembed"):
+                lp_full = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                             axis=-1)
+                top_vals, top_ids = lax.top_k(lp_full, lp_k)
+                chosen = jnp.take_along_axis(
+                    lp_full, nxt[:, None].astype(jnp.int32), axis=-1)[:, 0]
         # only rows active this step advance (staying ring-aligned with
         # the cursor); a frozen row keeps taking the shared-cursor garbage
         # write, but its data is dead — completions are extracted from the
@@ -1090,14 +1093,14 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
               if lp_k else emitted)
         if routed:
             picks = picks[0][:, :, 0]               # [layers, S, k]
-            load = functools.partial(expert_load, n_experts=cfg.moe_experts)
-            live = jax.vmap(lambda c: load(c, active))(picks)
-            every = jax.vmap(load)(picks)
-            first, count = cfg.experts_held
-            tiles = (tile_visits(every[:, first:first + count])
-                     if expert_kernel else jnp.int32(0))
-            ys = (ys, (jnp.moveaxis(picks, 0, 1), jnp.sum(live > 0),
-                       jnp.sum(every > 0), jnp.max(live), tiles))
+            with jax.named_scope("counts"):
+                load = functools.partial(expert_load,
+                                         n_experts=cfg.moe_experts)
+                live = jax.vmap(lambda c: load(c, active))(picks)
+                every = jax.vmap(load)(picks)
+                counts = (jnp.sum(live > 0), jnp.sum(every > 0),
+                          jnp.max(live))
+            ys = (ys, (jnp.moveaxis(picks, 0, 1), *counts))
         return (new_cache, tokens, still, (cursor + 1) % m_cap, key), ys
 
     def state_mark(cache):
@@ -1107,14 +1110,16 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         say whether a block changed a row's state at the cost of one read
         of one layer's state each (holding the old state to compare it
         whole would copy every layer's: the scan updates them in place)."""
-        return (None if cache.state is None
-                else jnp.sum(cache.state[0], axis=(1, 2, 3)))
+        if cache.state is None:
+            return None
+        with jax.named_scope("counts"):
+            return jnp.sum(cache.state[0], axis=(1, 2, 3))
 
     mark_in = state_mark(cache)
     (cache, tokens, active, cursor, key), ys = lax.scan(
         step, (cache, tokens, active, cursor, key), None, length=block)
     if routed:
-        ys, (picks, touched, read, busiest, tiles) = ys
+        ys, (picks, touched, read, busiest) = ys
     if lp_k:
         toks, chosen, ids, vals = ys
         s = toks.shape[1]
@@ -1130,15 +1135,17 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
         toks, extra = ys, []
     if routed:
         n_slots = picks.shape[1]                    # picks [block, S, layers, k]
-        counts = jnp.stack([jnp.sum(touched), jnp.sum(read),
-                            jnp.max(busiest), jnp.sum(tiles)]).astype(jnp.int32)
+        with jax.named_scope("counts"):
+            counts = jnp.stack([jnp.sum(touched), jnp.sum(read),
+                                jnp.max(busiest)]).astype(jnp.int32)
         extra = extra + [
             jnp.moveaxis(picks, 0, 1).reshape(n_slots, -1),
-            jnp.zeros((n_slots, 4), jnp.int32).at[0].set(counts)]
+            jnp.zeros((n_slots, 3), jnp.int32).at[0].set(counts)]
     if mark_in is not None:
         # the rows whose recurrent state this block changed, as the device
         # has it (a frozen row's is bit for bit what it was): the last column
-        moved = state_mark(cache) != mark_in
+        with jax.named_scope("counts"):
+            moved = state_mark(cache) != mark_in
         extra = extra + [moved.astype(jnp.int32)[:, None]]
     packed = jnp.concatenate(
         [toks.T, cache.length[:, None], active.astype(jnp.int32)[:, None]]
@@ -2079,6 +2086,9 @@ class SlotServer:
         self.expired_requests = 0       # deadline passed while queued
         self.resets = 0                 # reset() calls (loop recoveries)
         self.blocks_dispatched = 0      # decode blocks sent to the device
+        # the variants of the device programs this server has dispatched,
+        # each noted once in observability.PROGRAMS (`_register`)
+        self._programs_seen: set = set()
         # how far the decode kernel's live-range reads engage: KV blocks
         # a processed block's last step streamed / those its rings hold
         self.kv_blocks_read = 0
@@ -2102,10 +2112,6 @@ class SlotServer:
         self.experts_touched = 0
         self.experts_read = 0
         self.experts_held = 0
-        # the (row tile, expert) pairs the decode steps' Pallas grouped
-        # matmul visited (0 where ``jax.lax.ragged_dot`` runs): over
-        # experts_read, what experts straddling two row tiles cost
-        self.expert_tiles = 0
         self.expert_tokens_max = 0
         self.expert_assignments = 0
         self.max_queue = int(max_queue)
@@ -3275,7 +3281,6 @@ class SlotServer:
                 "routed_layers": c.n_routed_layers,
                 "touched": self.experts_touched,
                 "read": self.experts_read,
-                "tiles": self.expert_tiles,
                 "held_steps": self.experts_held,
                 "tokens_max": self.expert_tokens_max,
                 "tokens_mean": self._expert_tokens_mean(
@@ -3633,14 +3638,16 @@ class SlotServer:
         (the ring cache or a paged view; with ``draft`` the draft model's)
         -> the written cache. The slots' state vectors ride through every
         dispatch (donated), the target's and the draft's alike."""
+        args = (self._draft_params if draft else self._params, cache,
+                self._d_tokens, self._d_active, self._d_target,
+                self._d_offsets, self._d_temps, self._d_topks, *rows)
+        statics = dict(cfg=self._draft_cfg if draft else self.cfg,
+                       shardings=None if draft else self._shardings)
+        self._register(_prefill_batch, ("prefill", draft, rows[0].shape[0]),
+                       args, statics)
         (cache, self._d_tokens, self._d_active, self._d_target,
          self._d_offsets, self._d_temps, self._d_topks,
-         fence, *picks) = _prefill_batch(
-            self._draft_params if draft else self._params, cache,
-            self._d_tokens, self._d_active, self._d_target,
-            self._d_offsets, self._d_temps, self._d_topks, *rows,
-            cfg=self._draft_cfg if draft else self.cfg,
-            shardings=None if draft else self._shardings)
+         fence, *picks) = _prefill_batch(*args, **statics)
         self.admission_dispatches += 1
         self.dispatch_tracker.track(
             "draft_prefill" if draft else "prefill", fence)
@@ -4278,6 +4285,40 @@ class SlotServer:
         self.kv_imports += 1
         return req.id
 
+    def _register(self, fn, variant, args, statics) -> None:
+        """Note ``fn(*args, **statics)`` in observability.PROGRAMS the
+        first time this server dispatches ``variant`` of it: one set
+        look-up a dispatch, and nothing lowered."""
+        if variant not in self._programs_seen:
+            self._programs_seen.add(variant)
+            register_program(fn, args, statics)
+
+    def _dispatch_decode(self, cache, key, lp_k: int) -> tuple:
+        """One `_decode_block` over ``cache`` (the ring, or the paged
+        engine's gathered view) -> (cache, tokens, active, packed)."""
+        args = (self._params, self._fused, cache,
+                self._d_tokens, self._d_active, self._d_target,
+                self._d_offsets, jnp.int32(self._cursor), self._d_temps,
+                self._d_topks, key)
+        statics = dict(
+            cfg=self.cfg, block=self.block_size,
+            stop_tokens=self.stop_tokens, pad_id=self.pad_id,
+            top_k=self.top_k,
+            # _host_busy never goes False while a row is still active on
+            # device, so these are safe whenever they say all-greedy /
+            # nobody-overrides-k
+            per_row_topk=bool(
+                (self._np_topks[self._host_busy] != self.top_k).any()),
+            weight_dtype=self.weight_dtype, build_fused=self._build_fused,
+            all_greedy=not bool(
+                (self._np_temps[self._host_busy] > 0).any()),
+            lp_k=lp_k,
+            shardings=self._shardings)
+        self._register(_decode_block,
+                       ("decode", lp_k, statics["all_greedy"],
+                        statics["per_row_topk"]), args, statics)
+        return _decode_block(*args, **statics)
+
     def _dispatch_block_paged(self) -> None:
         """Paged decode block: pump at most ``prefill_interleave``
         pending prefill tokens, then gather -> `_decode_block` (the
@@ -4292,21 +4333,8 @@ class SlotServer:
         lp_k = (LOGPROBS_MAX
                 if bool((self._np_lp[self._host_busy] > 0).any()) else 0)
         view = self._gather_view()
-        (view, self._d_tokens, self._d_active, packed) = _decode_block(
-            self._params, self._fused, view,
-            self._d_tokens, self._d_active, self._d_target,
-            self._d_offsets, jnp.int32(self._cursor), self._d_temps,
-            self._d_topks, sub,
-            cfg=self.cfg, block=self.block_size,
-            stop_tokens=self.stop_tokens, pad_id=self.pad_id,
-            top_k=self.top_k,
-            per_row_topk=bool(
-                (self._np_topks[self._host_busy] != self.top_k).any()),
-            weight_dtype=self.weight_dtype, build_fused=self._build_fused,
-            all_greedy=not bool(
-                (self._np_temps[self._host_busy] > 0).any()),
-            lp_k=lp_k,
-            shardings=self._shardings)
+        (view, self._d_tokens, self._d_active,
+         packed) = self._dispatch_decode(view, sub, lp_k)
         self._d_lens = view.length
         # every row writes the shared cursor window; floors divert the
         # rows that must not commit (pending/idle/finished-and-lapped)
@@ -4407,24 +4435,8 @@ class SlotServer:
         # down to their own k at processing time
         lp_k = (LOGPROBS_MAX
                 if bool((self._np_lp[self._host_busy] > 0).any()) else 0)
-        (self._cache, self._d_tokens, self._d_active, packed) = _decode_block(
-            self._params, self._fused, self._cache,
-            self._d_tokens, self._d_active, self._d_target,
-            self._d_offsets, jnp.int32(self._cursor), self._d_temps,
-            self._d_topks, sub,
-            cfg=self.cfg, block=self.block_size,
-            stop_tokens=self.stop_tokens, pad_id=self.pad_id,
-            top_k=self.top_k,
-            # _host_busy never goes False while a row is still active on
-            # device, so these are safe whenever they say all-greedy /
-            # nobody-overrides-k
-            per_row_topk=bool(
-                (self._np_topks[self._host_busy] != self.top_k).any()),
-            weight_dtype=self.weight_dtype, build_fused=self._build_fused,
-            all_greedy=not bool(
-                (self._np_temps[self._host_busy] > 0).any()),
-            lp_k=lp_k,
-            shardings=self._shardings)
+        (self._cache, self._d_tokens, self._d_active,
+         packed) = self._dispatch_decode(self._cache, sub, lp_k)
         self._cursor = (self._cursor + self.block_size) % self.max_len
         self.blocks_dispatched += 1
         # host DISPATCH time (the program runs async): what a decode
@@ -4602,9 +4614,9 @@ class SlotServer:
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
-            e_touched, e_read, e_held, e_asg, e_tiles = (
+            e_touched, e_read, e_held, e_asg = (
                 self.experts_touched, self.experts_read, self.experts_held,
-                self.expert_assignments, self.expert_tiles)
+                self.expert_assignments)
             self._span_tokens_max = 0
             tokens = self._bookkeep(recs, flat, lags)
             span.set_metadata(tokens=tokens,
@@ -4620,7 +4632,6 @@ class SlotServer:
                     experts_touched=self.experts_touched - e_touched,
                     experts_read=self.experts_read - e_read,
                     experts_held=held,
-                    expert_tiles=self.expert_tiles - e_tiles,
                     expert_tokens_max=self._span_tokens_max,
                     expert_tokens_mean=self._expert_tokens_mean(
                         self.expert_assignments - e_asg, held))
@@ -4678,17 +4689,15 @@ class SlotServer:
             picks = None
             if self._routed:
                 # the routed layers' columns (`_decode_block`): the experts
-                # chosen, then the block's four counts in their first row
+                # chosen, then the block's three counts in their first row
                 n = routed_columns(self.cfg, self.block_size)
-                touched, streamed, busiest, tiles = (
-                    int(v) for v in packed[0, -4:])
-                picks = packed[:, -n:-4].reshape(
+                touched, streamed, busiest = (int(v) for v in packed[0, -3:])
+                picks = packed[:, -n:-3].reshape(
                     self.slots, self.block_size, self.cfg.n_routed_layers,
                     self.cfg.moe_top_k)
                 packed = packed[:, :-n]
                 self.experts_touched += touched
                 self.experts_read += streamed
-                self.expert_tiles += tiles
                 self.experts_held += (self.cfg.experts_held[1]
                                       * self.cfg.n_routed_layers
                                       * self.block_size)

@@ -879,25 +879,31 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
     in the same way (`latent_project` and its neighbours, around the
     caller's rows). ``expert_kernel`` is handed to `_mlp` (a routed layer's
     decode-step grouped matmul). Returns (x, aux, kv), ``aux`` as `_mlp`
-    has it."""
+    has it.
+
+    The two branches are the step's parts "mixer" and "mlp"
+    (``observability.STEP_PARTS``), each with its norm and its residual
+    add, so that every program built on this block is measured by them."""
     pre = cfg.norm_order == "pre"
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
-    if recur is not None:
-        mixed, kv = recur(kv, h, lp)
-    else:
-        q, k, v = _qkv(cfg, h, positions, lp)
-        attn, kv = attend(kv, q, k, v)
-        mixed = _attn_out(cfg, attn, lp)
-    if not pre:
-        mixed = rms_norm(mixed, lp["attn_norm"], cfg.norm_eps)
-    x = x + mixed
-    if pre:
-        mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp,
-                            expert_kernel)
-    else:
-        mlp_out, aux = _mlp(cfg, x, lp, expert_kernel)
-        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.norm_eps)
-    return x + mlp_out, aux, kv
+    with jax.named_scope("mixer"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
+        if recur is not None:
+            mixed, kv = recur(kv, h, lp)
+        else:
+            q, k, v = _qkv(cfg, h, positions, lp)
+            attn, kv = attend(kv, q, k, v)
+            mixed = _attn_out(cfg, attn, lp)
+        if not pre:
+            mixed = rms_norm(mixed, lp["attn_norm"], cfg.norm_eps)
+        x = x + mixed
+    with jax.named_scope("mlp"):
+        if pre:
+            mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps),
+                                lp, expert_kernel)
+        else:
+            mlp_out, aux = _mlp(cfg, x, lp, expert_kernel)
+            mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.norm_eps)
+        return x + mlp_out, aux, kv
 
 
 def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
@@ -928,15 +934,9 @@ def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
     return x, aux
 
 
-def apply_hidden(
-    params: dict,
-    tokens: jax.Array,          # [B, L] int32
-    cfg: TransformerConfig,
-    mesh=None,
-) -> tuple[jax.Array, jax.Array]:
-    """Forward pass up to (and including) the final norm -> (hidden
-    [B, L, D], aux_loss scalar). The unembed projection is left to the
-    caller so the loss can stream it blockwise."""
+def _hidden(params, tokens, cfg: TransformerConfig, mesh=None):
+    """The embedding and the layers -> (hidden [B, L, D] before the final
+    norm, the layers' aux losses)."""
     dt = cfg.dtype
     b, l = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(l), (b, l))
@@ -979,6 +979,19 @@ def apply_hidden(
         for i in range(cfg.n_layers):
             kind, _, lp = layer_at(cfg, params["layers"], i)
             x, _ = layer_fns[kind](x, positions, lp)
+    return x, auxes
+
+
+def apply_hidden(
+    params: dict,
+    tokens: jax.Array,          # [B, L] int32
+    cfg: TransformerConfig,
+    mesh=None,
+) -> tuple[jax.Array, jax.Array]:
+    """Forward pass up to (and including) the final norm -> (hidden
+    [B, L, D], aux_loss scalar). The unembed projection is left to the
+    caller so the loss can stream it blockwise."""
+    x, auxes = _hidden(params, tokens, cfg, mesh)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, jnp.sum(auxes) * cfg.aux_loss_weight
 
@@ -1070,9 +1083,14 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
 
     With blockwise CE (cfg.ce_impl, default at large vocab) the [B, L, V]
     logits tensor is never materialized — the unembed matmul, the softmax
-    and the loss's gradients go a chunk of rows at a time."""
-    x, aux = apply_hidden(params, tokens, cfg, mesh)
-    return token_nll(x, params["unembed"], targets, cfg, mesh, rules) + aux
+    and the loss's gradients go a chunk of rows at a time. The final norm
+    and everything after it are the step's part "loss"
+    (``observability.STEP_PARTS``), in the order `apply_hidden` has them."""
+    x, auxes = _hidden(params, tokens, cfg, mesh)
+    with jax.named_scope("loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        aux = jnp.sum(auxes) * cfg.aux_loss_weight
+        return token_nll(x, params["unembed"], targets, cfg, mesh, rules) + aux
 
 
 def num_params(params) -> int:

@@ -61,6 +61,11 @@ shared observability layer every serving component feeds:
   above runs on ``time.monotonic()`` beside the device trace, these
   lie in it, so a capture shows what the host was doing under each
   device gap.
+- **``STEP_PARTS``** — the parts of a device step, named by
+  ``jax.named_scope`` in the programs; ``step_parts`` maps a compiled
+  program's instructions to them, and ``PROGRAMS`` (``register_program``
+  / ``program_parts``) keeps the programs the process dispatched, so a
+  trace's device time can be summed by part after the fact.
 
 See docs/observability.md for metric names, the trace schema, and a
 scrape example.
@@ -768,6 +773,181 @@ def phase(name: str, **counts: int):
     if annotation is None:
         return _NO_SPAN
     return annotation(name, **counts)
+
+
+# ------------------------------------------------ the device step by part
+
+# The parts of one device step, as the programs name them with
+# ``jax.named_scope`` where each is written once: ``mixer`` and ``mlp``
+# (models/transformer.py ``decoder_layer``: each branch with its norm and
+# its residual add), ``router`` and ``experts`` (parallel/routed_experts.py),
+# ``unembed`` (the final norm, the unembedding and the sample:
+# models/generate.py, the decode block of models/serving.py), ``loss``
+# (models/transformer.py ``loss_fn``), ``optimizer`` (train/step.py) and
+# ``counts`` (the count columns the decode block computes for the host).
+# An instruction's part is the innermost of these on its scope path, and
+# STEP_OTHER under none; a fusion carries its root's path.
+STEP_PARTS = ("mixer", "mlp", "router", "experts", "unembed", "loss",
+              "optimizer", "counts")
+STEP_OTHER = "other"
+
+_HLO_NAME = re.compile(r"\s*(?:ROOT\s+)?%([^\s=]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE_WORD = re.compile(r"[A-Za-z0-9_]+")
+# "%name = <shape> <opcode>": how an instruction's line of the module's text
+# and a device event that ran it both begin
+_HLO_SIGNATURE = re.compile(r"\s*(?:ROOT\s+)?(%[^\s=]+ = .*? [a-z][a-z0-9\-]*)\(")
+
+
+def _part_of(op_name: str) -> str:
+    part = STEP_OTHER
+    for word in _SCOPE_WORD.findall(op_name):
+        if word in STEP_PARTS:
+            part = word
+    return part
+
+
+def step_parts(hlo_text: str) -> dict:
+    """{instruction name: part} of an optimized HLO module's text
+    (``Compiled.as_text()``). An instruction's ``op_name`` metadata is its
+    scope path (``jit(f)/while/body/closed_call/mlp/dot_general``); its part
+    is the innermost of ``STEP_PARTS`` on that path, so ``experts`` inside
+    ``mlp`` is ``experts`` and a backward's ``transpose(jvp(mlp))`` is
+    ``mlp``; an instruction under none, or with no metadata, is
+    ``STEP_OTHER``. Instruction names are unique within a module, and a
+    device trace's "XLA Ops" event begins with the name of the one it ran."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_NAME.match(line)
+        if m is not None:
+            op = _HLO_OP_NAME.search(line)
+            out[m.group(1)] = _part_of(op.group(1)) if op else STEP_OTHER
+    return out
+
+
+def _signatures(hlo_text: str) -> dict:
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_SIGNATURE.match(line)
+        if m is not None:
+            out[m.group(1).split(" = ", 1)[0][1:]] = m.group(1)
+    return out
+
+
+def _abstract(x):
+    """An array's shape, dtype and, where it is committed to one, sharding
+    (what jit saw when it was dispatched); anything else as it is."""
+    import jax
+
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    return x
+
+
+class _Program:
+    """A device program as it was dispatched: the jitted callable, its
+    static arguments and its arguments' abstract values, never an array."""
+
+    def __init__(self, fn, args, statics: dict):
+        # the module name jit gives the program (its own sanitizing)
+        self.module = re.sub(r"[^\w.-]", "_", "jit_" + fn.__name__).rstrip("_")
+        self.fn, self.args, self.statics = fn, args, statics
+        self._parsed = None
+
+    def parsed(self) -> tuple:
+        """-> ({instruction: part}, {instruction: signature}) of the
+        compiled program, lowered and compiled on the first call: JAX's
+        caches hold what the dispatch built, so nothing is built anew."""
+        if self._parsed is None:
+            text = self.fn.lower(*self.args, **self.statics).compile().as_text()
+            self._parsed = (step_parts(text), _signatures(text))
+        return self._parsed
+
+
+class ProgramRegistry:
+    """The device programs a process dispatched, one entry a variant (the
+    callable, its static arguments and its arguments' shapes, dtypes and
+    shardings), noted by the dispatching code the first time it runs a
+    variant. Nothing is lowered until ``parts`` is asked: the serving and
+    training loops pay for one set look-up of their own a dispatch."""
+
+    def __init__(self):
+        self._programs: dict = {}
+        self._lock = threading.Lock()
+
+    def register(self, fn, args, statics: dict | None = None) -> None:
+        import jax
+
+        statics = dict(statics or {})
+        abstract = jax.tree.map(_abstract, args)
+        key = (fn, jax.tree.structure(abstract),
+               tuple(jax.tree.leaves(abstract)), tuple(sorted(statics.items())))
+        with self._lock:
+            self._programs.setdefault(key, _Program(fn, abstract, statics))
+
+    def _named(self, module: str) -> list:
+        base, paren, _ = module.partition("(")
+        with self._lock:
+            return [p for p in self._programs.values()
+                    if (p.module == base if paren else base in p.module)]
+
+    def parts(self, module: str, ops=None) -> dict | None:
+        """{instruction name: part} of the registered program ``module``
+        names: the name of an "XLA Modules" event (``jit__decode_block(
+        <number>)``), or a part of one. ``ops``, the "XLA Ops" events' names
+        from that program's runs, tells its variants apart: a variant whose
+        instruction of that name begins otherwise (another shape, another
+        opcode) did not run them. None where no registered variant fits, or
+        where those that fit map the instructions seen to different parts:
+        no guess."""
+        maps = [p.parsed() for p in self._named(module)]
+        names = None
+        if ops is not None:
+            seen = _signatures("\n".join(ops))
+            maps = [(parts, sigs) for parts, sigs in maps
+                    if all(sigs.get(n) == s for n, s in seen.items())]
+            names = seen.keys()
+        if not maps:
+            return None
+        first = maps[0][0]
+        for parts, _ in maps[1:]:
+            if (parts != first if names is None
+                    else any(parts.get(n) != first.get(n) for n in names)):
+                return None
+        return first
+
+    def maps(self) -> dict:
+        """{module: {instruction: part}} of every registered program; a
+        module that ran in several variants is keyed ``<module> #<i>`` from
+        its second."""
+        with self._lock:
+            programs = list(self._programs.values())
+        out: dict = {}
+        for p in programs:
+            label, i = p.module, 1
+            while label in out:
+                i += 1
+                label = f"{p.module} #{i}"
+            out[label] = p.parsed()[0]
+        return out
+
+
+# the process-global registry the serving and training code note their
+# programs in, and the benchmark's readers and serve's capture ask
+PROGRAMS = ProgramRegistry()
+
+
+def register_program(fn, args, statics: dict | None = None) -> None:
+    """Note the device program ``fn(*args, **statics)`` (``fn`` jitted) in
+    ``PROGRAMS``; call it once a variant, before its first dispatch."""
+    PROGRAMS.register(fn, args, statics)
+
+
+def program_parts(module: str, ops=None) -> dict | None:
+    """``PROGRAMS.parts``: {instruction name: part} of the program an
+    "XLA Modules" event names, or None."""
+    return PROGRAMS.parts(module, ops)
 
 
 # the jax.monitoring event that fires once per actual XLA compilation

@@ -27,8 +27,11 @@ layer (models/transformer.py):
   not 256; a prefill
   (4-32 an expert), the CPU and a mesh keep ``jax.lax.ragged_dot``,
   which XLA lowers to its own grouped matmul at a 256-row tile. Both
-  visit only the (row tile, expert) pairs that hold rows; `tile_visits`
-  counts the kernel's.
+  visit only the (row tile, expert) pairs that hold rows.
+- **Parts of a step** (``observability.STEP_PARTS``): the scoring, the
+  selection, the sort, the gathers and the combine back into token order
+  are "router"; the two grouped matmuls and the SwiGLU between them are
+  "experts".
 - **The experts held** are an argument: ``held = (first, count)`` says
   which contiguous range of the ``E`` experts ``w_gu`` / ``w_down`` carry
   (a chip's share under expert parallelism). Routing is over all ``E``
@@ -58,13 +61,14 @@ TILE_ROWS = 16
 def route(x, router, router_bias, *, top_k: int, scale: float):
     """Tokens x [T, d] -> (chosen [T, k] int32, weights [T, k] float32).
     The scores, the selection and the weights are float32."""
-    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
-    w = jnp.take_along_axis(s, chosen, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
-    return chosen.astype(jnp.int32), w
+    with jax.named_scope("router"):
+        logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+        return chosen.astype(jnp.int32), w
 
 
 def expert_load(chosen, live=None, *, n_experts: int):
@@ -75,17 +79,6 @@ def expert_load(chosen, live=None, *, n_experts: int):
         ones = ones * live.astype(jnp.int32)[:, None]
     return jnp.zeros((n_experts,), jnp.int32).at[chosen.reshape(-1)].add(
         ones.reshape(-1))
-
-
-def tile_visits(sizes, tm: int = TILE_ROWS):
-    """The (row tile, group) pairs `grouped_matmul` visits for groups of
-    ``sizes`` [..., G] rows laid one after another (megablox's
-    ``make_group_metadata`` without empty groups): a non-empty group
-    spans the ``tm``-row tiles from the one its first row lies in to the
-    one its last row lies in. Summed over every leading axis."""
-    ends = jnp.cumsum(sizes, axis=-1)
-    span = -(-ends // tm) - (ends - sizes) // tm
-    return jnp.sum(jnp.where(sizes > 0, span, 0))
 
 
 def grouped_matmul(lhs, rhs, sizes):
@@ -118,19 +111,23 @@ def routed_ffn(x, chosen, weights, w_gu, w_down, *, held: tuple,
     k = chosen.shape[1]
     first, count = held
     f = w_down.shape[1]
-    local = chosen.reshape(-1) - first                      # [T k]
-    mine = (local >= 0) & (local < count)
-    # an expert held elsewhere sorts past the last group
-    group = jnp.where(mine, local, count)
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
-    xs = x[order // k]                                      # [T k, d]
+    with jax.named_scope("router"):
+        local = chosen.reshape(-1) - first                  # [T k]
+        mine = (local >= 0) & (local < count)
+        # an expert held elsewhere sorts past the last group
+        group = jnp.where(mine, local, count)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+        xs = x[order // k]                                  # [T k, d]
     matmul = grouped_matmul if kernel else jax.lax.ragged_dot
-    gu = matmul(xs, w_gu.astype(x.dtype), sizes)
-    act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-    ys = matmul(act, w_down.astype(x.dtype), sizes)
-    # back to token order; rows past the last group hold nothing defined
-    w = jnp.where(mine, weights.reshape(-1), 0.0)
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
-    ys = jnp.where(mine[:, None], ys[inverse].astype(jnp.float32), 0.0)
-    return jnp.sum((ys * w[:, None]).reshape(t, k, d), axis=1).astype(x.dtype)
+    with jax.named_scope("experts"):
+        gu = matmul(xs, w_gu.astype(x.dtype), sizes)
+        act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        ys = matmul(act, w_down.astype(x.dtype), sizes)
+    with jax.named_scope("router"):
+        # back to token order; rows past the last group hold nothing defined
+        w = jnp.where(mine, weights.reshape(-1), 0.0)
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+        ys = jnp.where(mine[:, None], ys[inverse].astype(jnp.float32), 0.0)
+        return jnp.sum((ys * w[:, None]).reshape(t, k, d),
+                       axis=1).astype(x.dtype)

@@ -20,6 +20,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer
+from ..observability import register_program
 from ..parallel import sharding as shlib
 
 
@@ -118,17 +119,20 @@ def create_train_step(
         loss, grads = jax.value_and_grad(transformer.loss_fn)(
             params, tokens, targets, cfg, mesh, rules
         )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
+        # the step's part "optimizer" (observability.STEP_PARTS); the
+        # reported norm is the one the clip computes
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
-    step_fn = jax.jit(
+    step_fn = _RegisteredStep(jax.jit(
         step,
         in_shardings=(param_shardings, opt_shardings, tok_sharding, tok_sharding),
         out_shardings=(param_shardings, opt_shardings, None),
         donate_argnums=(0, 1),
-    )
+    ))
     def eval_loss(params, tokens, targets):
         return transformer.loss_fn(params, tokens, targets, cfg, mesh, rules)
 
@@ -146,6 +150,26 @@ def create_train_step(
     bundle.tok_sharding = tok_sharding
     bundle.eval_fn = eval_fn
     return bundle
+
+
+class _RegisteredStep:
+    """The jitted step, noting each batch shape it is first called with as
+    a device program (``observability.register_program``): one set look-up
+    a step. ``lower`` and the rest are the jitted function's own."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._seen: set = set()
+
+    def __call__(self, params, opt_state, tokens, targets):
+        variant = (tokens.shape, tokens.dtype)
+        if variant not in self._seen:
+            self._seen.add(variant)
+            register_program(self._jitted, (params, opt_state, tokens, targets))
+        return self._jitted(params, opt_state, tokens, targets)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
 
 
 def _opt_state_shardings(opt_state_shape, params, param_shardings, mesh):
