@@ -15,8 +15,11 @@ only one process may load libtpu, and every xdist worker imports this file.
 from __future__ import annotations
 
 import functools
+import hashlib
+import json
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -105,16 +108,7 @@ def test_flash_decode_compiles_for_v5e(one_chip, d, variant):
     q = sds((b, kvh, rep, d), jnp.bfloat16)
     length = sds((), jnp.int32)
     if variant in ("ring", "ring_mha"):
-        b, layers, window = 16, 8, 4096
-        if variant == "ring_mha":
-            kvh, rep, layers, window = 30, 1, 2, 0
-        cache = sds((layers, b, kvh, m, d), jnp.bfloat16)
-        rows = sds((b,), jnp.int32)
-        fn = jax.jit(lambda q, ck, cv, lengths, offsets, active: flash_decode(
-            q, ck, cv, lengths, ring_offsets=offsets, active=active,
-            layer=layers - 1, window=window))
-        _compiled_text(fn, sds((b, kvh, rep, d), jnp.bfloat16), cache, cache,
-                       rows, rows, sds((b,), jnp.bool_))
+        _per_head_text(one_chip, variant, d)
     elif variant == "int8":
         cache = sds((b, kvh, m, d), jnp.int8)
         scale = sds((b, kvh, m), jnp.bfloat16)
@@ -126,6 +120,92 @@ def test_flash_decode_compiles_for_v5e(one_chip, d, variant):
     else:
         cache = sds((b, kvh, m, d), jnp.bfloat16)
         _compiled_text(flash_decode, q, cache, cache, length)
+
+
+def test_flash_decode_latent_compiles_for_v5e(one_chip):
+    """The latent cell's call: the whole [5, 32, 4096, 576] stack of
+    latent rows, 32 rows of 32 heads sharing the one row, the layer an
+    operand, per-row lengths, offsets and the active mask. The stack is
+    read where the TPU lays it out (positions minor: 576 is not a multiple
+    of 128): the kernel's [R, M] view of it is a bitcast, and no copy of
+    it is made."""
+    from tony_tpu.ops.decode_attention import flash_decode_latent
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    stack, rows = (5, 32, 4096, 576), sds((32,), jnp.int32)
+    fn = jax.jit(lambda q, c, lengths, offsets, active: flash_decode_latent(
+        q, c, lengths, 4, v_dim=512, scale=192 ** -0.5, ring_offsets=offsets,
+        active=active))
+    compiled = fn.lower(sds((32, 32, 576), jnp.bfloat16),
+                        sds(stack, jnp.bfloat16), rows, rows,
+                        sds((32,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    made = re.findall(r" = bf16\[(?:5,32,4096,576|5,32,576,4096)\]\{[^}]*\} "
+                      r"([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "bitcast"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+PER_HEAD = json.loads((Path(__file__).parent / "fixtures"
+                       / "flash_decode_per_head.json").read_text())
+
+
+def _per_head_text(sharding, variant, d) -> str:
+    """The serving cells' per-head ``flash_decode`` call (``ring``: 8 KV
+    heads of 4 queries, 8 layers, a window; ``ring_mha``: 30 KV heads of
+    one, 2 layers) lowered for the described chip, as the two compilers
+    get it with nothing that names a source line: the Mosaic module of the
+    kernel printed without locations, and the StableHLO around it without
+    debug info or the kernel's serialized form (whose locations would
+    differ). The program is compiled too, to a Mosaic kernel."""
+    from jax._src import tpu_custom_call
+
+    from tony_tpu.ops.decode_attention import flash_decode
+
+    b, kvh, rep, m, layers, window = 16, 8, 4, 4096, 8, 4096
+    if variant == "ring_mha":
+        kvh, rep, layers, window = 30, 1, 2, 0
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    cache = sds((layers, b, kvh, m, d), jnp.bfloat16)
+    rows = sds((b,), jnp.int32)
+    fn = jax.jit(lambda q, ck, cv, lengths, offsets, active: flash_decode(
+        q, ck, cv, lengths, ring_offsets=offsets, active=active,
+        layer=layers - 1, window=window))
+    kernels = []
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def recorded(module, **kw):
+        kernels.append(module.operation.get_asm(enable_debug_info=False))
+        return serialize(module, **kw)
+
+    tpu_custom_call._lower_mosaic_module_to_asm = recorded
+    try:
+        lowered = fn.lower(sds((b, kvh, rep, d), jnp.bfloat16), cache, cache,
+                           rows, rows, sds((b,), jnp.bool_))
+    finally:
+        tpu_custom_call._lower_mosaic_module_to_asm = serialize
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    assert len(kernels) == 1
+    return kernels[0] + "\n" + re.sub(r"\\22body\\22: \\22[^\\]*\\22", "",
+                                      lowered.as_text())
+
+
+@pytest.mark.parametrize("variant", ("ring", "ring_mha"))
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_decode_per_head_compiles_as_it_did(one_chip, d, variant):
+    """The per-head kernel and the program around it are the text they
+    were before the latent form joined the module (the fixture says how
+    the digests were taken; another jax or libtpu prints another text)."""
+    import importlib.metadata
+
+    versions = {"jax": jax.__version__,
+                "libtpu": importlib.metadata.version("libtpu")}
+    if versions != PER_HEAD["versions"]:
+        pytest.skip(f"digests are of {PER_HEAD['versions']}")
+    text = _per_head_text(one_chip, variant, d)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PER_HEAD["sha256"][f"{variant}[{d}]"]
 
 
 def test_flash_under_a_four_chip_mesh_compiles_for_v5e(topo):

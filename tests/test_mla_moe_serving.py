@@ -436,6 +436,106 @@ def test_the_decode_kernel_serves_the_reference(prepared, tcfg, monkeypatch,
     assert "tiles" not in e
 
 
+@pytest.mark.parametrize("backend, l_new, sharded, m_cap, engages", [
+    ("cpu", 1, False, 4096, False),     # the CPU keeps the einsum
+    ("tpu", 1, False, 4096, True),      # a decode step over a long ring
+    ("tpu", 8, False, 4096, False),     # a block of positions: the einsum
+    ("tpu", 1, True, 4096, False),      # under a mesh: a shard_map away
+    ("tpu", 1, False, 2048, False),     # a short ring: the einsum wins
+])
+def test_who_runs_the_latent_decode_kernel(prepared, tcfg, monkeypatch,
+                                           backend, l_new, sharded, m_cap,
+                                           engages):
+    """The gate on what the caller observes (``decode_kernel_engages`` on
+    the backend and the ring's length, one new token a row, no mesh), and
+    what it traces: `_latent_attention` runs ``flash_decode_latent`` on the
+    whole stack or the einsum on the layer's rows, and `_forward_with_cache`
+    calls the kernel once a latent layer, all of one traced pallas_call
+    (the layer is an operand)."""
+    import importlib
+
+    generate = importlib.import_module("tony_tpu.models.generate")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert generate.decode_kernel_engages(tcfg, m_cap) is (
+        backend == "tpu" and m_cap >= 4096)
+    cache = init_cache(tcfg, 2, m_cap)
+    q = jnp.zeros((2, l_new, 4, 40), jnp.float32)
+    lengths = jnp.zeros((2,), jnp.int32) + (m_cap - l_new)
+    jaxpr = str(jax.make_jaxpr(
+        lambda q, lat: generate._latent_attention(
+            tcfg, q, lat, lengths, l_new, jnp.zeros((2,), jnp.int32),
+            layer_idx=1, allow_kernel=not sharded))(q, cache.latent))
+    assert jaxpr.count("pallas_call") == (1 if engages else 0)
+    if sharded:
+        return      # SlotServer refuses a latent cache over a mesh
+    # (the routed layers' kernels, which their own gate opens, shut here)
+    monkeypatch.setattr(generate, "expert_kernel_engages", lambda *a: False)
+    jaxpr = str(jax.make_jaxpr(
+        lambda p, t, c: _forward_with_cache(p, tcfg, t, c))(
+            prepared.params, jnp.zeros((2, l_new), jnp.int32), cache))
+    assert jaxpr.count("name=flash_decode_latent") == (3 if engages else 0)
+    assert jaxpr.count("pallas_call") == (1 if engages else 0)
+
+
+def test_the_latent_decode_kernel_serves_the_reference(prepared, tcfg,
+                                                       monkeypatch):
+    """With the gate forced open (the kernel interpreted, in blocks of 16
+    positions of the 64-position rings), the served tokens are the
+    reference's; and ``kv_blocks_read`` on the bookkeep spans and on
+    ``SlotServer`` is ``live_kv_blocks`` of each processed block in that
+    unit (its final lengths, the offsets it was dispatched with, the rows
+    that emitted in it), below ``kv_blocks_ring``, for blocks in which a
+    row stops mid-block among them."""
+    import importlib
+
+    from tony_tpu.ops import decode_attention
+
+    generate = importlib.import_module("tony_tpu.models.generate")
+    for module in (generate, serving):
+        monkeypatch.setattr(module, "decode_kernel_engages",
+                            lambda *a: True)
+    for module in (decode_attention, serving):
+        monkeypatch.setattr(module, "kv_block_k", lambda *a: 16)
+    seen = []
+    monkeypatch.setattr(serving, "phase", _recording_phase(seen))
+    # the gate is read when `_decode_block` is traced: a block size no
+    # other test compiles
+    block = 5
+    server = SlotServer(prepared, tcfg, **dict(ENGINE, block_size=block))
+    blocks = []
+    count = server._count_kv_blocks
+
+    def counted(rec, lengths, active):
+        before = server.kv_blocks_read
+        expect = np.array(server._expect_len)
+        count(rec, lengths, active)
+        blocks.append((np.array(rec["offsets"]), np.array(lengths),
+                       np.array(active), expect,
+                       server.kv_blocks_read - before))
+
+    monkeypatch.setattr(server, "_count_kv_blocks", counted)
+    rng = np.random.default_rng(12)
+    reqs = [_request(rng, n, m) for n, m in ((4, 12), (11, 8), (20, 3))]
+    for r in reqs:
+        server.submit(r)
+    done = server.run_until_drained()
+    for r in reqs:
+        _check(r, done[r.id])
+    mid_block = 0
+    for offsets, lengths, active, expect, read in blocks:
+        emitted = lengths > expect
+        want = decode_attention.live_kv_blocks(
+            offsets, lengths, active | emitted, block_k=16, m_cap=MAX_LEN)[1]
+        assert read == int(want.sum())
+        mid_block += int((emitted & ~active
+                          & (lengths - expect < block)).any())
+    assert mid_block >= 1
+    spans = [c for name, c in seen if name == "serve.step.bookkeep"]
+    assert sum(c["kv_blocks_read"] for c in spans) == server.kv_blocks_read
+    assert 0 < server.kv_blocks_read < server.kv_blocks_ring
+    assert server.kv_blocks_ring == len(blocks) * ENGINE["slots"] * 4
+
+
 # ------------------------------------------------------------- refusals
 
 @pytest.mark.parametrize("kwargs, message", [

@@ -586,6 +586,65 @@ def test_flash_decode_ring_matches_cached_attention_einsum(case):
     assert not np.asarray(out)[~live].any()
 
 
+# (lengths, offsets, active, M) for a 4-row ring of latent rows read in
+# blocks of 64; each case against `_latent_attention`'s einsum
+_LATENT_CASES = {
+    "random_offsets_and_lengths":
+        ([17, 100, 201, 63], [5, 200, 130, 250], None, 256),
+    "rows_wrap_across_m":
+        ([90, 130, 40, 255], [250, 192, 255, 1], None, 256),
+    "some_rows_not_active":
+        ([37, 100, 0, 200], [9, 250, 3, 77], [True, False, False, True],
+         256),
+    "no_row_active":
+        ([37, 100, 5, 200], [9, 250, 3, 77], [False] * 4, 256),
+    "ragged_tail":
+        ([199, 150, 30, 120], [0, 100, 190, 185], [True, True, True, False],
+         200),
+    "one_live_position":
+        ([0, 0, 0, 3], [0, 63, 199, 64], None, 200),
+}
+
+
+@pytest.mark.parametrize("case", _LATENT_CASES)
+def test_flash_decode_latent_matches_the_absorbed_einsum(case):
+    """The absorbed form in the decode kernel: one latent row R wide the
+    keys of every head and its first kv_rank features their values, on
+    the flash_decode contract (lengths, ring offsets, active rows), read
+    out of layer 1 of a 3-layer stack whose other layers are NaN: the
+    kernel reads only the layer it is given."""
+    from tony_tpu.models.generate import _latent_attention
+    from tony_tpu.models.transformer import TransformerConfig, latent_scale
+    from tony_tpu.ops.decode_attention import flash_decode_latent
+
+    lengths, offsets, active, M = _LATENT_CASES[case]
+    Ly, B, H, kv_rank, rope = 3, 4, 4, 32, 8
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=64, n_layers=Ly, n_heads=H, n_kv_heads=H,
+        d_ff=64, max_seq_len=M, dtype=jnp.float32,
+        layer_kinds=("latent",) * Ly, lat_q_rank=16, lat_kv_rank=kv_rank,
+        lat_nope_dim=16, lat_rope_dim=rope, lat_v_dim=16)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 2)
+    q = jax.random.normal(ks[0], (B, 1, H, kv_rank + rope), jnp.float32)
+    rows = jax.random.normal(ks[1], (B, M, kv_rank + rope), jnp.float32)
+    stack = jnp.full((Ly, B, M, kv_rank + rope), jnp.nan).at[1].set(rows)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    offsets = jnp.asarray(offsets, jnp.int32)
+    ref = _latent_attention(cfg, q, rows, lengths, 1, offsets)[:, 0]
+    out = flash_decode_latent(
+        q[:, 0], stack, lengths, 1, v_dim=kv_rank, scale=latent_scale(cfg),
+        ring_offsets=offsets,
+        active=None if active is None else jnp.asarray(active),
+        block_k=64, interpret=True)
+    assert out.shape == (B, H, kv_rank)
+    live = np.ones(B, bool) if active is None else np.asarray(active)
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live, :, :kv_rank],
+                               rtol=2e-5, atol=2e-5)
+    # a row that is not active reads nothing: zeros, whatever its ring holds
+    assert not np.asarray(out)[~live].any()
+
+
 def test_flash_under_a_mesh_runs_in_a_shard_map_and_matches_reference():
     """GSPMD cannot partition a Mosaic kernel, so on a mesh of several
     devices the model wraps the flash call in a shard_map over batch and

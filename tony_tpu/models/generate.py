@@ -365,14 +365,33 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
     return out.reshape(b, l, h, d)
 
 
-def _latent_attention(cfg, q_lat, rows, cache_len, l_new, ring_offsets=None):
+def _latent_attention(cfg, q_lat, rows, cache_len, l_new, ring_offsets=None,
+                      layer_idx=None, active=None, allow_kernel=False):
     """The absorbed form's attention: q_lat [B, L, H, R] (`transformer
-    .latent_absorb`) against a latent layer's cached rows [B, M, R], which
-    already hold the new positions -> sum p row [B, L, H, R]. To
+    .latent_absorb`) against a latent layer's cached rows [B, M, R] (or
+    layer ``layer_idx`` of the [Ly, B, M, R] stack), which already hold
+    the new positions -> sum p row [B, L, H, >= kv_rank]. To
     `_cached_attention` this is attention with ONE K/V head R wide that
     every query head shares and whose values are its keys: the same
-    masks, lengths and ring offsets, the einsum always (the decode kernel
-    is built for per-head K and V of head_dim)."""
+    masks, lengths and ring offsets. Where the decode kernel may run
+    (``allow_kernel``, one new token a row, ``decode_kernel_engages``) it
+    is ``flash_decode_latent`` on the whole stack: each live block of a
+    row's rows read once, a row that is not ``active`` reading nothing;
+    elsewhere the einsum on the layer's rows, over the whole ring."""
+    b, l, h, r = q_lat.shape
+    if (allow_kernel and l == 1
+            and decode_kernel_engages(cfg, rows.shape[-2])):
+        from ..ops.decode_attention import flash_decode_latent
+
+        out = flash_decode_latent(
+            q_lat.reshape(b, h, r), rows, cache_len, layer_idx,
+            v_dim=cfg.lat_kv_rank, scale=transformer.latent_scale(cfg),
+            ring_offsets=ring_offsets, active=active,
+            window=cfg.attn_window or 0,
+            interpret=jax.default_backend() != "tpu")
+        return out.reshape(b, 1, h, cfg.lat_kv_rank)
+    if layer_idx is not None:
+        rows = rows[layer_idx]
     kv = rows[:, None]
     return _cached_attention(
         cfg, q_lat, kv, kv, cache_len, l_new, ring_offsets=ring_offsets,
@@ -579,7 +598,9 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
 
     A latent layer (``cfg.layer_kinds``) stores its positions' rows [c_kv
     | k_r] in ``cache.latent`` at the same shared offset and attends in the
-    absorbed form (`_latent_attention`: one read of a row for all heads),
+    absorbed form (`_latent_attention`: one read of a row for all heads;
+    a decode step on the TPU outside a mesh, ``flash_decode_latent`` over
+    the live blocks of the whole stack, as ``attend`` is ``flash_decode``),
     over the block's own rows on a prefill's empty cache. ``routes=True``
     adds a third result: the experts each of the L positions chose in each
     routed layer (``cfg.mlp_kinds``), [routed layers, B, L, k] int32. A
@@ -692,8 +713,10 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         if prefill:
             o = _latent_attention(cfg, q_lat, row, zero, l)
         else:
-            o = _latent_attention(cfg, q_lat, lat[layer], cache.length, l,
-                                  ring_offsets)
+            o = _latent_attention(cfg, q_lat, lat, cache.length, l,
+                                  ring_offsets, layer_idx=layer,
+                                  active=ring_active,
+                                  allow_kernel=shardings is None)
         return transformer.latent_out(cfg, o, lp), (kv, rec, lat)
 
     mixers = {"linear": recur, "latent": latent}

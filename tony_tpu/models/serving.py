@@ -29,10 +29,11 @@ Design — everything stays one compiled program over static shapes:
   would need dynamic shapes, and a masked row costs the same HBM stream
   the active rows already pay (decode is weight-bound; the weight read is
   shared). The cache read is NOT shared: on a TPU with a ring of 4096 or
-  more the step's attention is ``flash_decode`` (ops/decode_attention.py),
-  which takes each slot's (length, offset, active) and streams only the KV
-  blocks that hold a position the slot's query may see — nothing for an
-  inactive slot. Per-row EOS/budget masks freeze finished rows' lengths
+  more the step's attention is ``flash_decode`` (ops/decode_attention.py;
+  ``flash_decode_latent`` for a latent layer's rows), which takes each
+  slot's (length, offset, active) and streams only the KV blocks that
+  hold a position the slot's query may see — nothing for an inactive
+  slot. Per-row EOS/budget masks freeze finished rows' lengths
   in-device so a row that stops mid-block stays exactly where it stopped.
 - **Chunked prefill into a slot's ring, one dispatch per chunk round.**
   A new request's prompt (all but its last token) is fed through the
@@ -2190,15 +2191,15 @@ class SlotServer:
         self.prefill_chunk = prefill_chunk
         self.kv_dtype = kv_dtype
         # the unit of the kv_blocks_read / kv_blocks_ring counts, and
-        # whether the decode block's attention reads by it at all
-        # (a latent layer's ring is one "head" of its row's width, and
-        # its attention is the einsum over the whole ring)
+        # whether the decode block's attention reads by it at all (a
+        # latent layer's ring is one "head" of its row's width, read by
+        # flash_decode_latent in that unit)
         latent = self.cfg.n_latent_layers > 0
         self._kv_block_k = kv_block_k(
             max_len, 1 if latent else self.cfg.n_kv_heads,
             self.cfg.lat_row_dim if latent else self.cfg.head_dim,
             1 if kv_dtype == "int8" else jnp.dtype(self.cfg.dtype).itemsize)
-        self._decode_kernel = (self._shardings is None and not latent
+        self._decode_kernel = (self._shardings is None
                                and decode_kernel_engages(self.cfg, max_len))
         # whether the decode block's recurrence reads the live rows only
         self._state_kernel = state_kernel_engages(

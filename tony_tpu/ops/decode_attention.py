@@ -38,6 +38,13 @@ pre-multiply the (tiny) probability row — the same scale-folding
 discipline as the XLA path, so no dequantized copy of the cache ever
 exists anywhere.
 
+``flash_decode_latent`` walks the same work list in the absorbed form of
+latent attention (models/transformer.py ``latent_absorb``): one cached
+row [c_kv | k_r] a position that every head reads as its key and whose
+first kv_rank features are the value, so a grid step streams one block
+of a row's latent rows once for all heads. It has its own kernel body
+and shares the work list, the mask and ``live_kv_blocks``.
+
 The current token's K/V must already be written to the cache (the
 write-then-attend order generate uses).
 
@@ -107,6 +114,48 @@ def _wrap(blk, n_blocks):
     return jnp.where(blk >= n_blocks, blk - n_blocks, blk)
 
 
+def _visible(shape, dim, *, blk, row, off_ref, length, block_k, m_cap,
+             window):
+    """Mask of block ``blk``'s ring indices (along ``dim`` of ``shape``)
+    whose logical position (index - offset) mod M the query of ``row`` at
+    ``length`` may see."""
+    idx = blk * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+    pos = idx - off_ref[row]
+    pos = jnp.where(pos < 0, pos + m_cap, pos)
+    ok = pos <= length
+    if window:
+        ok &= pos > length - window
+    if m_cap % block_k:
+        ok &= idx < m_cap                               # the ragged tail
+    return ok
+
+
+def _work_list(length, ring_offsets, active, *, b, block_k, m_cap, window):
+    """The grid of a decode kernel -> (row lengths [B], offsets [B], row
+    of each step [B * n_blocks], each row's block count [B] and first
+    block [B], the rows' running count of steps [B]). Grid step s is
+    block (s - (ends - count)[b]) of row b = ``row_of[s]``: the blocks
+    ``live_kv_blocks`` names, row after row, ``ends[-1]`` steps."""
+    n_blocks = pl.cdiv(m_cap, block_k)
+    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
+    offsets = (jnp.zeros((b,), jnp.int32) if ring_offsets is None
+               else ring_offsets.astype(jnp.int32))
+    live = jnp.ones((b,), bool) if active is None else active
+    first, count = live_kv_blocks(offsets, lengths, live, block_k=block_k,
+                                  m_cap=m_cap, window=window, xp=jnp)
+    ends = jnp.cumsum(count)
+    steps = jnp.arange(b * n_blocks)
+    row_of = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1), b - 1)
+    return lengths, offsets, row_of, count, first, ends
+
+
+def _block_of(s, row_ref, start_ref, count_ref, first_ref, *_, n_blocks):
+    """(row, ring block) that grid step ``s`` of the work list streams."""
+    row = row_ref[s]
+    return row, _wrap(first_ref[row] + s - start_ref[row], n_blocks)
+
+
 def _decode_kernel(row_ref, start_ref, count_ref, first_ref, len_ref, off_ref,
                    q_ref, k_ref, v_ref, ks_ref, vs_ref, zero_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale, block_k, n_blocks,
@@ -139,20 +188,9 @@ def _decode_kernel(row_ref, start_ref, count_ref, first_ref, len_ref, off_ref,
         if ks_ref is not None:
             s = s * ks_ref[...].astype(jnp.float32)     # [kvH, 1, block_k]
 
-        def visible(shape, dim):
-            """Mask of the block's ring indices (along ``dim``) whose
-            logical position (index - offset) mod M the query may see."""
-            idx = blk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, shape, dim)
-            pos = idx - off_ref[b]
-            pos = jnp.where(pos < 0, pos + m_cap, pos)
-            ok = pos <= length
-            if window:
-                ok &= pos > length - window
-            if m_cap % block_k:
-                ok &= idx < m_cap                       # the ragged tail
-            return ok
-
+        visible = functools.partial(
+            _visible, blk=blk, row=b, off_ref=off_ref, length=length,
+            block_k=block_k, m_cap=m_cap, window=window)
         mask = visible(s.shape, 2)
         s = jnp.where(mask, s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
@@ -239,22 +277,10 @@ def flash_decode(q, ck, cv, length, k_scale=None, v_scale=None, *,
                if block_k is None else min(block_k, m_cap))
     n_blocks = pl.cdiv(m_cap, block_k)
     int8 = k_scale is not None
-
-    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
-    offsets = (jnp.zeros((b,), jnp.int32) if ring_offsets is None
-               else ring_offsets.astype(jnp.int32))
-    live = jnp.ones((b,), bool) if active is None else active
-    first, count = live_kv_blocks(offsets, lengths, live, block_k=block_k,
-                                  m_cap=m_cap, window=window, xp=jnp)
-    # the work list: grid step s is block (s - start_b) of row b = row_of[s]
-    ends = jnp.cumsum(count)
-    steps = jnp.arange(b * n_blocks)
-    row_of = jnp.minimum(
-        jnp.sum(steps[:, None] >= ends[None, :], axis=1), b - 1)
-
-    def kv_block(s, row_ref, start_ref, count_ref, first_ref, *_):
-        row = row_ref[s]
-        return row, _wrap(first_ref[row] + s - start_ref[row], n_blocks)
+    lengths, offsets, row_of, count, first, ends = _work_list(
+        length, ring_offsets, active, b=b, block_k=block_k, m_cap=m_cap,
+        window=window)
+    kv_block = functools.partial(_block_of, n_blocks=n_blocks)
 
     def kv_index(s, *refs):
         row, blk = kv_block(s, *refs)
@@ -308,4 +334,135 @@ def flash_decode(q, ck, cv, length, k_scale=None, v_scale=None, *,
       *args)
 
 
-__all__ = ["flash_decode", "kv_block_k", "live_kv_blocks"]
+def _latent_kernel(row_ref, start_ref, count_ref, first_ref, len_ref,
+                   off_ref, layer_ref, q_ref, c_ref, zero_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, scale, v_dim, block_k, n_blocks,
+                   m_cap, window):
+    """``_decode_kernel``'s walk in the absorbed form of latent attention:
+    a grid step streams one [R, block_k] block of a row's latent rows
+    (positions along the lanes), which are the keys of every head, and
+    whose first ``v_dim`` features are their values. The probabilities
+    enter the weighted sum in the rows' dtype, accumulated in f32, as the
+    einsum of the absorbed form does."""
+    del zero_ref, layer_ref         # the output's start; the index maps'
+    b = row_ref[pl.program_id(0)]
+    j = pl.program_id(0) - start_ref[b]
+    count = count_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j < count)
+    def _block():
+        blk = _wrap(first_ref[b] + j, n_blocks)
+        c = c_ref[...]                                  # [R, block_k]
+        s = jnp.einsum("hr,rk->hk", q_ref[...], c,
+                       preferred_element_type=jnp.float32) * scale
+        mask = _visible(s.shape, 1, blk=blk, row=b, off_ref=off_ref,
+                        length=len_ref[b], block_k=block_k, m_cap=m_cap,
+                        window=window)
+        s = jnp.where(mask, s, NEG_INF)                 # [H, block_k]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        v = c[:v_dim]
+        if m_cap % block_k:
+            # the ragged tail block's positions past M hold unspecified
+            # values (p is 0 there, but 0 * NaN = NaN): zeroed
+            idx = blk * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            v = jnp.where(idx < m_cap, v, jnp.zeros_like(v))
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "hk,vk->hv", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == count - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    @pl.when(count == 0)            # the one step of an empty work list
+    def _nothing():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("v_dim", "scale", "window", "block_k", "interpret"))
+def flash_decode_latent(q, rows, length, layer, *, v_dim: int, scale: float,
+                        ring_offsets=None, active=None, window: int = 0,
+                        block_k: int | None = None, interpret: bool = False):
+    """The absorbed form of latent attention for ONE new token per
+    sequence, over the live blocks of each row's latent rows only.
+
+    q: [B, H, R] the heads' queries against the rows themselves
+        (``transformer.latent_absorb``; R = kv_rank + rope)
+    rows: the WHOLE [Ly, B, M, R] stack of latent rows (``KVCache
+        .latent``), ``layer`` (an int32 scalar, traced: one kernel for
+        every layer) picking the layer inside the BlockSpec, so no slice
+        of the stack is made; the rows are the keys of every head, and
+        their first ``v_dim`` features the values. The kernel reads the
+        stack as [Ly, B, R, M], positions along the lanes: that is how
+        the TPU lays out a stack whose R (576) is not a multiple of 128
+        and M is, so the transposition is a view and not a copy
+    length, ring_offsets, active: ``flash_decode``'s contract
+    -> [B, H, v_dim] sum p row[:v_dim] in q's dtype (zeros for a row that
+    is not active). ``scale`` is the scores' factor; ``block_k`` defaults
+    to ``kv_block_k`` of one head R wide, the unit the serving engine's
+    ``kv_blocks_read`` counts in.
+    """
+    b, h, r = q.shape
+    m_cap = rows.shape[-2]
+    rows = jnp.swapaxes(rows, -1, -2)                   # [Ly, B, R, M]
+    block_k = (kv_block_k(m_cap, 1, r, rows.dtype.itemsize)
+               if block_k is None else min(block_k, m_cap))
+    n_blocks = pl.cdiv(m_cap, block_k)
+    lengths, offsets, row_of, count, first, ends = _work_list(
+        length, ring_offsets, active, b=b, block_k=block_k, m_cap=m_cap,
+        window=window)
+
+    def row_index(s, *refs):
+        row, blk = _block_of(s, *refs, n_blocks=n_blocks)
+        return refs[6][0], row, 0, blk
+
+    out_spec = pl.BlockSpec((None, h, v_dim),
+                            lambda s, row_ref, *_: (row_ref[s], 0, 0))
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, v_dim=v_dim, block_k=block_k,
+        n_blocks=n_blocks, m_cap=m_cap, window=window)
+    n_prefetch = 7
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(jnp.maximum(ends[-1], 1),),
+            in_specs=[
+                pl.BlockSpec((None, h, r),
+                             lambda s, row_ref, *_: (row_ref[s], 0, 0)),
+                pl.BlockSpec((None, None, r, block_k), row_index),
+                # rows with no live block are never visited: the output
+                # starts as these zeros (aliased) and stays so for them
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=out_spec,
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, v_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        input_output_aliases={n_prefetch + 2: 0},
+        interpret=interpret,
+    )(row_of.astype(jnp.int32), ends - count, count, first, lengths, offsets,
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), q, rows,
+      jnp.zeros((b, h, v_dim), q.dtype))
+
+
+__all__ = ["flash_decode", "flash_decode_latent", "kv_block_k",
+           "live_kv_blocks"]
