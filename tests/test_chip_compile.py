@@ -122,6 +122,23 @@ def test_flash_decode_compiles_for_v5e(one_chip, d, variant):
         _compiled_text(flash_decode, q, cache, cache, length)
 
 
+def test_flash_decode_of_a_window_ring_compiles_for_v5e(one_chip):
+    """The window-and-global cell's call in its window layers: the whole
+    [3, 16, 8, 8192, 128] stack of window rings, 16 rows of 16 query heads
+    a KV head (128 over 8), the layer an operand, the rows' lengths and
+    offsets as `window_view` makes them, a window of 4096."""
+    from tony_tpu.ops.decode_attention import flash_decode
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    rows = sds((16,), jnp.int32)
+    ring = sds((3, 16, 8, 8192, 128), jnp.bfloat16)
+    fn = jax.jit(lambda q, k, v, lengths, offsets, active: flash_decode(
+        q, k, v, lengths, ring_offsets=offsets, active=active, window=4096,
+        layer=2))
+    _compiled_text(fn, sds((16, 8, 16, 128), jnp.bfloat16), ring, ring, rows,
+                   rows, sds((16,), jnp.bool_))
+
+
 def test_flash_decode_latent_compiles_for_v5e(one_chip):
     """The latent cell's call: the whole [5, 32, 4096, 576] stack of
     latent rows, 32 rows of 32 heads sharing the one row, the layer an
@@ -315,3 +332,30 @@ def test_routed_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
         assert text.count("ragged-dot") >= 2
     # the sorted tokens, their gate-and-up and the combine, not the experts
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * tokens * k * d
+
+
+def test_a_share_of_wide_experts_compiles_for_v5e(one_chip, monkeypatch):
+    """A decode step's kernel path over a chip's share of wide experts (16
+    of 128 held, 4096 x 2 x 4096 gate-and-up, 16 tokens): an expert's
+    whole matrix would not fit the kernel's scoped VMEM twice, so its
+    weight block is an N tile of it (`weight_tile`), and the two Mosaic
+    kernels compile."""
+    from tony_tpu.parallel.routed_experts import (WEIGHT_BLOCK_BYTES, route,
+                                                  routed_ffn, weight_tile)
+
+    e, held, d, f, k = 128, (0, 16), 4096, 4096, 8
+    assert 2 * d * f * 2 > WEIGHT_BLOCK_BYTES
+    assert weight_tile(d, 2 * f, 2) == 512 == weight_tile(f, d, 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def layer(x, router, bias, w_gu, w_down):
+        chosen, w = route(x, router, bias, top_k=k, scale=1.0)
+        return routed_ffn(x, chosen, w, w_gu, w_down, held=held, kernel=True)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = jax.jit(layer).lower(
+        sds((16, d), jnp.bfloat16), sds((d, e), jnp.float32),
+        sds((e,), jnp.float32), sds((16, d, 2 * f), jnp.bfloat16),
+        sds((16, f, d), jnp.bfloat16)).compile().as_text()
+    assert "ragged-dot" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

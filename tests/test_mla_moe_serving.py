@@ -591,12 +591,9 @@ def test_the_other_paths_refuse_the_kind_by_name(prepared, tcfg):
 
 # ------------------ the other configurations' programs are the parent's
 
-def _hybrid_program_texts():
-    hybrid = lib.load("drivers/serve_hybrid.py")
-    cfg_file = lib.read_json(
-        BENCH / "tests/fixture_hybrid/benchmark/configs/tiny-hybrid.json")
-    cfg = dataclasses.replace(hybrid.transformer_config(cfg_file, 64),
-                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+def _program_texts(cfg):
+    """`_decode_block` and `_prefill_batch` (1 and 2 rows) of ``cfg``,
+    lowered at 4 slots of 64 positions: {program: StableHLO text}."""
     prep = prepare_decode(transformer.init(jax.random.PRNGKey(0), cfg), cfg)
     s = 4
     cache = init_cache(cfg, s, 64)._replace(length=jnp.zeros((s,), jnp.int32))
@@ -618,24 +615,42 @@ def _hybrid_program_texts():
     return out
 
 
+def _bf16(cfg):
+    return dataclasses.replace(cfg, dtype=jnp.bfloat16,
+                               param_dtype=jnp.bfloat16)
+
+
+def _hybrid_program_texts():
+    hybrid = lib.load("drivers/serve_hybrid.py")
+    cfg_file = lib.read_json(
+        BENCH / "tests/fixture_hybrid/benchmark/configs/tiny-hybrid.json")
+    return _program_texts(_bf16(hybrid.transformer_config(cfg_file, 64)))
+
+
+def _latent_routed_program_texts():
+    return _program_texts(_bf16(driver.transformer_config(CFG, 64)))
+
+
 GOLDEN = {name: json.loads((REPO / f"tests/fixtures/{name}_stablehlo.json")
-                           .read_text()) for name in ("uniform", "hybrid")}
+                           .read_text())
+          for name in ("uniform", "hybrid", "latent_routed")}
 
 
 @pytest.mark.parametrize("config, program", [
     (name, program) for name in GOLDEN
     for program in sorted(GOLDEN[name]["sha256"])])
 def test_the_other_configs_lower_to_the_parents_stablehlo(config, program):
-    """A uniform (Mistral-shaped) and a hybrid (gated-delta-rule) config
-    lower `_decode_block` and `_prefill_batch` to the text the tree before
-    the latent kind and the routed MLP lowered them to: the measured cells
-    load the programs they loaded (the fixtures say how the digests were
-    taken; another jax prints another text)."""
+    """A uniform (Mistral-shaped), a hybrid (gated-delta-rule) and a
+    latent-routed config lower `_decode_block` and `_prefill_batch` to the
+    text the trees before them lowered them to: the measured cells load
+    the programs they loaded (the fixtures say how the digests were taken;
+    another jax prints another text)."""
     if jax.__version__ != GOLDEN[config]["jax"]:
         pytest.skip(f"digests are of jax {GOLDEN[config]['jax']}")
     if config == "uniform":
         from test_hybrid_serving import _uniform_program_texts as texts
     else:
-        texts = _hybrid_program_texts
+        texts = {"hybrid": _hybrid_program_texts,
+                 "latent_routed": _latent_routed_program_texts}[config]
     assert hashlib.sha256(texts()[program].encode()).hexdigest() \
         == GOLDEN[config]["sha256"][program]

@@ -66,6 +66,7 @@ DECODE_PARTS = {
 TRAIN_PARTS = {"mixer", "mlp", "loss", "optimizer"}
 CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
 ROLLOUT, TRAIN = "joyai-llm-flash.rollout-closed", "mistral7b-v03.train-1chip"
+LONGCTX = "command-a-plus.longctx-closed"
 ENGINE = dict(slots=3, max_len=64, block_size=4, prefill_chunk=8)
 
 
@@ -152,7 +153,7 @@ def test_step_parts_are_pinned_and_each_is_read():
                ["per_layer"] if m["name"].startswith(("decode_step_part_ms.",
                                                      "train_step_part_ms."))]
     assert len(entries) == 12
-    serve = [CHAT, REASON, ROLLOUT]
+    serve = [CHAT, REASON, ROLLOUT, LONGCTX]
     for m in entries:
         stem, _, part = m["name"].partition(".")
         assert part in (decode if stem == "decode_step_part_ms" else train)
@@ -163,9 +164,9 @@ def test_step_parts_are_pinned_and_each_is_read():
                                                     [TRAIN])
         else:
             assert m["moves"] == "token_gap_p50_ms"
-            assert m["workloads"] == {"router": [ROLLOUT],
-                                      "experts": [ROLLOUT],
-                                      "counts": [REASON, ROLLOUT]}.get(
+            assert m["workloads"] == {"router": [ROLLOUT, LONGCTX],
+                                      "experts": [ROLLOUT, LONGCTX],
+                                      "counts": [REASON, ROLLOUT, LONGCTX]}.get(
                                           part, serve)
 
 

@@ -61,6 +61,7 @@ ROUTED = transformer.TransformerConfig(
 )
 CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
 ROLLOUT = "joyai-llm-flash.rollout-closed"
+LONGCTX = "command-a-plus.longctx-closed"
 STEP_PHASES = (obs.PHASE_ADMIT, obs.PHASE_DISPATCH, obs.PHASE_SYNC,
                obs.PHASE_BOOKKEEP)
 NEW_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
@@ -410,14 +411,21 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     # neither
     assert entry["workloads"] == {
         "state_rows_advanced_pct": [REASON], "state_read_pct": [REASON],
-        "expert_weights_read_pct": [ROLLOUT], "experts_touched_pct": [ROLLOUT],
+        "expert_weights_read_pct": [ROLLOUT, LONGCTX],
+        "experts_touched_pct": [ROLLOUT, LONGCTX],
+        "window_kv_read_pct": [LONGCTX],
         "submit_lock_wait_ms": [CHAT]}.get(
-            stem, [CHAT, REASON, ROLLOUT])
+            stem, [CHAT, REASON, ROLLOUT, LONGCTX])
     # a program without the spans (the chip fixture; the parent commit)
     shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
     assert _read(entry, tmp_path, monkeypatch) is None
     assert _read(entry, tmp_path / "no_trace", monkeypatch) is None
     value = _read(entry, run.dir, monkeypatch)
+    if stem == "window_kv_read_pct":
+        # no engine of this session has window layers: a window engine's
+        # spans are read in tests/test_window_moe_serving.py
+        assert value is None
+        return
     assert isinstance(value, float) and value >= 0.0
     if entry["unit"] == "%" and "occupancy" in entry["name"]:
         assert value <= 100.0
@@ -455,7 +463,7 @@ def test_part_readers_find_no_device_run_in_the_cpu_session(entry, run,
 
 def test_new_entries_are_the_thirteen_and_the_shares_are_disjoint(
         run, monkeypatch):
-    assert len(NEW_ENTRIES) == 13
+    assert len(NEW_ENTRIES) == 14      # with the window layers' reader
     monkeypatch.setattr(host_spans, "TRACE_ROOT", run.dir)
     pct = lib.load("layer_metrics/serve_loop_phase_pct.py")
     named = [name for names in pct.PHASES.values() for name in names]

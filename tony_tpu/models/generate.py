@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import transformer
-from .transformer import TransformerConfig, rms_norm
+from .transformer import TransformerConfig
 
 NEG_INF = -1e30
 
@@ -90,12 +90,52 @@ class KVCache(NamedTuple):
     # (one row serves every head, so there is no head axis to put outside
     # it): [n_latent, B, max_len, lat_kv_rank + lat_rope_dim]
     latent: jax.Array | None = None
+    # a config with window layers only: their K and V, each in a ring of
+    # its own R positions (`window_ring`; R = max_len where nothing asks
+    # for less): [n_window, B, n_kv_heads, R, head_dim]; k and v above
+    # then hold the full layers only
+    win_k: jax.Array | None = None
+    win_v: jax.Array | None = None
 
 
-def _rec_buffers(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
+def window_ring(cfg: TransformerConfig, max_len: int, chunk: int) -> int:
+    """Positions a window layer's slot ring holds: the shortest max_len /
+    2^j that still holds a window and a prefill chunk (a chunk's first
+    query sees win_size - 1 positions back and its writes reach chunk - 1
+    forward, so R >= win_size + chunk leaves every position a query of the
+    chunk may see in place). R divides max_len, so index (p + offset) mod
+    R is the full ring's index mod R: the shared cursor and the offsets
+    serve both rings, the window ring's write index being the cursor mod
+    R."""
+    ring = max_len
+    while ring % 2 == 0 and ring // 2 >= cfg.win_size + chunk:
+        ring //= 2
+    return ring
+
+
+def window_view(lengths, offsets, ring: int, l_new: int, xp=jnp):
+    """A window layer's ring of ``ring`` positions, seen as a ring that has
+    not wrapped: -> (lengths, offsets) to hand to the attention in place of
+    the rows' own, for rows whose ``l_new`` new positions start at
+    ``lengths`` and whose position p lies at index (p + offset) mod ring.
+
+    Once a row's positions outnumber the ring, its oldest are overwritten;
+    after the new positions' write the ring holds positions base .. base +
+    ring - 1, base = lengths + l_new - ring. Counted from base (position p
+    as p - base, which lies at offset + base), the ring holds each of
+    those once and nothing past them: its mask and its live blocks are any
+    ring's (``window`` <= ring - l_new + 1 keeps what a query may see
+    inside it). ``xp`` is numpy on the host (the serving engine's counts)."""
+    base = xp.maximum(lengths + l_new - ring, 0)
+    return lengths - base, (offsets + base) % ring
+
+
+def _rec_buffers(cfg: TransformerConfig, batch: int, max_len: int,
+                 ring: int | None = None) -> dict:
     """The zeroed ``state`` / ``conv`` fields of a cache of ``batch``
-    sequences (none for a config without linear layers) and its ``latent``
-    field (none without latent layers)."""
+    sequences (none for a config without linear layers), its ``latent``
+    field (none without latent layers) and its ``win_k`` / ``win_v``
+    (none without window layers; ``ring`` positions, None = max_len)."""
     out = {}
     if cfg.n_linear_layers:
         state, tail = transformer.linear_state_zeros(cfg, batch)
@@ -105,11 +145,17 @@ def _rec_buffers(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     if cfg.n_latent_layers:
         out["latent"] = jnp.zeros(
             (cfg.n_latent_layers, batch, max_len, cfg.lat_row_dim), cfg.dtype)
+    if cfg.n_window_layers:
+        shape = (cfg.n_window_layers, batch, cfg.n_kv_heads, ring or max_len,
+                 cfg.head_dim)
+        out["win_k"] = jnp.zeros(shape, cfg.dtype)
+        out["win_v"] = jnp.zeros(shape, cfg.dtype)
     return out
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
-               kv_dtype: str = "native") -> KVCache:
+               kv_dtype: str = "native",
+               window_ring: int | None = None) -> KVCache:
     """kv_dtype "native" stores cfg.dtype (exact); "int8" stores
     per-token-per-head symmetric int8 with bf16 scales — half the cache's
     HBM capacity (2x the context per GB) and, with the scale-folded
@@ -122,14 +168,19 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     decode attention reads one head's whole history at a time, and with
     position outermost that read is strided by kvH*D — measured ~3x below
     streaming bandwidth on v5e. Head-major, each head's [M, D] block is
-    contiguous."""
+    contiguous.
+
+    ``window_ring`` (the serving engine's `window_ring`) is the positions
+    of a window layer's ring; None: max_len, a ring that never wraps (the
+    lockstep path writes a prompt at one offset)."""
     shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
-    rec = _rec_buffers(cfg, batch, max_len)
+    rec = _rec_buffers(cfg, batch, max_len, window_ring)
     if kv_dtype == "int8":
-        if cfg.n_latent_layers:
+        if cfg.n_latent_layers or cfg.n_window_layers:
             raise ValueError(
-                "kv_dtype='int8' is not implemented for latent layers (the "
-                "cached rows are stored in the activation dtype)")
+                "kv_dtype='int8' is not implemented for latent or window "
+                "layers (their cached rows are stored in the activation "
+                "dtype)")
         return KVCache(
             k=jnp.zeros(shape, jnp.int8),
             v=jnp.zeros(shape, jnp.int8),
@@ -267,7 +318,7 @@ def expert_kernel_engages(l_new: int, sharded: bool) -> bool:
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
                       allow_kernel=True, layer_idx=None, active=None,
-                      scale=None):
+                      scale=None, window=None):
     """q: [B, L, H, D] for the L new positions (absolute offsets cache_len..
     cache_len+L-1); ck/cv: [B, kvH, max_len, D] full cache buffers (already
     containing the new keys). Scores run against the whole static buffer;
@@ -302,8 +353,11 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
     reads nothing and returns zeros. The einsum ignores ``active`` (an
     idle row attends over its stale positions; its output is dropped).
     ``scale`` (None = head_dim ** -0.5) is the scores' factor where q and
-    k are not head_dim wide (`_latent_attention`)."""
+    k are not head_dim wide (`_latent_attention`); ``window`` (None =
+    cfg.attn_window) a window layer's."""
     b, l, h, d = q.shape
+    if window is None:
+        window = cfg.attn_window
     kvh = ck.shape[1 if layer_idx is None else 2]
     rep = h // kvh
     if allow_kernel and l == 1 and decode_kernel_engages(cfg, ck.shape[-2]):
@@ -314,7 +368,7 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
         out = flash_decode(
             q.reshape(b, kvh, rep, d), ck, cv, cache_len,
             k_scale, v_scale, ring_offsets=ring_offsets, active=active,
-            window=cfg.attn_window or 0, layer=layer_idx,
+            window=window or 0, layer=layer_idx,
         )
         return out.reshape(b, 1, h, d)
     if layer_idx is not None:           # einsum path works on the slice
@@ -345,16 +399,16 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
         mask_bc = (slice(None), None, None)             # -> [B,1,1,L,M]
     if ring_offsets is not None:
         mask = key_log[:, None, :] <= q_pos[..., :, None]
-        if cfg.attn_window:
-            mask &= key_log[:, None, :] > q_pos[..., :, None] - cfg.attn_window
+        if window:
+            mask &= key_log[:, None, :] > q_pos[..., :, None] - window
         mask_bc = (slice(None), None, None)
     else:
         mask = key_log <= q_pos[..., :, None]           # causal + validity
-        if cfg.attn_window:
+        if window:
             # sliding-window models must decode with the same band they
             # trained with, or generation attends to positions the model
             # never saw
-            mask &= key_log > q_pos[..., :, None] - cfg.attn_window
+            mask &= key_log > q_pos[..., :, None] - window
     s = jnp.where(mask[mask_bc], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if v_scale is not None:
@@ -503,7 +557,8 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
         if cfg.mlp_kinds is not None:
             # the MLPs lie a layer in a list and are read in the one format
             # they are made in: a fused copy of a routed layer's experts
-            # would be a second residency of nearly the whole model
+            # would be a second residency of nearly the whole model (the
+            # attention's q/k/v are fused in place: `_qkv_in_one`)
             return {}
         out = {}
         for kind, lp in params["layers"].items():
@@ -542,6 +597,27 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
         out[name] = q
         out[name + "_s"] = s.astype(dt)
     return out
+
+
+def _qkv_in_one(params, fused: dict):
+    """The attention stacks ("full", "window") of a layer-kinds config that
+    ``fused`` (`_fuse_decode_weights`) holds no ``wqkv`` copy of, with
+    ``wq`` / ``wk`` / ``wv`` replaced by their concatenation ``wqkv`` [n,
+    d, (heads + 2 kv) hd], which `_qkv` reads in every program: one
+    resident copy, and a 2-D matrix that a layer's slice of the stack hands
+    the matmul as it lies (the 3-D ``wq`` made the TPU relayout each
+    layer's 134 MB every decode step at 128 heads). A stack with a fused
+    copy keeps both (fault 3), so that its programs stay as they were."""
+    layers = dict(params["layers"])
+    for kind in ("full", "window"):
+        if kind in layers and "wqkv" not in fused.get(kind, {}):
+            lp = dict(layers[kind])
+            n, d = lp["wq"].shape[:2]
+            lp["wqkv"] = jnp.concatenate(
+                [lp.pop(w).reshape(n, d, -1) for w in ("wq", "wk", "wv")],
+                axis=-1)
+            layers[kind] = lp
+    return {**params, "layers": layers}
 
 
 def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
@@ -595,6 +671,12 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     elsewhere (the CPU, a mesh) ``gated_delta_step`` on the slice, set
     back with ``.at[layer].set``, the statement the kernel is tested
     against.
+
+    A window layer (``cfg.layer_kinds``) stores its K/V in ``cache.win_k``
+    / ``.win_v``, a ring of its own whose index is the shared offset mod
+    its length, and attends over its ``win_size`` positions through
+    `window_view` (``flash_decode`` with its window where the gate opens,
+    as ``attend``).
 
     A latent layer (``cfg.layer_kinds``) stores its positions' rows [c_kv
     | k_r] in ``cache.latent`` at the same shared offset and attends in the
@@ -653,7 +735,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         path: that is the point of the ring layout, see the docstring),
         then read the whole stack — or, on the empty cache of a prefill,
         the block itself. ``layer`` counts the layers that hold K/V."""
-        kv, rec, lat = carry
+        kv, rec, lat, win = carry
         offset = cache.length if ring_cursor is None else ring_cursor
 
         def put(buf, new):  # buf [Ly, B, kvH, M(, D)], new [B, kvH, L(, D)]
@@ -665,7 +747,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         if prefill:
             kr, vr = transformer._repeat_kv(cfg, k, v)
             return (transformer._attention(q, kr, vr, p_cfg, None),
-                    (kv, rec, lat))
+                    (kv, rec, lat, win))
         ck, cv, ks_buf, vs_buf = kv
         attn = _cached_attention(
             cfg, q, ck, cv, cache.length, l, ks_buf, vs_buf,
@@ -675,7 +757,39 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             allow_kernel=shardings is None,
             layer_idx=layer, active=ring_active,
         )
-        return attn, (kv, rec, lat)
+        return attn, (kv, rec, lat, win)
+
+    def attend_window(layer, carry, q, k, v):
+        """A window layer (``layer`` counts them): its K/V into its own
+        ring of R positions at the shared offset mod R (R divides max_len:
+        `window_ring`), then the attention over its window, the ring seen
+        through `window_view`. The lockstep path's ring is max_len long and
+        never wraps."""
+        kv, rec, lat, (wk, wv) = carry
+        ring_len = wk.shape[3]
+        if ring_cursor is None and ring_len != cache.k.shape[3]:
+            raise ValueError("a lockstep cache's window rings are max_len "
+                             "long (init_cache without window_ring)")
+        offset = cache.length if ring_cursor is None else ring_cursor % ring_len
+
+        def put(buf, new):  # buf [Ly, B, kvH, R, D], new [B, kvH, L, D]
+            return lax.dynamic_update_slice(
+                buf, new[None], (jnp.int32(layer), zero, zero, offset, zero))
+
+        wk, wv, _, _ = _store_kv((wk, wv, None, None), k, v, put)
+        if prefill:
+            kr, vr = transformer._repeat_kv(cfg, k, v)
+            return (transformer._attention(q, kr, vr, p_cfg, None,
+                                           cfg.win_size),
+                    (kv, rec, lat, (wk, wv)))
+        lengths, offsets = cache.length, ring_offsets
+        if ring_offsets is not None:
+            lengths, offsets = window_view(lengths, offsets, ring_len, l)
+        attn = _cached_attention(
+            cfg, q, wk, wv, lengths, l, ring_offsets=offsets,
+            allow_kernel=shardings is None, layer_idx=layer,
+            active=ring_active, window=cfg.win_size)
+        return attn, (kv, rec, lat, (wk, wv))
 
     def recur(layer, carry, h, lp):
         """A linear layer from and to the cache's ``state`` / ``conv``
@@ -684,7 +798,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         K/V beyond a length can take, would be wrong for a state. Where
         ``state_kernel_engages`` the recurrence gets the whole stack and
         no slice of it is made or set back (the docstring above)."""
-        kv, (state, conv), lat = carry
+        kv, (state, conv), lat, win = carry
         n_valid = (None if ring_active is None
                    else ring_active.astype(jnp.int32))
         if state_kernel_engages(l, shardings is not None):
@@ -697,13 +811,13 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             out, new_state, new_tail = transformer.linear_mixer(
                 cfg, h, lp, state[layer], conv[layer], n_valid)
             state = state.at[layer].set(new_state)
-        return out, (kv, (state, conv.at[layer].set(new_tail)), lat)
+        return out, (kv, (state, conv.at[layer].set(new_tail)), lat, win)
 
     def latent(layer, carry, h, lp):
         """A latent layer (``layer`` counts them): the block's rows into
         ``cache.latent`` at the shared offset, then the absorbed form over
         the layer's rows (on a prefill's empty cache: over the block's)."""
-        kv, rec, lat = carry
+        kv, rec, lat, win = carry
         q_nope, q_rope, row = transformer.latent_project(cfg, h, positions, lp)
         offset = cache.length if ring_cursor is None else ring_cursor
         lat = lax.dynamic_update_slice(
@@ -717,23 +831,26 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
                                   ring_offsets, layer_idx=layer,
                                   active=ring_active,
                                   allow_kernel=shardings is None)
-        return transformer.latent_out(cfg, o, lp), (kv, rec, lat)
+        return transformer.latent_out(cfg, o, lp), (kv, rec, lat, win)
 
     mixers = {"linear": recur, "latent": latent}
+    attends = {"window": attend_window}
     expert_kernel = expert_kernel_engages(l, shardings is not None)
     carry = ((cache.k, cache.v, cache.k_scale, cache.v_scale),
-             (cache.state, cache.conv), cache.latent)
+             (cache.state, cache.conv), cache.latent,
+             (cache.win_k, cache.win_v))
     picks = []
     for i in range(cfg.n_layers):
         kind, j, lp = transformer.layer_at(cfg, params["layers"], i,
                                            fused_layers)
         x, aux, carry = transformer.decoder_layer(
-            cfg, x, positions, lp, functools.partial(attend, j), carry,
+            cfg, x, positions, lp,
+            functools.partial(attends.get(kind, attend), j), carry,
             functools.partial(mixers[kind], j) if kind in mixers else None,
-            expert_kernel)
+            expert_kernel, kind)
         if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
             picks.append(aux)
-    (ck, cv, ks_buf, vs_buf), (state, conv), lat = carry
+    (ck, cv, ks_buf, vs_buf), (state, conv), lat, (wk, wv) = carry
 
     # all_logits=True projects EVERY position ([B, L, V]) — the speculative
     # verify forward needs the target's prediction after each drafted
@@ -743,8 +860,8 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     # a pure HBM bonfire at long prompts / large vocab).
     # the final norm and the unembedding: the step's part "unembed"
     with jax.named_scope("unembed"):
-        x_out = rms_norm(x if all_logits else x[:, -1], params["final_norm"],
-                         cfg.norm_eps)
+        x_out = transformer.norm(cfg, x if all_logits else x[:, -1],
+                                 params["final_norm"])
         eq = "bld,dv->blv" if all_logits else "bd,dv->bv"
         if w8:
             logits = (
@@ -764,7 +881,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
             vs_buf = lax.with_sharding_constraint(vs_buf, shardings.scale)
     new_cache = KVCache(k=ck, v=cv, length=cache.length + l,
                         k_scale=ks_buf, v_scale=vs_buf, state=state, conv=conv,
-                        latent=lat)
+                        latent=lat, win_k=wk, win_v=wv)
     if routes:
         return logits, new_cache, jnp.stack(picks)
     return logits, new_cache
@@ -928,6 +1045,8 @@ def prepare_decode(
     params = _cast_decode_params(params, cfg)
     fused = (None if sharded_tp
              else _fuse_decode_weights(params, cfg, weight_dtype))
+    if fused is not None:
+        params = _qkv_in_one(params, fused)
     return DecodeWeights(params=params, fused=fused,
                          weight_dtype=weight_dtype, mesh=mesh, rules=rules)
 

@@ -271,6 +271,7 @@ def _stop_match_end(tokens, stop_seqs, start: int = 0) -> int | None:
     return best
 
 from .generate import (
+    NEG_INF,
     DecodeShardings,
     DecodeWeights,
     KVCache,
@@ -291,11 +292,13 @@ from .generate import (
     prepare_decode,
     sample_token,
     state_kernel_engages,
+    window_ring,
+    window_view,
 )
 from ..ops.decode_attention import kv_block_k, live_kv_blocks
 from ..ops.gated_delta import live_state_rows
 from ..parallel.routed_experts import expert_load
-from .transformer import TransformerConfig, rms_norm
+from .transformer import TransformerConfig
 from . import transformer
 
 
@@ -781,6 +784,76 @@ def _insert_prefix_blocks(pool, cache, slots, blocks, chunk_idx, offsets,
 
 # rows of a chunk round whose latent attention is taken at once
 LATENT_ROWS_AT_ONCE = 8
+# bytes of one row's float32 scores against its whole ring above which a
+# chunk round's attention walks the ring's live key blocks instead
+ROW_SCORES_BYTES = 256 << 20
+
+
+def _rows_attention(cfg, q, ck, cv, starts, l, ks, vs, offsets, window=None):
+    """`_cached_attention` of a chunk round's rows: q [K, L, H, D] at
+    positions ``starts[r]..`` against their slots' rings [K, kvH, M, D].
+
+    Its einsum scores every row against the whole ring, [K, H, L, M]
+    float32: 64 MB a row at the widths of a few thousand positions and
+    30-32 heads, which keep it, and a gigabyte a row at 128 heads over
+    16k, whose softmax then holds several such arrays at once. Where a
+    row's scores would pass ``ROW_SCORES_BYTES`` the rows go one at a time
+    over the blocks of positions that hold a position
+    some query of the chunk may see (``live_kv_blocks``: from the window's
+    start or 0 to the chunk's last position; a block is ``kv_block_k``'s,
+    the decode kernel's unit and the counters'), with an online softmax: the
+    same mask on the same logical positions, in float32, a block's scores
+    at a time, and only the live part of the ring read."""
+    k_rows, _, h, d = q.shape
+    m_cap = ck.shape[2]
+    window = cfg.attn_window if window is None else window
+    if h * l * m_cap * 4 <= ROW_SCORES_BYTES or ks is not None:
+        # the einsum, also for a draft's single-token steps: the rows are
+        # a slice of the cache, which as a Pallas operand would be a copy
+        return _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
+                                 ring_offsets=offsets, allow_kernel=False,
+                                 window=window)
+    f32, kvh = jnp.float32, ck.shape[1]
+    rep = h // kvh
+    bk = math.gcd(m_cap, kv_block_k(m_cap, kvh, d, ck.dtype.itemsize))
+    n_blocks = m_cap // bk
+    first, count = live_kv_blocks(
+        offsets, starts + l - 1, jnp.ones((k_rows,), bool), block_k=bk,
+        m_cap=m_cap, window=window + l - 1 if window else 0, xp=jnp)
+
+    def row(t):
+        q, ck, cv, start, offset, first, count = t
+        qg = q.reshape(l, kvh, rep, d).transpose(1, 2, 0, 3)    # [g, r, L, D]
+        q_pos = start + jnp.arange(l)
+
+        def block(j, carry):
+            m, total, acc = carry
+            blk = (first + j) % n_blocks
+            k, v = (lax.dynamic_slice_in_dim(c, blk * bk, bk, axis=1)
+                    for c in (ck, cv))
+            s = jnp.einsum("grld,gkd->grlk", qg, k.astype(cfg.dtype),
+                           preferred_element_type=f32) * d ** -0.5
+            pos = (blk * bk + jnp.arange(bk) - offset) % m_cap
+            ok = pos[None, :] <= q_pos[:, None]
+            if window:
+                ok &= pos[None, :] > q_pos[:, None] - window
+            s = jnp.where(ok, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "grlk,gkd->grld", p.astype(cfg.dtype), v.astype(cfg.dtype),
+                preferred_element_type=f32)
+            return m_new, total * corr + jnp.sum(p, axis=-1), acc
+
+        m, total, acc = lax.fori_loop(0, count, block, (
+            jnp.full((kvh, rep, l), NEG_INF, f32),
+            jnp.zeros((kvh, rep, l), f32), jnp.zeros((kvh, rep, l, d), f32)))
+        # a pad position may see nothing: zeros, as for an idle decode row
+        o = acc / jnp.where(total > 0, total, 1.0)[..., None]
+        return o.transpose(2, 0, 1, 3).reshape(l, h, d).astype(q.dtype)
+
+    return lax.map(row, (q, ck, cv, starts, offsets, first, count))
 
 
 def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
@@ -793,8 +866,9 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
 
     ``bufs`` = the cache's (k, v, k_scale, v_scale) buffers [layers, S,
     kvH, M(, D)], followed by its (state, conv) buffers where the config
-    has linear layers and by its latent buffer [layers, S, M, R] where it
-    has latent ones (below). A slot's buffer is a RING: logical
+    has linear layers, by its latent buffer [layers, S, M, R] where it
+    has latent ones and by its (win_k, win_v) where it has window ones
+    (below). A slot's buffer is a RING: logical
     position p lives at index (p + offsets[r]) mod M, and each layer's
     K/V scatter there where ``write_ok`` [K, L] holds. Every other position — a chunk's pad tail, a
     row with nothing to write, a window overhanging the row's budget — gets
@@ -817,6 +891,14 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     trace and a multi-chunk prompt carries its state from round to round;
     a padding row's state goes nowhere (mode="drop").
 
+    A window layer scatters its K/V into its own ring of R positions, at
+    (p + offsets[r]) mod R under the same rule, and attends over its
+    ``win_size`` positions with the ring seen through `window_view`.
+
+    A round whose rows' scores against the whole ring would pass
+    ``ROW_SCORES_BYTES`` a row takes its attention over the rings' live
+    key blocks, a row at a time (`_rows_attention`).
+
     A latent layer scatters its positions' rows [c_kv | k_r] into the
     slot's ring under the same ``write_ok`` / out-of-bounds rule as K/V and
     attends in the ABSORBED form over the row's own slot
@@ -832,7 +914,9 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     dt = cfg.dtype
     k_rows, l = tokens.shape
     n_rec = 2 if cfg.n_linear_layers else 0
-    bufs, rec, lat = bufs[:4], bufs[4:4 + n_rec], bufs[4 + n_rec:]
+    n_lat = 4 + n_rec + (1 if cfg.n_latent_layers else 0)
+    bufs, rec, lat, win = (bufs[:4], bufs[4:4 + n_rec], bufs[4 + n_rec:n_lat],
+                           bufs[n_lat:])
     n_slots, m_cap = bufs[0].shape[1], bufs[0].shape[3]
     positions = starts[:, None] + jnp.arange(l)[None, :]        # [K, L]
     ring_idx = jnp.where(write_ok, (offsets[:, None] + positions) % m_cap,
@@ -842,31 +926,41 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     else:
         read_rows = jnp.minimum(rows, n_slots - 1)  # clamp padding rows
 
-    def attend(layer, carry, q, k, v):
-        bufs, rec, lat = carry
-
+    def scatter(layer, idx):
         def put(buf, new):
             # advanced indices [K,1] x [K,L] around the kvH slice put the
             # broadcast dims first: the updates arrive [K, L, kvH(, D)]
-            return buf.at[layer, rows[:, None], :, ring_idx].set(
+            return buf.at[layer, rows[:, None], :, idx].set(
                 jnp.moveaxis(new, 1, 2), unique_indices=True, mode="drop")
+        return put
 
-        def rows_of(buf):       # what the rows attend over: [K, kvH, M(, D)]
-            if buf is None:
-                return None
-            return buf[layer] if read_rows is None else buf[layer][read_rows]
+    def rows_of(layer, buf):    # what the rows attend over: [K, kvH, M(, D)]
+        if buf is None:
+            return None
+        return buf[layer] if read_rows is None else buf[layer][read_rows]
 
-        bufs = _store_kv(bufs, k, v, put)
-        ck, cv, ks, vs = map(rows_of, bufs)
-        # the einsum, also for a draft's single-token steps: this program
-        # hands the attention a slice of the cache, which as a Pallas
-        # operand would be a copy
-        attn = _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
-                                 ring_offsets=offsets, allow_kernel=False)
-        return attn, (bufs, rec, lat)
+    def attend(layer, carry, q, k, v):
+        bufs, rec, lat, win = carry
+        bufs = _store_kv(bufs, k, v, scatter(layer, ring_idx))
+        ck, cv, ks, vs = (rows_of(layer, b) for b in bufs)
+        attn = _rows_attention(cfg, q, ck, cv, starts, l, ks, vs, offsets)
+        return attn, (bufs, rec, lat, win)
+
+    def attend_window(layer, carry, q, k, v):
+        bufs, rec, lat, (wk, wv) = carry
+        ring = wk.shape[3]
+        idx = jnp.where(write_ok, (offsets[:, None] + positions) % ring,
+                        ring + jnp.arange(l)[None, :])
+        wk, wv, _, _ = _store_kv((wk, wv, None, None), k, v,
+                                 scatter(layer, idx))
+        w_starts, w_offsets = window_view(starts, offsets, ring, l)
+        attn = _rows_attention(cfg, q, rows_of(layer, wk), rows_of(layer, wv),
+                               w_starts, l, None, None, w_offsets,
+                               window=cfg.win_size)
+        return attn, (bufs, rec, lat, (wk, wv))
 
     def recur(layer, carry, h, lp):
-        bufs, (state, conv), lat = carry
+        bufs, (state, conv), lat, win = carry
 
         def of_rows(buf):       # the rows' own, zero at a sequence's start
             mine = buf[layer] if read_rows is None else buf[layer][read_rows]
@@ -878,10 +972,11 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
             jnp.sum(write_ok, axis=1, dtype=jnp.int32))
         swr = dict(unique_indices=True, mode="drop")
         return out, (bufs, (state.at[layer, rows].set(new_state, **swr),
-                            conv.at[layer, rows].set(new_tail, **swr)), lat)
+                            conv.at[layer, rows].set(new_tail, **swr)), lat,
+                     win)
 
     def latent(layer, carry, h, lp):
-        bufs, rec, (lat,) = carry
+        bufs, rec, (lat,), win = carry
         q_nope, q_rope, row = transformer.latent_project(cfg, h, positions, lp)
         lat = lat.at[layer, rows[:, None], ring_idx].set(
             row.astype(lat.dtype), unique_indices=True, mode="drop")
@@ -900,20 +995,23 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
             o = o.reshape((k_rows,) + o.shape[2:])
         else:
             o = _latent_attention(cfg, q_lat, mine, starts, l, offsets)
-        return transformer.latent_out(cfg, o, lp), (bufs, rec, (lat,))
+        return transformer.latent_out(cfg, o, lp), (bufs, rec, (lat,), win)
 
     mixers = {"linear": recur, "latent": latent}
+    attends = {"window": attend_window}
     x = params["embed"].astype(dt)[tokens]
-    carry = (bufs, rec, lat)
+    carry = (bufs, rec, lat, win)
     picks = []
     for i in range(cfg.n_layers):
         kind, j, lp = transformer.layer_at(cfg, params["layers"], i)
         x, aux, carry = transformer.decoder_layer(
-            cfg, x, positions, lp, functools.partial(attend, j), carry,
-            functools.partial(mixers[kind], j) if kind in mixers else None)
+            cfg, x, positions, lp,
+            functools.partial(attends.get(kind, attend), j), carry,
+            functools.partial(mixers[kind], j) if kind in mixers else None,
+            kind=kind)
         if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
             picks.append(aux)
-    return (x, carry[0] + carry[1] + carry[2],
+    return (x, carry[0] + carry[1] + carry[2] + carry[3],
             jnp.stack(picks) if picks else None)
 
 
@@ -959,7 +1057,7 @@ def _prefill_batch(params, cache, d_tokens, d_active, d_target, d_offsets,
     k_rows, l = tokens.shape
     n_slots = cache.k.shape[1]
     write_ok = jnp.arange(l)[None, :] < n_valids[:, None]
-    more = [f for f in ("state", "conv", "latent")
+    more = [f for f in ("state", "conv", "latent", "win_k", "win_v")
             if getattr(cache, f) is not None]
     _, (ck, cv, ks_buf, vs_buf, *bufs), picks = _rows_forward(
         params, cfg, tokens,
@@ -1029,8 +1127,9 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
     `routed_columns`: the experts each row's fed token chose at each step
     in each routed layer ([S, block * layers * k], step-major), then three
     columns whose FIRST row carries a count of the whole block, summed
-    over its steps and routed layers: the distinct experts the rows live
-    at a step routed to, the distinct experts ANY row routed to (an idle
+    over its steps and routed layers, of the experts this chip holds: the
+    distinct experts the rows live at a step routed to, the distinct
+    experts ANY row routed to (an idle
     row computes on its stale token and routes it like any other: those
     are the experts whose weights the grouped matmul streams), and the
     most assignments of live rows that one expert got at one step of one
@@ -1099,6 +1198,10 @@ def _decode_block(params, fused, cache, tokens, active, target_len,
                                          n_experts=cfg.moe_experts)
                 live = jax.vmap(lambda c: load(c, active))(picks)
                 every = jax.vmap(load)(picks)
+                first, count = cfg.experts_held
+                if count < cfg.moe_experts:     # a chip's share: its own
+                    live = live[:, first:first + count]
+                    every = every[:, first:first + count]
                 counts = (jnp.sum(live > 0), jnp.sum(every > 0),
                           jnp.max(live))
             ys = (ys, (jnp.moveaxis(picks, 0, 1), *counts))
@@ -1240,7 +1343,7 @@ def _spec_block(params, draft_params, cache, draft_cache, d_tokens,
         write_ok = active[:, None] & (positions < d_target[:, None])
         x, bufs, _ = _rows_forward(p, p_cfg, toks, bufs, None, lens,
                                    d_offsets, write_ok)
-        x = rms_norm(x, p["final_norm"], p_cfg.norm_eps)
+        x = transformer.norm(p_cfg, x, p["final_norm"])
         logits = jnp.einsum("bld,dv->blv", x,
                             p["unembed"].astype(p_cfg.dtype))
         return logits.astype(jnp.float32), bufs
@@ -1892,6 +1995,33 @@ class SlotServer:
                 raise ValueError(
                     no + f"cannot use role={role!r}: the KV handoff "
                     "carries blocks of per-head K and V only")
+        # ---- window layers: a ring of their own beside the full one ----
+        # a window layer's K/V ring is shorter than max_len and shares the
+        # cursor (`window_ring`); what reads or moves blocks of max_len
+        # rings only is refused here, each by name, as above
+        if cfg.n_window_layers:
+            no = ("a config with window layers (a window ring beside the "
+                  "full one) ")
+            for bad, why in (
+                    (prefix_cache_blocks > 0,
+                     "cannot use prefix_cache_blocks: the prefix pool copies "
+                     "its blocks into max_len rings only"),
+                    (paged, "cannot use paged=True: the block pool has no "
+                     "window ring"),
+                    (draft is not None or spec_gamma,
+                     "cannot use a draft / spec_gamma: the verify forward "
+                     "is not built for it"),
+                    (kv_dtype == "int8", "cannot use kv_dtype='int8': the "
+                     "window ring is stored in the activation dtype"),
+                    (weight_dtype == "int8", "cannot use weight_dtype='int8': "
+                     "the int8 forms are the uniform stack's"),
+                    (mesh is not None
+                     or getattr(params, "mesh", None) is not None,
+                     "cannot use a mesh: the window ring is not sharded"),
+                    (role != "both", f"cannot use role={role!r}: the KV "
+                     "handoff carries max_len rings only")):
+                if bad:
+                    raise ValueError(no + why)
         if isinstance(params, DecodeWeights):
             if params.mesh is not None:
                 if mesh is not None and mesh != params.mesh:
@@ -2094,6 +2224,14 @@ class SlotServer:
         # a processed block's last step streamed / those its rings hold
         self.kv_blocks_read = 0
         self.kv_blocks_ring = 0
+        # window layers, at a processed block's last step: the blocks of a
+        # window ring it streamed and those its live rows' whole contexts
+        # span; the positions those rows hold and those a window layer's
+        # query sees (their least K/V a step)
+        self.window_blocks_read = 0
+        self.window_blocks_context = 0
+        self.live_positions = 0
+        self.window_positions = 0
         # rows whose recurrent state the processed decode blocks changed,
         # by the device's own account (0 without linear layers)
         self.state_rows = 0
@@ -2201,6 +2339,14 @@ class SlotServer:
             1 if kv_dtype == "int8" else jnp.dtype(self.cfg.dtype).itemsize)
         self._decode_kernel = (self._shardings is None
                                and decode_kernel_engages(self.cfg, max_len))
+        # a window layer's ring (max_len where no shorter one holds a
+        # window and a chunk), its unit and its kernel gate
+        self._window_ring = window_ring(self.cfg, max_len, prefill_chunk)
+        self._win_block_k = kv_block_k(
+            self._window_ring, self.cfg.n_kv_heads, self.cfg.head_dim,
+            jnp.dtype(self.cfg.dtype).itemsize)
+        self._win_kernel = (self._shardings is None and decode_kernel_engages(
+            self.cfg, self._window_ring))
         # whether the decode block's recurrence reads the live rows only
         self._state_kernel = state_kernel_engages(
             1, self._shardings is not None)
@@ -2302,7 +2448,8 @@ class SlotServer:
             self._cache = None
             self._d_lens = jnp.zeros((slots,), jnp.int32)
         else:
-            cache = init_cache(self.cfg, slots, self.max_len, self.kv_dtype)
+            cache = init_cache(self.cfg, slots, self.max_len, self.kv_dtype,
+                               window_ring=self._window_ring)
             # device-carried slot state: blocks consume the previous
             # block's outputs directly, never waiting on a host round trip
             self._cache = cache._replace(
@@ -4613,6 +4760,7 @@ class SlotServer:
         with phase(PHASE_BOOKKEEP) as span:
             done = len(self._done)
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
+            window = self._window_counts()
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
             e_touched, e_read, e_held, e_asg = (
@@ -4627,6 +4775,9 @@ class SlotServer:
                               state_rows=self.state_rows - rows,
                               state_rows_read=self.state_rows_read - s_read,
                               state_rows_held=self.state_rows_held - s_held)
+            if self.cfg.n_window_layers:
+                span.set_metadata(**{k: v - window[k] for k, v in
+                                     self._window_counts().items()})
             if self._routed:
                 held = self.experts_held - e_held
                 span.set_metadata(
@@ -4865,17 +5016,56 @@ class SlotServer:
         lengths and the offsets it was dispatched with; a row that
         emitted in the block counts as live for it) against the blocks
         its slots' rings hold. Where the step runs the einsum (a mesh,
-        the CPU, a short ring, a speculative round) it reads them all."""
+        the CPU, a short ring, a speculative round) it reads them all.
+
+        With window layers the two counts are over one period of the
+        pattern's attention layers (its full and window layers in their
+        ratio: 1 and 3 for three window layers to a full one), a window
+        ring's blocks by the same rule through `window_view`; and
+        ``window_blocks_read`` / ``_context`` add a window layer's blocks
+        read and those its live rows' whole contexts span (the blocks of
+        the full ring holding each row's positions 0 .. length), and
+        ``live_positions`` / ``window_positions`` the positions those rows
+        hold and the ones a window layer's query sees, min(length,
+        win_size) a row."""
         ring = self.slots * -(-self.max_len // self._kv_block_k)
         read = ring
-        if self._decode_kernel and "offsets" in rec:
-            live = active | (lengths > self._expect_len)
+        block = "offsets" in rec        # a decode block, no speculative round
+        live = active | (lengths > self._expect_len)
+        if self._decode_kernel and block:
             read = int(live_kv_blocks(
                 rec["offsets"], lengths, live, block_k=self._kv_block_k,
                 m_cap=self.max_len, window=self.cfg.attn_window or 0,
             )[1].sum())
+        if self.cfg.n_window_layers:
+            r, bk = self._window_ring, self._win_block_k
+            w_ring = w_read = self.slots * -(-r // bk)
+            if self._win_kernel and block:
+                w_len, w_off = window_view(lengths, rec["offsets"], r, 1,
+                                           xp=np)
+                w_read = int(live_kv_blocks(
+                    w_off, w_len, live, block_k=bk, m_cap=r,
+                    window=self.cfg.win_size)[1].sum())
+            self.window_blocks_read += w_read
+            self.window_blocks_context += int(live_kv_blocks(
+                rec.get("offsets", np.zeros_like(lengths)), lengths, live,
+                block_k=bk, m_cap=self.max_len)[1].sum())
+            held = np.where(live, lengths, 0)
+            self.live_positions += int(held.sum())
+            self.window_positions += int(
+                np.minimum(held, self.cfg.win_size).sum())
+            n_full, n_win = self.cfg.n_attn_layers, self.cfg.n_window_layers
+            g = math.gcd(n_full, n_win)
+            read = (n_full * read + n_win * w_read) // g
+            ring = (n_full * ring + n_win * w_ring) // g
         self.kv_blocks_read += read
         self.kv_blocks_ring += ring
+
+    def _window_counts(self) -> dict:
+        return {"window_blocks_read": self.window_blocks_read,
+                "window_blocks_context": self.window_blocks_context,
+                "live_positions": self.live_positions,
+                "window_positions": self.window_positions}
 
     def _count_state_rows(self, lengths) -> None:
         """Add one processed block to ``state_rows_read`` / ``_held``:

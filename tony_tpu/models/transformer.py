@@ -31,7 +31,7 @@ from ..parallel.expert import load_balancing_loss, moe_ffn
 from ..parallel.ring_attention import reference_attention
 
 
-LAYER_KINDS = ("full", "linear", "latent")
+LAYER_KINDS = ("full", "linear", "latent", "window")
 # the MLP's form a layer (cfg.mlp_kinds): SwiGLU at d_ff, or routed experts
 MLP_KINDS = ("dense", "routed")
 # under the root of the linear mixer's L2 normalisation of q and k
@@ -106,7 +106,8 @@ class TransformerConfig:
     # RMSNorm on q and k over the whole projected width, before the heads
     # are split (full-attention layers)
     qk_norm: bool = False
-    # "pre": x + Mixer(Norm(x)) (Llama); "post": x + Norm(Mixer(x))
+    # "pre": x + Mixer(Norm(x)) (Llama); "post": x + Norm(Mixer(x));
+    # "parallel": x + Mixer(Norm(x)) + MLP(Norm(x)), one norm (attn_norm)
     norm_order: str = "pre"
     # "latent" layers (multi-head latent attention, `latent_mixer`): q
     # through a bottleneck of lat_q_rank, a head's q and k [lat_nope_dim |
@@ -118,7 +119,8 @@ class TransformerConfig:
     lat_nope_dim: int = 0
     lat_rope_dim: int = 0
     lat_v_dim: int = 0
-    # rotate the adjacent pairs (2i, 2i+1) in place, not the halves
+    # rotate the adjacent pairs (2i, 2i+1) in place, not the halves (every
+    # rotated layer)
     rope_interleave: bool = False
     # the MLP's form a layer: None = d_ff SwiGLU (or the n_experts toy)
     # everywhere; else one of MLP_KINDS per layer (needs layer_kinds), the
@@ -135,6 +137,19 @@ class TransformerConfig:
     moe_shared: int = 0
     moe_scale: float = 1.0
     moe_held: tuple | None = None
+    # the shared experts' outputs averaged, not summed
+    moe_shared_mean: bool = False
+    # the width of a head; 0 = d_model // n_heads
+    attn_head_dim: int = 0
+    # "window" layers: a full layer's attention over each position's last
+    # win_size positions inclusive, rotated at win_rope_theta (None: not
+    # rotated); the "full" layers beside them keep rope_theta (None = NoPE)
+    # and the whole context. A serving slot keeps a window layer's K/V in
+    # a ring of its own (generate.init_cache)
+    win_size: int = 0
+    win_rope_theta: float | None = None
+    # "rms": RMSNorm; "layer": LayerNorm (mean-centred, a weight, no bias)
+    norm_type: str = "rms"
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -166,9 +181,15 @@ class TransformerConfig:
                 "latent layers need lat_q_rank, lat_kv_rank, lat_nope_dim, "
                 "an even lat_rope_dim, lat_v_dim, a rope_theta and a causal "
                 "model")
-        if self.norm_order not in ("pre", "post"):
-            raise ValueError(f"norm_order must be 'pre' or 'post', got "
-                             f"{self.norm_order!r}")
+        if self.norm_order not in ("pre", "post", "parallel"):
+            raise ValueError(f"norm_order must be 'pre', 'post' or "
+                             f"'parallel', got {self.norm_order!r}")
+        if self.norm_type not in ("rms", "layer"):
+            raise ValueError(f"norm_type must be 'rms' or 'layer', got "
+                             f"{self.norm_type!r}")
+        if self.n_window_layers and (self.win_size < 1 or not self.causal):
+            raise ValueError("window layers need win_size >= 1 and a causal "
+                             "model")
         if self.n_linear_layers and (
                 self.n_experts or not self.causal
                 or min(self.lin_heads, self.lin_key_dim, self.lin_value_dim,
@@ -179,7 +200,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def n_linear_layers(self) -> int:
@@ -190,13 +211,19 @@ class TransformerConfig:
         return (self.layer_kinds or ()).count("latent")
 
     @property
+    def n_window_layers(self) -> int:
+        return (self.layer_kinds or ()).count("window")
+
+    @property
     def n_routed_layers(self) -> int:
         return (self.mlp_kinds or ()).count("routed")
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers that hold K/V: the full-attention ones."""
-        return self.n_layers - self.n_linear_layers - self.n_latent_layers
+        """Layers that hold K/V over the whole context: the full-attention
+        ones."""
+        return (self.n_layers - self.n_linear_layers - self.n_latent_layers
+                - self.n_window_layers)
 
     @property
     def lat_row_dim(self) -> int:
@@ -226,7 +253,9 @@ def _attention_stack(keys, cfg: TransformerConfig, n: int) -> dict:
     shapes = (("wq", (d, cfg.n_heads, hd), d), ("wk", (d, cfg.n_kv_heads, hd), d),
               ("wv", (d, cfg.n_kv_heads, hd), d),
               ("wo", (cfg.n_heads, hd, d), cfg.n_heads * hd))
-    out = {"attn_norm": jnp.ones((n, d), pd), "mlp_norm": jnp.ones((n, d), pd)}
+    out = {"attn_norm": jnp.ones((n, d), pd)}
+    if cfg.norm_order != "parallel":        # the parallel block norms once
+        out["mlp_norm"] = jnp.ones((n, d), pd)
     for name, shape, fan_in in shapes:
         out[name] = _dense_init(next(keys), (n,) + shape, fan_in, pd)
     if cfg.qk_norm:
@@ -364,6 +393,11 @@ def init(key: jax.Array, cfg: TransformerConfig) -> dict:
             layers["latent"] = {
                 **_latent_stack(lkeys, cfg, cfg.n_latent_layers),
                 **mlp(cfg.n_latent_layers)}
+        if cfg.n_window_layers:
+            wkeys = iter(jax.random.split(jax.random.fold_in(key, 3), 8))
+            layers["window"] = {
+                **_attention_stack(wkeys, cfg, cfg.n_window_layers),
+                **mlp(cfg.n_window_layers)}
         if by_layer:
             layers.update(_mlp_lists(jax.random.fold_in(key, 2), cfg))
     return {"embed": embed, "layers": layers,
@@ -383,6 +417,8 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
     }
     if cfg.qk_norm:
         attn.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
+    if cfg.norm_order == "parallel":
+        del attn["mlp_norm"]
     if cfg.n_experts > 0:
         mlp = {
             "router": ("layers", "embed", None),
@@ -424,7 +460,8 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
             **mlp,
         }
         layers = {kind: tree for kind, tree in
-                  (("full", layers), ("linear", linear), ("latent", latent))
+                  (("full", layers), ("linear", linear), ("latent", latent),
+                   ("window", layers))
                   if kind in cfg.layer_kinds}
         if by_layer:
             routed = {"router": ("embed", None), "router_bias": (None,),
@@ -451,6 +488,22 @@ def rms_norm(x, weight, eps=1e-6):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
+
+
+def layer_norm(x, weight, eps=1e-5):
+    """LayerNorm with a weight and no bias; its statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    c = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(c * c, axis=-1, keepdims=True)
+    return (c * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
+
+
+def norm(cfg: TransformerConfig, x, weight):
+    """The model's norm of a block's branches and of its end
+    (``cfg.norm_type``)."""
+    if cfg.norm_type == "layer":
+        return layer_norm(x, weight, cfg.norm_eps)
+    return rms_norm(x, weight, cfg.norm_eps)
 
 
 def rope(x, positions, theta, scaling=None, interleave=False):
@@ -521,14 +574,15 @@ def _partition_over_batch_and_heads(attn, mesh, shape):
                      out_specs=spec, check_vma=False)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh):
-    """[B, L, H, D] in/out; dispatch on attn_impl."""
+def _attention(q, k, v, cfg: TransformerConfig, mesh, window=None):
+    """[B, L, H, D] in/out; dispatch on attn_impl. ``window`` (None =
+    cfg.attn_window) is a window layer's."""
     impl = cfg.attn_impl
     if cfg.attn_window < 0:
         raise ValueError(
             f"attn_window must be >= 0 (0 = full causal), got {cfg.attn_window}"
         )
-    window = cfg.attn_window or None
+    window = (cfg.attn_window if window is None else window) or None
     if window is not None and not cfg.causal:
         raise ValueError("attn_window requires causal=True")
     if impl == "auto":
@@ -580,9 +634,11 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh):
     return reference_attention(q, k, v, causal=cfg.causal, window=window)
 
 
-def _qkv(cfg: TransformerConfig, h, positions, lp):
+def _qkv(cfg: TransformerConfig, h, positions, lp, kind: str = "full"):
     """Projections + rope for a block of hidden states; k/v stay at
-    n_kv_heads (GQA repeat happens at attention time).
+    n_kv_heads (GQA repeat happens at attention time). A "window" layer
+    rotates at ``cfg.win_rope_theta``, a full one at ``cfg.rope_theta``
+    (None: not at all), both in ``cfg.rope_interleave``'s pairing.
 
     The weight formats a layer's ``lp`` may carry — this function,
     `_attn_out` and `_mlp` are where they are read;
@@ -620,9 +676,10 @@ def _qkv(cfg: TransformerConfig, h, positions, lp):
             return rms_norm(flat, w, cfg.norm_eps).reshape(x.shape)
 
         q, k = whole(q, lp["q_norm"]), whole(k, lp["k_norm"])
-    if cfg.rope_theta is not None:
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    theta = cfg.win_rope_theta if kind == "window" else cfg.rope_theta
+    if theta is not None:
+        q = rope(q, positions, theta, cfg.rope_scaling, cfg.rope_interleave)
+        k = rope(k, positions, theta, cfg.rope_scaling, cfg.rope_interleave)
     return q, k, v
 
 
@@ -665,9 +722,12 @@ def _mlp(cfg: TransformerConfig, h, lp, expert_kernel: bool = False):
         if cfg.moe_shared:      # what every chip computes alike
             sf = cfg.moe_shared * cfg.moe_ff
             gu = jnp.einsum("td,de->te", flat, lp["ws_gu"].astype(dt))
-            out = out + jnp.einsum(
+            shared = jnp.einsum(
                 "tf,fd->td", jax.nn.silu(gu[:, :sf]) * gu[:, sf:],
                 lp["ws_down"].astype(dt))
+            if cfg.moe_shared_mean:
+                shared = shared / cfg.moe_shared
+            out = out + shared
         return out.reshape(b, l, d), chosen.reshape(b, l, -1)
     if cfg.n_experts > 0:
         b, l, d = h.shape
@@ -861,12 +921,15 @@ def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
 
 
 def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
-                  recur=None, expert_kernel: bool = False):
+                  recur=None, expert_kernel: bool = False, kind: str = "full"):
     """One decoder block, the only one: norm -> mixer -> norm -> _mlp
-    (``norm_order`` "pre"; "post" norms each branch's OUTPUT instead). lp =
-    this layer's params (stack dim removed). The caller names the mixer by
-    what it hands in: a full-attention layer (``recur`` None) is _qkv ->
-    attend -> wo, a linear layer is ``recur``.
+    (``norm_order`` "pre"; "post" norms each branch's OUTPUT instead;
+    "parallel" hands the mixer's normed input to the MLP as well, x +
+    Mixer(Norm(x)) + MLP(Norm(x))). The norms are ``cfg.norm_type``'s. lp
+    = this layer's params (stack dim removed). The caller names the mixer
+    by what it hands in: an attention layer (``recur`` None; ``kind``
+    "full" or "window", which `_qkv` rotates by) is _qkv -> attend -> wo,
+    a linear layer is ``recur``.
 
     ``attend(kv, q, k, v) -> (attn [B, L, H, D], kv)`` is the one decision
     the block's callers differ in: how this layer's K/V are stored and what
@@ -883,26 +946,30 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
 
     The two branches are the step's parts "mixer" and "mlp"
     (``observability.STEP_PARTS``), each with its norm and its residual
-    add, so that every program built on this block is measured by them."""
-    pre = cfg.norm_order == "pre"
+    add, so that every program built on this block is measured by them.
+    The parallel block's one norm is the mixer's, and its sum x + mixer +
+    MLP is two adds, (x + mixer) in "mixer" and + MLP in "mlp"."""
+    post = cfg.norm_order == "post"
     with jax.named_scope("mixer"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps) if pre else x
+        h = x if post else norm(cfg, x, lp["attn_norm"])
         if recur is not None:
             mixed, kv = recur(kv, h, lp)
         else:
-            q, k, v = _qkv(cfg, h, positions, lp)
+            q, k, v = _qkv(cfg, h, positions, lp, kind)
             attn, kv = attend(kv, q, k, v)
             mixed = _attn_out(cfg, attn, lp)
-        if not pre:
-            mixed = rms_norm(mixed, lp["attn_norm"], cfg.norm_eps)
+        if post:
+            mixed = norm(cfg, mixed, lp["attn_norm"])
         x = x + mixed
     with jax.named_scope("mlp"):
-        if pre:
-            mlp_out, aux = _mlp(cfg, rms_norm(x, lp["mlp_norm"], cfg.norm_eps),
-                                lp, expert_kernel)
-        else:
+        if cfg.norm_order == "parallel":
+            mlp_out, aux = _mlp(cfg, h, lp, expert_kernel)
+        elif post:
             mlp_out, aux = _mlp(cfg, x, lp, expert_kernel)
-            mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.norm_eps)
+            mlp_out = norm(cfg, mlp_out, lp["mlp_norm"])
+        else:
+            mlp_out, aux = _mlp(cfg, norm(cfg, x, lp["mlp_norm"]), lp,
+                                expert_kernel)
         return x + mlp_out, aux, kv
 
 
@@ -911,7 +978,8 @@ def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
     K/V through the model's kernel, a linear layer from a zero state."""
     def attend(kv, q, k, v):
         k, v = _repeat_kv(cfg, k, v)
-        return _attention(q, k, v, cfg, mesh), kv
+        window = cfg.win_size if kind == "window" else None
+        return _attention(q, k, v, cfg, mesh, window), kv
 
     def recur(kv, h, lp):
         out, _, _ = linear_mixer(cfg, h, lp,
@@ -930,7 +998,7 @@ def _layer(cfg: TransformerConfig, mesh, x, positions, lp, *, kind="full"):
 
     x, aux, _ = decoder_layer(
         cfg, x, positions, lp, attend,
-        recur={"linear": recur, "latent": latent}.get(kind))
+        recur={"linear": recur, "latent": latent}.get(kind), kind=kind)
     return x, aux
 
 
@@ -992,7 +1060,7 @@ def apply_hidden(
     [B, L, D], aux_loss scalar). The unembed projection is left to the
     caller so the loss can stream it blockwise."""
     x, auxes = _hidden(params, tokens, cfg, mesh)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = norm(cfg, x, params["final_norm"])
     return x, jnp.sum(auxes) * cfg.aux_loss_weight
 
 
@@ -1088,7 +1156,7 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig, mesh=None,
     (``observability.STEP_PARTS``), in the order `apply_hidden` has them."""
     x, auxes = _hidden(params, tokens, cfg, mesh)
     with jax.named_scope("loss"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = norm(cfg, x, params["final_norm"])
         aux = jnp.sum(auxes) * cfg.aux_loss_weight
         return token_nll(x, params["unembed"], targets, cfg, mesh, rules) + aux
 

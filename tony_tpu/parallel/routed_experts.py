@@ -56,6 +56,21 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 # the decode kernel's row tile: a decode step gives an expert 0-3 rows,
 # and bfloat16's native tile is 16 sublanes
 TILE_ROWS = 16
+# the most bytes of one expert's weights a grid step of the decode kernel
+# holds: twice, double-buffered, beside its rows, inside a Mosaic kernel's
+# 16 MiB of scoped VMEM (whole matrices of 2048 x 1536, an N tile of 512
+# of 4096 x 8192)
+WEIGHT_BLOCK_BYTES = 6 << 20
+
+
+def weight_tile(k: int, n: int, itemsize: int) -> int:
+    """The decode kernel's N tile for an expert's [k, n] matrix: the whole
+    of n where it fits ``WEIGHT_BLOCK_BYTES``, else n halved until it does
+    (while the half stays a multiple of 128 lanes)."""
+    tile = n
+    while k * tile * itemsize > WEIGHT_BLOCK_BYTES and tile % 256 == 0:
+        tile //= 2
+    return tile
 
 
 def route(x, router, router_bias, *, top_k: int, scale: float):
@@ -86,16 +101,18 @@ def grouped_matmul(lhs, rhs, sizes):
     [m, K], rhs [G, K, N], sizes [G] -> [m, N] in lhs's dtype, float32
     accumulation; rows past ``sum(sizes)`` are left undefined (the
     caller masks them). The megablox kernel at a ``TILE_ROWS`` row tile,
-    its K and N tiles the whole of K and N: a weight block's index is then
-    the group's alone, so a group that straddles two row tiles keeps its
-    resident block and each touched group's weights are read once (an N
-    tile of 256-768 ran as fast on a v5e and only adds grid steps: PERF.md
-    §6, PR 37). Interpreted off the TPU (the tests)."""
+    its K tile the whole of K and its N tile `weight_tile`'s (the whole of
+    N where an expert's matrix fits VMEM twice): a weight block's index is
+    then the group's and the N tile's, so a group that straddles two row
+    tiles keeps its resident block and each touched group's weights are
+    read once (an N tile of 256-768 ran as fast on a v5e and only adds
+    grid steps). Interpreted off the TPU (the tests)."""
     m, k = lhs.shape
     n = rhs.shape[2]
     pad = -m % TILE_ROWS
     out = gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, sizes,
-              preferred_element_type=lhs.dtype, tiling=(TILE_ROWS, k, n),
+              preferred_element_type=lhs.dtype,
+              tiling=(TILE_ROWS, k, weight_tile(k, n, rhs.dtype.itemsize)),
               interpret=jax.default_backend() != "tpu")
     return out[:m]
 
