@@ -164,7 +164,66 @@ def test_flash_decode_latent_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-PER_HEAD = json.loads((Path(__file__).parent / "fixtures"
+LATENT = (5, 32, 4096, 576)     # the latent cell's stack: layers, slots, M, R
+_LATENT_MADE = r" = bf16\[(?:5,32,4096,576|5,32,576,4096)\]\{[^}]*\} ([\w-]+)\("
+
+
+def test_write_latent_rows_compiles_for_v5e(one_chip):
+    """The latent cell's row write: 32 new rows into the whole donated
+    [5, 32, 4096, 576] stack at a traced cursor, one Mosaic kernel on the
+    stack's [R, M] view (a bitcast of it) and aliased to it: the stack is
+    made by nothing but its parameter, the bitcasts and the kernel."""
+    from tony_tpu.ops.decode_attention import write_latent_rows
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = jax.jit(lambda c, new, at: write_latent_rows(c, new, 4, at),
+                 donate_argnums=0)
+    compiled = fn.lower(sds(LATENT, jnp.bfloat16),
+                        sds(LATENT[1::2], jnp.bfloat16),
+                        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    made = re.findall(_LATENT_MADE, text)
+    assert set(made) == {"parameter", "bitcast", "custom-call"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_latent_write_and_read_stay_in_place_under_a_scan(one_chip):
+    """Two decode steps of five latent layers each on a scan-carried,
+    donated stack, every layer ``write_latent_rows`` then
+    ``flash_decode_latent`` as ``_forward_with_cache`` calls them: ten
+    kernels, and nothing in the compiled program copies, slices, sets or
+    fuses an array of the stack's shape (one copy is 755 MB)."""
+    from tony_tpu.ops.decode_attention import (flash_decode_latent,
+                                               write_latent_rows)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    ly, b, _, r = LATENT
+
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def two_steps(q, new, stack, lengths, offsets, active, at):
+        def step(stack, _):
+            total = 0.0
+            for layer in range(ly):
+                stack = write_latent_rows(stack, new, layer, at)
+                total += jnp.sum(flash_decode_latent(
+                    q, stack, lengths, layer, v_dim=512, scale=192 ** -0.5,
+                    ring_offsets=offsets, active=active))
+            return stack, total
+        return jax.lax.scan(step, stack, None, length=2)
+
+    rows = sds((b,), jnp.int32)
+    text = _compiled_text(
+        two_steps, sds((b, 32, r), jnp.bfloat16), sds((b, r), jnp.bfloat16),
+        sds(LATENT, jnp.bfloat16), rows, rows, sds((b,), jnp.bool_),
+        sds((), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * ly
+    made = re.findall(_LATENT_MADE, text)
+    assert made and not set(made) & {
+        "copy", "dynamic-update-slice", "dynamic-slice", "fusion"}, made
+
+
+PER_HEAD =json.loads((Path(__file__).parent / "fixtures"
                        / "flash_decode_per_head.json").read_text())
 
 

@@ -434,6 +434,10 @@ def test_the_decode_kernel_serves_the_reference(prepared, tcfg, monkeypatch,
              if name == "serve.step.bookkeep" and "experts_read" in c]
     assert spans and sum(c["experts_read"] for c in spans) == e["read"] > 0
     assert "tiles" not in e
+    # the CPU's decode gate is shut: the latent rows' write is the
+    # update, and no row is counted as written in place
+    assert server.stats()["latent_rows_in_place"] == 0
+    assert all(c["latent_rows_in_place"] == 0 for c in spans)
 
 
 @pytest.mark.parametrize("backend, l_new, sharded, m_cap, engages", [
@@ -446,25 +450,29 @@ def test_the_decode_kernel_serves_the_reference(prepared, tcfg, monkeypatch,
 def test_who_runs_the_latent_decode_kernel(prepared, tcfg, monkeypatch,
                                            backend, l_new, sharded, m_cap,
                                            engages):
-    """The gate on what the caller observes (``decode_kernel_engages`` on
-    the backend and the ring's length, one new token a row, no mesh), and
-    what it traces: `_latent_attention` runs ``flash_decode_latent`` on the
-    whole stack or the einsum on the layer's rows, and `_forward_with_cache`
-    calls the kernel once a latent layer, all of one traced pallas_call
-    (the layer is an operand)."""
+    """The one gate on what the caller observes (`latent_kernel_engages`:
+    ``decode_kernel_engages`` on the backend and the ring's length, one
+    new token a row, no mesh), and what it traces: `_latent_attention`
+    runs ``flash_decode_latent`` on the whole stack or the einsum on the
+    layer's rows, and `_forward_with_cache` calls ``write_latent_rows``
+    and ``flash_decode_latent`` once a latent layer where it opens, each
+    one traced pallas_call (the layer is an operand), and neither where
+    it does not: the write and the read cannot disagree."""
     import importlib
 
     generate = importlib.import_module("tony_tpu.models.generate")
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert generate.decode_kernel_engages(tcfg, m_cap) is (
         backend == "tpu" and m_cap >= 4096)
+    assert generate.latent_kernel_engages(tcfg, l_new, m_cap,
+                                          sharded) is engages
     cache = init_cache(tcfg, 2, m_cap)
     q = jnp.zeros((2, l_new, 4, 40), jnp.float32)
     lengths = jnp.zeros((2,), jnp.int32) + (m_cap - l_new)
     jaxpr = str(jax.make_jaxpr(
         lambda q, lat: generate._latent_attention(
             tcfg, q, lat, lengths, l_new, jnp.zeros((2,), jnp.int32),
-            layer_idx=1, allow_kernel=not sharded))(q, cache.latent))
+            layer_idx=1, kernel=engages))(q, cache.latent))
     assert jaxpr.count("pallas_call") == (1 if engages else 0)
     if sharded:
         return      # SlotServer refuses a latent cache over a mesh
@@ -474,18 +482,21 @@ def test_who_runs_the_latent_decode_kernel(prepared, tcfg, monkeypatch,
         lambda p, t, c: _forward_with_cache(p, tcfg, t, c))(
             prepared.params, jnp.zeros((2, l_new), jnp.int32), cache))
     assert jaxpr.count("name=flash_decode_latent") == (3 if engages else 0)
-    assert jaxpr.count("pallas_call") == (1 if engages else 0)
+    assert jaxpr.count("name=write_latent_rows") == (3 if engages else 0)
+    assert jaxpr.count("pallas_call") == (2 if engages else 0)
+    assert ("dynamic_update_slice" in jaxpr) is not engages
 
 
 def test_the_latent_decode_kernel_serves_the_reference(prepared, tcfg,
                                                        monkeypatch):
-    """With the gate forced open (the kernel interpreted, in blocks of 16
-    positions of the 64-position rings), the served tokens are the
-    reference's; and ``kv_blocks_read`` on the bookkeep spans and on
+    """With the gate forced open (the kernels interpreted, the read in
+    blocks of 16 positions of the 64-position rings), the served tokens
+    are the reference's; ``kv_blocks_read`` on the bookkeep spans and on
     ``SlotServer`` is ``live_kv_blocks`` of each processed block in that
     unit (its final lengths, the offsets it was dispatched with, the rows
     that emitted in it), below ``kv_blocks_ring``, for blocks in which a
-    row stops mid-block among them."""
+    row stops mid-block among them; and ``latent_rows_in_place`` is every
+    row of every latent layer at every step of those blocks."""
     import importlib
 
     from tony_tpu.ops import decode_attention
@@ -534,6 +545,10 @@ def test_the_latent_decode_kernel_serves_the_reference(prepared, tcfg,
     assert sum(c["kv_blocks_read"] for c in spans) == server.kv_blocks_read
     assert 0 < server.kv_blocks_read < server.kv_blocks_ring
     assert server.kv_blocks_ring == len(blocks) * ENGINE["slots"] * 4
+    in_place = len(blocks) * ENGINE["slots"] * 3 * block
+    assert server.latent_rows_in_place == in_place \
+        == sum(c["latent_rows_in_place"] for c in spans) \
+        == server.stats()["latent_rows_in_place"]
 
 
 # ------------------------------------------------------------- refusals

@@ -645,6 +645,36 @@ def test_flash_decode_latent_matches_the_absorbed_einsum(case):
     assert not np.asarray(out)[~live].any()
 
 
+@pytest.mark.parametrize("cursor", ["first", "127", "128", "129", "last"])
+@pytest.mark.parametrize("b", [32, 12])
+@pytest.mark.parametrize("r, dtype", [(576, jnp.bfloat16), (40, jnp.float32)],
+                         ids=["r576_bf16", "r40_f32"])
+def test_write_latent_rows_is_the_update_bit_for_bit(cursor, b, r, dtype):
+    """``write_latent_rows`` against ``lax.dynamic_update_slice`` of the
+    same rows at the same cursor, bit for bit, interpreted: 576 is the
+    latent cell's row, 40 the tiny config's; 12 rows are not a whole
+    number of the kernel's steps. Layer 1 of a 3-layer stack is written,
+    and the other layers hold NaN and keep it, as do the other positions
+    of the lane tile the kernel rewrites."""
+    from tony_tpu.ops.decode_attention import write_latent_rows
+
+    m = 256
+    at = {"first": 0, "last": m - 1}.get(cursor)
+    at = int(cursor) if at is None else at
+    ks = jax.random.split(jax.random.PRNGKey(at + b + r), 2)
+    stack = jnp.full((3, b, m, r), jnp.nan, dtype).at[1].set(
+        jax.random.normal(ks[0], (b, m, r)).astype(dtype))
+    new = jax.random.normal(ks[1], (b, r), jnp.float32)
+    want = jax.lax.dynamic_update_slice(
+        stack, new.astype(dtype)[None, :, None], (1, 0, at, 0))
+    got = write_latent_rows(stack, new, 1, at, interpret=True)
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    assert got.shape == stack.shape and got.dtype == stack.dtype
+    np.testing.assert_array_equal(np.asarray(got).view(bits),
+                                  np.asarray(want).view(bits))
+    assert np.isnan(np.asarray(got, np.float32)[[0, 2]]).all()
+
+
 def test_flash_under_a_mesh_runs_in_a_shard_map_and_matches_reference():
     """GSPMD cannot partition a Mosaic kernel, so on a mesh of several
     devices the model wraps the flash call in a shard_map over batch and

@@ -183,7 +183,8 @@ def test_step_phases_carry_their_counts(run):
                    "expert_tokens_max", "expert_tokens_mean"}
         assert set(counts) - experts == {
             "tokens", "completions", "kv_blocks_read", "kv_blocks_ring",
-            "state_rows", "state_rows_read", "state_rows_held"}
+            "latent_rows_in_place", "state_rows", "state_rows_read",
+            "state_rows_held"}
         # (an engine with routed expert layers adds all five or none)
         assert set(counts) & experts in (set(), experts)
         assert 0 <= counts["state_rows"] <= 3
@@ -192,6 +193,8 @@ def test_step_phases_carry_their_counts(run):
         # linear layers holds no state
         assert counts["state_rows_read"] == counts["state_rows_held"]
         assert counts["state_rows_held"] % 3 == 0
+        # no latent layers here, and the CPU's decode gate is shut
+        assert counts["latent_rows_in_place"] == 0
     done = {**run.engine[0], **run.engine[1], **run.engine[2],
             **run.engine[3]}
     assert len(done) == 17

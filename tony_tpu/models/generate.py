@@ -315,6 +315,17 @@ def expert_kernel_engages(l_new: int, sharded: bool) -> bool:
     return l_new == 1 and not sharded and jax.default_backend() == "tpu"
 
 
+def latent_kernel_engages(cfg, l_new: int, m_cap: int, sharded: bool) -> bool:
+    """Whether a latent layer's step over ``l_new`` new positions a row
+    runs the Pallas kernels on the whole ``KVCache.latent`` stack of
+    ``m_cap`` positions (ops/decode_attention.py): ``write_latent_rows``
+    for the rows' write and ``flash_decode_latent`` for the read. One gate
+    for both, so they cannot disagree: one new token a row, not under a
+    mesh, and ``decode_kernel_engages``; elsewhere the write is a
+    ``dynamic_update_slice`` and the read the einsum over the whole ring."""
+    return l_new == 1 and not sharded and decode_kernel_engages(cfg, m_cap)
+
+
 def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
                       k_scale=None, v_scale=None, ring_offsets=None,
                       allow_kernel=True, layer_idx=None, active=None,
@@ -420,21 +431,20 @@ def _cached_attention(cfg, q, ck, cv, cache_len, l_new,
 
 
 def _latent_attention(cfg, q_lat, rows, cache_len, l_new, ring_offsets=None,
-                      layer_idx=None, active=None, allow_kernel=False):
+                      layer_idx=None, active=None, kernel=False):
     """The absorbed form's attention: q_lat [B, L, H, R] (`transformer
     .latent_absorb`) against a latent layer's cached rows [B, M, R] (or
     layer ``layer_idx`` of the [Ly, B, M, R] stack), which already hold
     the new positions -> sum p row [B, L, H, >= kv_rank]. To
     `_cached_attention` this is attention with ONE K/V head R wide that
     every query head shares and whose values are its keys: the same
-    masks, lengths and ring offsets. Where the decode kernel may run
-    (``allow_kernel``, one new token a row, ``decode_kernel_engages``) it
-    is ``flash_decode_latent`` on the whole stack: each live block of a
+    masks, lengths and ring offsets. With ``kernel`` (the caller's
+    `latent_kernel_engages`: one new token a row) it is
+    ``flash_decode_latent`` on the whole stack: each live block of a
     row's rows read once, a row that is not ``active`` reading nothing;
     elsewhere the einsum on the layer's rows, over the whole ring."""
     b, l, h, r = q_lat.shape
-    if (allow_kernel and l == 1
-            and decode_kernel_engages(cfg, rows.shape[-2])):
+    if kernel:
         from ..ops.decode_attention import flash_decode_latent
 
         out = flash_decode_latent(
@@ -682,7 +692,8 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     | k_r] in ``cache.latent`` at the same shared offset and attends in the
     absorbed form (`_latent_attention`: one read of a row for all heads;
     a decode step on the TPU outside a mesh, ``flash_decode_latent`` over
-    the live blocks of the whole stack, as ``attend`` is ``flash_decode``),
+    the live blocks of the whole stack, as ``attend`` is ``flash_decode``,
+    after ``write_latent_rows`` wrote the rows there in place),
     over the block's own rows on a prefill's empty cache. ``routes=True``
     adds a third result: the experts each of the L positions chose in each
     routed layer (``cfg.mlp_kinds``), [routed layers, B, L, k] int32. A
@@ -816,21 +827,31 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     def latent(layer, carry, h, lp):
         """A latent layer (``layer`` counts them): the block's rows into
         ``cache.latent`` at the shared offset, then the absorbed form over
-        the layer's rows (on a prefill's empty cache: over the block's)."""
+        the layer's rows (on a prefill's empty cache: over the block's).
+        Where `latent_kernel_engages` both are Pallas kernels on the whole
+        stack: ``write_latent_rows`` writes the rows in place as whole
+        lane tiles, and ``flash_decode_latent`` reads the live blocks."""
         kv, rec, lat, win = carry
+        kernel = not prefill and latent_kernel_engages(
+            cfg, l, lat.shape[-2], shardings is not None)
         q_nope, q_rope, row = transformer.latent_project(cfg, h, positions, lp)
         offset = cache.length if ring_cursor is None else ring_cursor
-        lat = lax.dynamic_update_slice(
-            lat, row[None].astype(lat.dtype),
-            (jnp.int32(layer), zero, offset, zero))
+        if kernel:
+            from ..ops.decode_attention import write_latent_rows
+
+            lat = write_latent_rows(lat, row[:, 0], layer, offset,
+                                    interpret=jax.default_backend() != "tpu")
+        else:
+            lat = lax.dynamic_update_slice(
+                lat, row[None].astype(lat.dtype),
+                (jnp.int32(layer), zero, offset, zero))
         q_lat = transformer.latent_absorb(cfg, q_nope, q_rope, lp)
         if prefill:
             o = _latent_attention(cfg, q_lat, row, zero, l)
         else:
             o = _latent_attention(cfg, q_lat, lat, cache.length, l,
                                   ring_offsets, layer_idx=layer,
-                                  active=ring_active,
-                                  allow_kernel=shardings is None)
+                                  active=ring_active, kernel=kernel)
         return transformer.latent_out(cfg, o, lp), (kv, rec, lat, win)
 
     mixers = {"linear": recur, "latent": latent}

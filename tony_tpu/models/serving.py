@@ -20,9 +20,13 @@ Design — everything stays one compiled program over static shapes:
   the same cheap shared-offset dynamic_update_slice the lockstep
   generate() path uses — per-row-offset writes lower to TPU scatters
   that cost more than the whole step — and only the attention pays the
-  index→logical remap. Active rows advance one position per step
-  exactly as the cursor does, so a live row never wraps onto its own
-  data. No tensor ever changes shape when requests come and go.
+  index→logical remap (a latent layer's rows, laid out positions-minor
+  on the TPU, are written there as whole lane tiles by one Pallas call,
+  ``write_latent_rows``, where the decode kernel engages;
+  ``latent_rows_in_place`` on the bookkeep span counts them). Active
+  rows advance one position per step exactly as the cursor does, so a
+  live row never wraps onto its own data. No tensor ever changes shape
+  when requests come and go.
 - **One decode step for all slots.** Every block runs ``block_size``
   single-token steps for ALL S slots under one jit (a lax.scan) — active
   or not. Inactive slots compute garbage that is never read: masking rows
@@ -2224,6 +2228,10 @@ class SlotServer:
         # a processed block's last step streamed / those its rings hold
         self.kv_blocks_read = 0
         self.kv_blocks_ring = 0
+        # latent rows the processed decode blocks wrote in place as whole
+        # lane tiles (`write_latent_rows`): rows x latent layers x steps
+        # where the decode kernel engages, else 0
+        self.latent_rows_in_place = 0
         # window layers, at a processed block's last step: the blocks of a
         # window ring it streamed and those its live rows' whole contexts
         # span; the positions those rows hold and those a window layer's
@@ -3365,6 +3373,7 @@ class SlotServer:
             "blocks_dispatched": self.blocks_dispatched,
             "prefill_tokens_computed": self.prefill_tokens_computed,
             "prefill_tokens_reused": self.prefill_tokens_reused,
+            "latent_rows_in_place": self.latent_rows_in_place,
             # failure-model counters: recovery/shedding must be VISIBLE
             # (a server that silently sheds reads as a server that lost
             # requests)
@@ -4760,6 +4769,7 @@ class SlotServer:
         with phase(PHASE_BOOKKEEP) as span:
             done = len(self._done)
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
+            in_place = self.latent_rows_in_place
             window = self._window_counts()
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
@@ -4772,6 +4782,8 @@ class SlotServer:
                               completions=len(self._done) - done,
                               kv_blocks_read=self.kv_blocks_read - read,
                               kv_blocks_ring=self.kv_blocks_ring - ring,
+                              latent_rows_in_place=(
+                                  self.latent_rows_in_place - in_place),
                               state_rows=self.state_rows - rows,
                               state_rows_read=self.state_rows_read - s_read,
                               state_rows_held=self.state_rows_held - s_held)
@@ -5027,12 +5039,19 @@ class SlotServer:
         the full ring holding each row's positions 0 .. length), and
         ``live_positions`` / ``window_positions`` the positions those rows
         hold and the ones a window layer's query sees, min(length,
-        win_size) a row."""
+        win_size) a row.
+
+        Where the decode kernel engages a decode block also adds its
+        latent rows written in place to ``latent_rows_in_place``."""
         ring = self.slots * -(-self.max_len // self._kv_block_k)
         read = ring
         block = "offsets" in rec        # a decode block, no speculative round
         live = active | (lengths > self._expect_len)
         if self._decode_kernel and block:
+            # every row's latent row, an idle row's dead one included,
+            # each step and latent layer (`latent_kernel_engages`)
+            self.latent_rows_in_place += (
+                self.slots * self.cfg.n_latent_layers * self.block_size)
             read = int(live_kv_blocks(
                 rec["offsets"], lengths, live, block_k=self._kv_block_k,
                 m_cap=self.max_len, window=self.cfg.attn_window or 0,

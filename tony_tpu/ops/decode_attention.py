@@ -464,5 +464,66 @@ def flash_decode_latent(q, rows, length, layer, *, v_dim: int, scale: float,
       jnp.zeros((b, h, v_dim), q.dtype))
 
 
+# Rows a grid step of ``write_latent_rows`` rewrites. A step holds each
+# row's [R, 128] lane tile in and out, double-buffered: 4 x 8 x 576 x 128
+# x 2 B = 4.7 MB of a bf16 stack, inside Mosaic's 16 MiB of scoped VMEM
+# with room for the f32 broadcast of the new rows (2.4 MB)
+LATENT_WRITE_ROWS = 8
+
+
+def _row_write_kernel(at_ref, new_ref, tile_ref, out_ref):
+    """Lane ``cursor % lanes`` of each row's [R, lanes] tile becomes the
+    row's new latent row; the other lanes are the tile as it was."""
+    lanes = tile_ref.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile_ref.shape, 2)
+    new = jnp.broadcast_to(new_ref[...][:, :, None], tile_ref.shape)
+    out_ref[...] = jnp.where(lane == at_ref[1] % lanes,
+                             new.astype(out_ref.dtype), tile_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def write_latent_rows(rows, new, layer, cursor, *, interpret: bool = False):
+    """Every row's new latent row into the WHOLE [Ly, B, M, R] stack
+    (``KVCache.latent``) at position ``cursor`` of layer ``layer`` (both
+    int32 scalars, traced: one kernel for every layer and step), in place:
+    ``lax.dynamic_update_slice(rows, new[None, :, None], (layer, 0,
+    cursor, 0))`` bit for bit, with ``new`` [B, R].
+
+    The stack is read as ``flash_decode_latent`` reads it, [Ly, B, R, M],
+    positions along the lanes: there one position of a row is one lane in
+    each of its R / 16 (16, 128) tiles, which XLA's update writes as that
+    many partial-tile read-modify-writes. Here a grid step brings the [R,
+    128] tile holding the cursor's lane of ``LATENT_WRITE_ROWS`` rows into
+    VMEM, sets that lane and writes the tiles back whole, to where they
+    came from (the stack is aliased to the output): 2 x 147 KB a row of a
+    bf16 stack 576 wide. Every row is written, an idle row's dead write
+    included, as the update writes it."""
+    _, b, m_cap, r = rows.shape
+    lanes = min(128, m_cap)
+    rb = min(LATENT_WRITE_ROWS, b)
+    tile = pl.BlockSpec((None, rb, r, lanes),
+                        lambda i, at: (at[0], i, 0, at[1] // lanes))
+    out = pl.pallas_call(
+        _row_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(b, rb),),
+            in_specs=[pl.BlockSpec((rb, r), lambda i, at: (i, 0)), tile],
+            out_specs=tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct(rows.shape[:2] + (r, m_cap),
+                                       rows.dtype),
+        input_output_aliases={2: 0},
+        interpret=interpret,
+    )(jnp.stack([jnp.asarray(layer, jnp.int32),
+                 jnp.asarray(cursor, jnp.int32)]),
+      # a 32-bit operand: its rows go from the lanes to the sublanes of
+      # the tile in VMEM (Mosaic inserts a minor dim of 32-bit values
+      # only); the value is the stack's own, so the write is exact
+      new.astype(rows.dtype).astype(jnp.float32),
+      jnp.swapaxes(rows, -1, -2))
+    return jnp.swapaxes(out, -1, -2)
+
+
 __all__ = ["flash_decode", "flash_decode_latent", "kv_block_k",
-           "live_kv_blocks"]
+           "live_kv_blocks", "write_latent_rows"]
