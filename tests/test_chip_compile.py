@@ -393,18 +393,22 @@ def test_routed_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * tokens * k * d
 
 
-def test_a_share_of_wide_experts_compiles_for_v5e(one_chip, monkeypatch):
-    """A decode step's kernel path over a chip's share of wide experts (16
-    of 128 held, 4096 x 2 x 4096 gate-and-up, 16 tokens): an expert's
-    whole matrix would not fit the kernel's scoped VMEM twice, so its
-    weight block is an N tile of it (`weight_tile`), and the two Mosaic
-    kernels compile."""
+@pytest.mark.parametrize("e, held, d, f, k, tiles", [
+    (128, (0, 16), 4096, 4096, 8, (512, 512)),      # command-a-plus' share
+    (64, (0, 64), 3584, 1024, 4, (512, 1792)),      # xing4's whole layer
+])
+def test_a_share_of_wide_experts_compiles_for_v5e(one_chip, monkeypatch, e,
+                                                  held, d, f, k, tiles):
+    """A decode step's kernel path over wide experts (16 of 128 held, 4096
+    x 2 x 4096 gate-and-up; all 64 of 3584 x 2 x 1024), 16 tokens: an
+    expert's whole matrix would not fit the kernel's scoped VMEM twice, so
+    its weight block is an N tile of it (`weight_tile`), and the two
+    Mosaic kernels compile."""
     from tony_tpu.parallel.routed_experts import (WEIGHT_BLOCK_BYTES, route,
                                                   routed_ffn, weight_tile)
 
-    e, held, d, f, k = 128, (0, 16), 4096, 4096, 8
     assert 2 * d * f * 2 > WEIGHT_BLOCK_BYTES
-    assert weight_tile(d, 2 * f, 2) == 512 == weight_tile(f, d, 2)
+    assert (weight_tile(d, 2 * f, 2), weight_tile(f, d, 2)) == tiles
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def layer(x, router, bias, w_gu, w_down):
@@ -414,7 +418,7 @@ def test_a_share_of_wide_experts_compiles_for_v5e(one_chip, monkeypatch):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     text = jax.jit(layer).lower(
         sds((16, d), jnp.bfloat16), sds((d, e), jnp.float32),
-        sds((e,), jnp.float32), sds((16, d, 2 * f), jnp.bfloat16),
-        sds((16, f, d), jnp.bfloat16)).compile().as_text()
+        sds((e,), jnp.float32), sds((held[1], d, 2 * f), jnp.bfloat16),
+        sds((held[1], f, d), jnp.bfloat16)).compile().as_text()
     assert "ragged-dot" not in text
     assert text.count('custom_call_target="tpu_custom_call"') == 2

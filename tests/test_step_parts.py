@@ -10,6 +10,7 @@ chip is the benchmark's ``--trace 1`` line.
 """
 
 import contextlib
+import dataclasses
 import gc
 import json
 import re
@@ -55,18 +56,23 @@ ROUTED = transformer.TransformerConfig(
     mlp_kinds=("dense", "routed"), moe_experts=16, moe_top_k=4, moe_ff=24,
     moe_shared=1, moe_scale=2.5,
 )
-KINDS = {"uniform": UNIFORM, "hybrid": HYBRID, "routed": ROUTED}
+# the routed block with four streams of hyper-connections around it
+HYPER = dataclasses.replace(ROUTED, hc_mult=4)
+KINDS = {"uniform": UNIFORM, "hybrid": HYBRID, "routed": ROUTED,
+         "hyper": HYPER}
 # the parts each kind's decode block has: the count columns are the
 # recurrent state's mark and the routed layers' expert counts
 DECODE_PARTS = {
     "uniform": {"mixer", "mlp", "unembed"},
     "hybrid": {"mixer", "mlp", "unembed", "counts"},
     "routed": {"mixer", "mlp", "router", "experts", "unembed", "counts"},
+    "hyper": {"mixer", "mlp", "router", "experts", "unembed", "counts",
+              "hyper"},
 }
 TRAIN_PARTS = {"mixer", "mlp", "loss", "optimizer"}
 CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
 ROLLOUT, TRAIN = "joyai-llm-flash.rollout-closed", "mistral7b-v03.train-1chip"
-LONGCTX = "command-a-plus.longctx-closed"
+LONGCTX, XING = "command-a-plus.longctx-closed", "xing4.longctx-closed"
 ENGINE = dict(slots=3, max_len=64, block_size=4, prefill_chunk=8)
 
 
@@ -143,17 +149,23 @@ ENTRY %main.9 (x.1: f32[8,16]) -> f32[8,16] {
 
 def test_step_parts_are_pinned_and_each_is_read():
     """The parts, and each read by the benchmark reader whose program has
-    it; the twelve entries name a reader's part each."""
+    it (``hyper`` by ``hyper_decode_ms``, in the one cell with streams);
+    the twelve entries name a reader's part each."""
     assert obs.STEP_PARTS == ("mixer", "mlp", "router", "experts", "unembed",
-                              "loss", "optimizer", "counts")
+                              "loss", "optimizer", "counts", "hyper")
     decode = lib.load("layer_metrics/decode_step_part_ms.py").PARTS
     train = lib.load("layer_metrics/train_step_part_ms.py").PARTS
-    assert set(decode) | set(train) == set(obs.STEP_PARTS) | {obs.STEP_OTHER}
+    assert set(decode) | set(train) | {"hyper"} \
+        == set(obs.STEP_PARTS) | {obs.STEP_OTHER}
+    (hyper,) = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
+                ["per_layer"] if m["name"] == "hyper_decode_ms"]
+    assert (hyper["source"], hyper["layer"], hyper["workloads"]) == (
+        "device_trace", "model step", [XING])
     entries = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
                ["per_layer"] if m["name"].startswith(("decode_step_part_ms.",
                                                      "train_step_part_ms."))]
     assert len(entries) == 12
-    serve = [CHAT, REASON, ROLLOUT, LONGCTX]
+    serve = [CHAT, REASON, ROLLOUT, LONGCTX, XING]
     for m in entries:
         stem, _, part = m["name"].partition(".")
         assert part in (decode if stem == "decode_step_part_ms" else train)
@@ -164,10 +176,10 @@ def test_step_parts_are_pinned_and_each_is_read():
                                                     [TRAIN])
         else:
             assert m["moves"] == "token_gap_p50_ms"
-            assert m["workloads"] == {"router": [ROLLOUT, LONGCTX],
-                                      "experts": [ROLLOUT, LONGCTX],
-                                      "counts": [REASON, ROLLOUT, LONGCTX]}.get(
-                                          part, serve)
+            assert m["workloads"] == {
+                "router": [ROLLOUT, LONGCTX, XING],
+                "experts": [ROLLOUT, LONGCTX, XING],
+                "counts": [REASON, ROLLOUT, LONGCTX, XING]}.get(part, serve)
 
 
 def test_step_parts_of_a_fixed_module():

@@ -61,7 +61,7 @@ ROUTED = transformer.TransformerConfig(
 )
 CHAT, REASON = "mistral7b-v01.chat-open", "olmo-hybrid-7b.reason-open"
 ROLLOUT = "joyai-llm-flash.rollout-closed"
-LONGCTX = "command-a-plus.longctx-closed"
+LONGCTX, XING = "command-a-plus.longctx-closed", "xing4.longctx-closed"
 STEP_PHASES = (obs.PHASE_ADMIT, obs.PHASE_DISPATCH, obs.PHASE_SYNC,
                obs.PHASE_BOOKKEEP)
 NEW_ENTRIES = [m for m in json.loads((REPO / "BENCHMARK.json").read_text())
@@ -184,7 +184,7 @@ def test_step_phases_carry_their_counts(run):
         assert set(counts) - experts == {
             "tokens", "completions", "kv_blocks_read", "kv_blocks_ring",
             "latent_rows_in_place", "state_rows", "state_rows_read",
-            "state_rows_held"}
+            "state_rows_held", "hyper_rows"}
         # (an engine with routed expert layers adds all five or none)
         assert set(counts) & experts in (set(), experts)
         assert 0 <= counts["state_rows"] <= 3
@@ -195,6 +195,8 @@ def test_step_phases_carry_their_counts(run):
         assert counts["state_rows_held"] % 3 == 0
         # no latent layers here, and the CPU's decode gate is shut
         assert counts["latent_rows_in_place"] == 0
+        # no engine here has hyper-connections
+        assert counts["hyper_rows"] == 0
     done = {**run.engine[0], **run.engine[1], **run.engine[2],
             **run.engine[3]}
     assert len(done) == 17
@@ -414,11 +416,11 @@ def test_reader_of_each_new_entry(entry, run, tmp_path, monkeypatch):
     # neither
     assert entry["workloads"] == {
         "state_rows_advanced_pct": [REASON], "state_read_pct": [REASON],
-        "expert_weights_read_pct": [ROLLOUT, LONGCTX],
-        "experts_touched_pct": [ROLLOUT, LONGCTX],
+        "expert_weights_read_pct": [ROLLOUT, LONGCTX, XING],
+        "experts_touched_pct": [ROLLOUT, LONGCTX, XING],
         "window_kv_read_pct": [LONGCTX],
         "submit_lock_wait_ms": [CHAT]}.get(
-            stem, [CHAT, REASON, ROLLOUT, LONGCTX])
+            stem, [CHAT, REASON, ROLLOUT, LONGCTX, XING])
     # a program without the spans (the chip fixture; the parent commit)
     shutil.copy(BENCH / "tests" / "small.xplane.pb", tmp_path)
     assert _read(entry, tmp_path, monkeypatch) is None

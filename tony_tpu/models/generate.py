@@ -506,12 +506,16 @@ def _cast_decode_params(params, cfg: TransformerConfig):
         return params
     router = params["layers"].get("router") if cfg.n_experts > 0 else None
     routed = params["layers"].get("routed") if cfg.n_routed_layers else None
+    # the hyper-connections' projections and gains stay float32 too
+    hyper = params["layers"].get("hyper") if cfg.hc_mult else None
     params = jax.tree.map(
         lambda a: a.astype(cfg.dtype) if a.dtype == jnp.float32 else a,
         params,
     )
     if router is not None:
         params["layers"]["router"] = router
+    if hyper is not None:
+        params["layers"]["hyper"] = hyper
     if routed is not None:      # a routed layer's router and selection bias
         params["layers"]["routed"] = [
             {**cast, "router": lp["router"], "router_bias": lp["router_bias"]}
@@ -572,6 +576,8 @@ def _fuse_decode_weights(params, cfg: TransformerConfig,
             return {}
         out = {}
         for kind, lp in params["layers"].items():
+            if kind == "hyper":         # a list a layer, nothing to fuse
+                continue
             out[kind] = {
                 "w_gu": jnp.concatenate([lp["w_gate"], lp["w_up"]], axis=-1)}
             if kind == "full":
@@ -724,7 +730,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
     else:
         ring_cursor = ring_offsets = ring_active = None
         positions = jnp.broadcast_to(cache.length + jnp.arange(l), (b, l))
-    x = params["embed"].astype(dt)[tokens]
+    x = transformer.hyper_expand(cfg, params["embed"].astype(dt)[tokens])
     if shardings is not None:
         # pin activations batch-sharded / model-dim-replicated so GSPMD
         # keeps the Megatron layout (psum after wo / w_down) instead of
@@ -872,6 +878,7 @@ def _forward_with_cache(params, cfg: TransformerConfig, tokens, cache: KVCache,
         if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
             picks.append(aux)
     (ck, cv, ks_buf, vs_buf), (state, conv), lat, (wk, wv) = carry
+    x = transformer.hyper_collapse(cfg, x)
 
     # all_logits=True projects EVERY position ([B, L, V]) — the speculative
     # verify forward needs the target's prediction after each drafted

@@ -791,6 +791,12 @@ LATENT_ROWS_AT_ONCE = 8
 # bytes of one row's float32 scores against its whole ring above which a
 # chunk round's attention walks the ring's live key blocks instead
 ROW_SCORES_BYTES = 256 << 20
+# bytes of the float32 scores of the rows a chunk round's latent attention
+# takes at once against their whole rings, above which it walks the rings'
+# live blocks a row at a time instead (8 rows of a 4096-position ring at
+# 32 heads hold 512 MiB and keep the einsum; 4 of a 12288-position ring
+# walk)
+LATENT_SCORES_BYTES = 512 << 20
 
 
 def _rows_attention(cfg, q, ck, cv, starts, l, ks, vs, offsets, window=None):
@@ -817,6 +823,18 @@ def _rows_attention(cfg, q, ck, cv, starts, l, ks, vs, offsets, window=None):
         return _cached_attention(cfg, q, ck, cv, starts, l, ks, vs,
                                  ring_offsets=offsets, allow_kernel=False,
                                  window=window)
+    return _rows_walk(cfg, q, ck, cv, starts, l, offsets, window,
+                      q.shape[-1] ** -0.5)
+
+
+def _rows_walk(cfg, q, ck, cv, starts, l, offsets, window, scale):
+    """`_rows_attention`'s walk: the rows one at a time over the blocks of
+    their rings that hold a position some query of the chunk may see,
+    with an online softmax in float32, the scores times ``scale``. Also
+    the latent admission's (``cv`` is then ``ck``: the rows are their own
+    values)."""
+    k_rows, _, h, d = q.shape
+    m_cap = ck.shape[2]
     f32, kvh = jnp.float32, ck.shape[1]
     rep = h // kvh
     bk = math.gcd(m_cap, kv_block_k(m_cap, kvh, d, ck.dtype.itemsize))
@@ -836,7 +854,7 @@ def _rows_attention(cfg, q, ck, cv, starts, l, ks, vs, offsets, window=None):
             k, v = (lax.dynamic_slice_in_dim(c, blk * bk, bk, axis=1)
                     for c in (ck, cv))
             s = jnp.einsum("grld,gkd->grlk", qg, k.astype(cfg.dtype),
-                           preferred_element_type=f32) * d ** -0.5
+                           preferred_element_type=f32) * scale
             pos = (blk * bk + jnp.arange(bk) - offset) % m_cap
             ok = pos[None, :] <= q_pos[:, None]
             if window:
@@ -908,7 +926,13 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
     attends in the ABSORBED form over the row's own slot
     (`_latent_attention`), the form the decode step uses: one statement of
     the cached attention for both programs, and no [K, M, H, nope + v]
-    expansion of a ring that is mostly not the chunk's.
+    expansion of a ring that is mostly not the chunk's. Where the scores
+    of the rows it takes at once would pass ``LATENT_SCORES_BYTES`` (a
+    long ring) it walks each row's live blocks instead (`_rows_walk`).
+
+    With hyper-connections the embedding is copied into the streams and
+    the streams are summed before the return (`transformer.hyper_expand`
+    / `hyper_collapse`).
 
     No fused/quantized weights: prefill is MXU-bound (the fusions are
     decode, weight-streaming, optimizations) and the speculative verify
@@ -986,7 +1010,14 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
             row.astype(lat.dtype), unique_indices=True, mode="drop")
         mine = lat[layer] if read_rows is None else lat[layer][read_rows]
         q_lat = transformer.latent_absorb(cfg, q_nope, q_rope, lp)
-        if k_rows > LATENT_ROWS_AT_ONCE:
+        at_once = min(k_rows, LATENT_ROWS_AT_ONCE)
+        if at_once * cfg.n_heads * l * mine.shape[1] * 4 > LATENT_SCORES_BYTES:
+            # a long ring: its rows are one K/V head whose values are its
+            # keys, walked over the live blocks (the whole rows weighed:
+            # `latent_out` reads their first kv_rank values)
+            o = _rows_walk(cfg, q_lat, mine[:, None], mine[:, None], starts,
+                           l, offsets, 0, transformer.latent_scale(cfg))
+        elif k_rows > LATENT_ROWS_AT_ONCE:
             # a wide burst's scores [K, H, L, M] float32 would be
             # gigabytes beside weights that fill the chip: a few rows at
             # a time (K is a power of two)
@@ -1003,7 +1034,7 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
 
     mixers = {"linear": recur, "latent": latent}
     attends = {"window": attend_window}
-    x = params["embed"].astype(dt)[tokens]
+    x = transformer.hyper_expand(cfg, params["embed"].astype(dt)[tokens])
     carry = (bufs, rec, lat, win)
     picks = []
     for i in range(cfg.n_layers):
@@ -1015,7 +1046,8 @@ def _rows_forward(params, cfg, tokens, bufs, rows, starts, offsets, write_ok):
             kind=kind)
         if cfg.mlp_kinds is not None and cfg.mlp_kinds[i] == "routed":
             picks.append(aux)
-    return (x, carry[0] + carry[1] + carry[2] + carry[3],
+    return (transformer.hyper_collapse(cfg, x),
+            carry[0] + carry[1] + carry[2] + carry[3],
             jnp.stack(picks) if picks else None)
 
 
@@ -1925,6 +1957,32 @@ class SlotServer:
             self.model = str(model)
         if not cfg.causal:
             raise ValueError("serving requires a causal model")
+        # ---- hyper-connections: n residual streams a position ----
+        # the streams live inside one program's forward and nothing of them
+        # is cached; what would carry or verify positions some other way
+        # than the ring engine's forward is refused here, each by name
+        if cfg.hc_mult:
+            no = "a config with hyper-connections (hc_mult > 0) "
+            for bad, why in (
+                    (prefix_cache_blocks > 0,
+                     "cannot use prefix_cache_blocks: the prefix trie is "
+                     "not built for it"),
+                    (paged, "cannot use paged=True: the paged pool is not "
+                     "built for it"),
+                    (draft is not None or spec_gamma,
+                     "cannot use a draft / spec_gamma: the verify forward "
+                     "is not built for it"),
+                    (kv_dtype == "int8", "cannot use kv_dtype='int8': not "
+                     "measured beside the streams"),
+                    (weight_dtype == "int8", "cannot use weight_dtype='int8': "
+                     "the int8 forms are the uniform stack's"),
+                    (mesh is not None
+                     or getattr(params, "mesh", None) is not None,
+                     "cannot use a mesh: the streams are not sharded"),
+                    (role != "both", f"cannot use role={role!r}: the KV "
+                     "handoff (serialize_kv_blocks) is not built for it")):
+                if bad:
+                    raise ValueError(no + why)
         # ---- recurrent slot state (cfg.layer_kinds with "linear") ----
         # a linear layer carries a state and a convolution tail a slot
         # beside the full layers' K/V ring. The ring engine on one device
@@ -2232,6 +2290,10 @@ class SlotServer:
         # lane tiles (`write_latent_rows`): rows x latent layers x steps
         # where the decode kernel engages, else 0
         self.latent_rows_in_place = 0
+        # rows x hyper-connection sites (two a layer) x steps of the
+        # processed decode blocks, an idle row's included (every row runs
+        # through the streams); 0 without hyper-connections
+        self.hyper_rows = 0
         # window layers, at a processed block's last step: the blocks of a
         # window ring it streamed and those its live rows' whole contexts
         # span; the positions those rows hold and those a window layer's
@@ -3374,6 +3436,7 @@ class SlotServer:
             "prefill_tokens_computed": self.prefill_tokens_computed,
             "prefill_tokens_reused": self.prefill_tokens_reused,
             "latent_rows_in_place": self.latent_rows_in_place,
+            "hyper_rows": self.hyper_rows,
             # failure-model counters: recovery/shedding must be VISIBLE
             # (a server that silently sheds reads as a server that lost
             # requests)
@@ -4770,6 +4833,7 @@ class SlotServer:
             done = len(self._done)
             read, ring = self.kv_blocks_read, self.kv_blocks_ring
             in_place = self.latent_rows_in_place
+            hyper = self.hyper_rows
             window = self._window_counts()
             rows = self.state_rows
             s_read, s_held = self.state_rows_read, self.state_rows_held
@@ -4784,6 +4848,7 @@ class SlotServer:
                               kv_blocks_ring=self.kv_blocks_ring - ring,
                               latent_rows_in_place=(
                                   self.latent_rows_in_place - in_place),
+                              hyper_rows=self.hyper_rows - hyper,
                               state_rows=self.state_rows - rows,
                               state_rows_read=self.state_rows_read - s_read,
                               state_rows_held=self.state_rows_held - s_held)
@@ -4899,6 +4964,9 @@ class SlotServer:
             self._count_kv_blocks(rec, lengths, active)
             if self._recurrent:
                 self._count_state_rows(lengths)
+            if self.cfg.hc_mult and "offsets" in rec:   # a decode block
+                self.hyper_rows += (self.slots * 2 * self.cfg.n_layers
+                                    * self.block_size)
             for slot in np.nonzero(self._expect_active)[0]:
                 if slot in self._stop_cancelled:
                     continue

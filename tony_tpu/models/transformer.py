@@ -150,6 +150,18 @@ class TransformerConfig:
     win_rope_theta: float | None = None
     # "rms": RMSNorm; "layer": LayerNorm (mean-centred, a weight, no bias)
     norm_type: str = "rms"
+    # manifold-constrained hyper-connections: 0 = the single residual
+    # stream; n > 0 = n streams [B, L, n, d] carried between the layers,
+    # each branch fed a dynamic mix of them and added back through a
+    # Sinkhorn-projected (doubly stochastic) stream mix (`hyper_coeffs`):
+    # hc_sinkhorn_iters alternations, each denominator + hc_eps, the
+    # mix's logits clipped to +-hc_res_clamp. Needs layer_kinds and the
+    # "pre" norm order; the parameters lie a layer in a list under
+    # params["layers"]["hyper"]
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -190,6 +202,12 @@ class TransformerConfig:
         if self.n_window_layers and (self.win_size < 1 or not self.causal):
             raise ValueError("window layers need win_size >= 1 and a causal "
                              "model")
+        if self.hc_mult and (
+                self.hc_mult < 0 or kinds is None
+                or self.norm_order != "pre" or self.hc_sinkhorn_iters < 1):
+            raise ValueError(
+                "hyper-connections need hc_mult >= 1, layer_kinds, the "
+                "'pre' norm order and hc_sinkhorn_iters >= 1")
         if self.n_linear_layers and (
                 self.n_experts or not self.causal
                 or min(self.lin_heads, self.lin_key_dim, self.lin_value_dim,
@@ -365,6 +383,24 @@ def _mlp_lists(key, cfg: TransformerConfig) -> dict:
     return out
 
 
+def _hyper_lists(key, cfg: TransformerConfig) -> list:
+    """A layer's hyper-connections (`hyper_coeffs` reads them), a dict a
+    layer: ``phi`` [2, n d, 2n + n^2] (the mixer's site, then the MLP's),
+    ``bias`` [2, 2n + n^2] and ``alpha`` [2, 3] (the pre, post and res
+    gains), all float32 as the router is. phi normal(0, (n d)^-1/2), the
+    gains 0.1, the biases 0: every coefficient starts near its neutral
+    value (a uniform pre-mix, a post-spread of 1, a nearly uniform
+    stream mix)."""
+    n, w = cfg.hc_mult * cfg.d_model, 2 * cfg.hc_mult + cfg.hc_mult ** 2
+    out = []
+    for i in range(cfg.n_layers):
+        k = jax.random.fold_in(key, i)
+        out.append({"phi": _dense_init(k, (2, n, w), n, jnp.float32),
+                    "bias": jnp.zeros((2, w), jnp.float32),
+                    "alpha": jnp.full((2, 3), 0.1, jnp.float32)})
+    return out
+
+
 def init(key: jax.Array, cfg: TransformerConfig) -> dict:
     """Build the parameter pytree. Layer params are stacked [n_layers, ...];
     with ``cfg.layer_kinds`` one such stack per kind, each over that
@@ -400,6 +436,8 @@ def init(key: jax.Array, cfg: TransformerConfig) -> dict:
                 **mlp(cfg.n_window_layers)}
         if by_layer:
             layers.update(_mlp_lists(jax.random.fold_in(key, 2), cfg))
+        if cfg.hc_mult:
+            layers["hyper"] = _hyper_lists(jax.random.fold_in(key, 4), cfg)
     return {"embed": embed, "layers": layers,
             "final_norm": jnp.ones((cfg.d_model,), pd), "unembed": unembed}
 
@@ -474,6 +512,10 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
                 n = cfg.mlp_kinds.count(form)
                 if n:
                     layers[form] = [dict(one) for _ in range(n)]
+        if cfg.hc_mult:     # replicated: no caller shards the streams
+            layers["hyper"] = [{"phi": (None, None, None),
+                                "bias": (None, None), "alpha": (None, None)}
+                               for _ in range(cfg.n_layers)]
     return {
         "embed": ("vocab", "embed"),
         "layers": layers,
@@ -506,6 +548,41 @@ def norm(cfg: TransformerConfig, x, weight):
     return rms_norm(x, weight, cfg.norm_eps)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor: 0.1 m ln(factor) + 1 (1 at a
+    factor of 1 or less)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_ramp(d: int, theta: float, scaling: tuple):
+    """The YaRN rule's ramp over the rotated pairs i < d/2 [d/2] float32:
+    0 where a pair turns more than beta_fast times over the original
+    context (kept as it is), 1 where it turns less than beta_slow times
+    (slowed by the factor), linear between the pair indices floor(low)
+    and ceil(high) of the DeepSeek-V3 rule."""
+    _, _, beta_fast, beta_slow, orig_max = scaling[:5]
+
+    def pair_of(turns):     # the pair index that turns `turns` times
+        return d * math.log(orig_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), d - 1)
+    if high == low:
+        high += 0.001
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    return jnp.clip((i - low) / (high - low), 0.0, 1.0)
+
+
+def rope_attention_factor(cfg: TransformerConfig) -> float:
+    """What the rope's scaling multiplies the scores' factor by: YaRN's
+    mscale(factor, mscale_all_dim) squared, else 1."""
+    sc = cfg.rope_scaling
+    if sc is None or sc[0] != "yarn":
+        return 1.0
+    return yarn_mscale(sc[1], sc[6]) ** 2
+
+
 def rope(x, positions, theta, scaling=None, interleave=False):
     """Rotary position embedding; x: [B, L, H, D]. ``interleave`` rotates
     the adjacent pairs (2i, 2i+1) in place instead of pairing dim i with
@@ -517,11 +594,24 @@ def rope(x, positions, theta, scaling=None, interleave=False):
     are slowed by ``factor``, short wavelengths are untouched, and the
     band between interpolates smoothly (the HF _compute_llama3_parameters
     rule). Every Llama 3.1+ checkpoint ships this; without it long-range
-    positions are rotated off the manifold the weights were trained on."""
+    positions are rotated off the manifold the weights were trained on.
+
+    ("yarn", factor, beta_fast, beta_slow, original_max_position
+    _embeddings, mscale, mscale_all_dim) is YaRN as DeepSeek-V3 states
+    it: pair i's frequency times 1 - ramp_i + ramp_i / factor
+    (`yarn_ramp`), cos and sin times mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim); the scores' factor is the caller's
+    (`rope_attention_factor`)."""
     d = x.shape[-1]
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    if scaling is not None:
+    gain = 1.0
+    if scaling is not None and scaling[0] == "yarn":
+        ramp = yarn_ramp(d, theta, scaling)
+        freqs = freqs * (1.0 - ramp + ramp / scaling[1])
+        gain = yarn_mscale(scaling[1], scaling[5]) / yarn_mscale(
+            scaling[1], scaling[6])
+    elif scaling is not None:
         kind, factor, low_f, high_f, orig_max = scaling
         if kind != "llama3":
             raise ValueError(f"unsupported rope scaling kind {kind!r}")
@@ -537,6 +627,8 @@ def rope(x, positions, theta, scaling=None, interleave=False):
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, L, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     if interleave:
         x1, x2 = x[..., 0::2], x[..., 1::2]
         out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -867,7 +959,10 @@ def latent_project(cfg: TransformerConfig, h, positions, lp):
 
 
 def latent_scale(cfg: TransformerConfig) -> float:
-    return (cfg.lat_nope_dim + cfg.lat_rope_dim) ** -0.5
+    """The latent attention's scores' factor: (nope + rope)^-1/2, times
+    the rope scaling's own (`rope_attention_factor`)."""
+    return ((cfg.lat_nope_dim + cfg.lat_rope_dim) ** -0.5
+            * rope_attention_factor(cfg))
 
 
 def latent_expand(cfg: TransformerConfig, q_nope, q_rope, row, lp):
@@ -900,6 +995,82 @@ def latent_out(cfg: TransformerConfig, o_lat, lp):
     return jnp.einsum("blhv,hvd->bld", o, lp["wo"].astype(cfg.dtype))
 
 
+def sinkhorn(m, iters: int, eps: float):
+    """m [..., n, n] positive -> nearly doubly stochastic: ``iters``
+    alternations of column then row normalisation, each denominator +
+    ``eps`` (the rows then sum to 1, the columns to within the last
+    alternation's change)."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+    return m
+
+
+def hyper_coeffs(cfg: TransformerConfig, x, hc, site: int):
+    """A hyper-connection site's coefficients for the streams x [B, L, n,
+    d] -> (pre [B, L, n], post [B, L, n], res [B, L, n, n]), float32.
+    ``hc`` is the layer's entry of ``params["layers"]["hyper"]``, ``site``
+    0 for the mixer's branch and 1 for the MLP's.
+
+    x^ = vec(x) rsqrt(mean(vec(x)^2) + norm_eps) over the n d values, no
+    weight; [~pre | ~post | ~res] = alpha * (x^ phi) + bias, a gain for
+    each of the three; pre = sigmoid(~pre) + hc_eps, post = 2
+    sigmoid(~post), res = `sinkhorn`(exp(clip(~res, +-hc_res_clamp))) over
+    the n x n mix (row i: what stream i takes from each stream)."""
+    f32, n = jnp.float32, cfg.hc_mult
+    b, l = x.shape[:2]
+    flat = x.reshape(b, l, -1).astype(f32)
+    flat = flat * jax.lax.rsqrt(
+        jnp.mean(flat * flat, axis=-1, keepdims=True) + cfg.norm_eps)
+    proj = jnp.matmul(flat, hc["phi"][site].astype(f32),
+                      precision=jax.lax.Precision.HIGHEST)
+    alpha, bias = hc["alpha"][site].astype(f32), hc["bias"][site].astype(f32)
+    pre = jax.nn.sigmoid(alpha[0] * proj[..., :n] + bias[:n]) + cfg.hc_eps
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * proj[..., n:2 * n]
+                                + bias[n:2 * n])
+    res = jnp.clip(alpha[2] * proj[..., 2 * n:] + bias[2 * n:],
+                   -cfg.hc_res_clamp, cfg.hc_res_clamp)
+    res = sinkhorn(jnp.exp(res).reshape(b, l, n, n), cfg.hc_sinkhorn_iters,
+                   cfg.hc_eps)
+    return pre, post, res
+
+
+def hyper_in(pre, x):
+    """A branch's input from the streams: sum_i pre[i] x[i] -> [B, L, d]
+    in x's dtype (the sum in float32)."""
+    return jnp.einsum("bln,blnd->bld", pre, x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST).astype(x.dtype)
+
+
+def hyper_out(res, post, x, y):
+    """The new streams: res x + post^T y -> [B, L, n, d] in x's dtype
+    (the sums in float32)."""
+    mixed = jnp.einsum("blij,bljd->blid", res, x.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    return (mixed + post[..., None] * y.astype(jnp.float32)[:, :, None, :]
+            ).astype(x.dtype)
+
+
+def hyper_expand(cfg: TransformerConfig, x):
+    """The embedding [B, L, d] copied into the cfg.hc_mult streams (the
+    single stream as it is without hyper-connections); the step's part
+    "hyper"."""
+    if not cfg.hc_mult:
+        return x
+    with jax.named_scope("hyper"):
+        b, l, d = x.shape
+        return jnp.broadcast_to(x[:, :, None], (b, l, cfg.hc_mult, d))
+
+
+def hyper_collapse(cfg: TransformerConfig, x):
+    """The streams [..., n, d] summed before the final norm -> [..., d]
+    (the single stream as it is without hyper-connections)."""
+    if not cfg.hc_mult:
+        return x
+    with jax.named_scope("hyper"):
+        return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
+
+
 def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
     """Layer ``i`` of the model -> (its kind, its index among the layers of
     that kind, its params with the stack dim removed). ``extra`` is a second
@@ -917,6 +1088,8 @@ def layer_at(cfg: TransformerConfig, layers, i: int, extra=None):
     if cfg.mlp_kinds is not None:
         form = cfg.mlp_kinds[i]
         lp = {**lp, **layers[form][cfg.mlp_kinds[:i].count(form)]}
+    if cfg.hc_mult:
+        lp = {**lp, "hc": layers["hyper"][i]}
     return kind, j, lp
 
 
@@ -948,9 +1121,22 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
     (``observability.STEP_PARTS``), each with its norm and its residual
     add, so that every program built on this block is measured by them.
     The parallel block's one norm is the mixer's, and its sum x + mixer +
-    MLP is two adds, (x + mixer) in "mixer" and + MLP in "mlp"."""
+    MLP is two adds, (x + mixer) in "mixer" and + MLP in "mlp".
+
+    With hyper-connections (``cfg.hc_mult`` = n > 0, the "pre" order) x
+    is the layer's n streams [B, L, n, d] and each branch is fed
+    `hyper_in` of them and added back by `hyper_out`, with its site's
+    `hyper_coeffs` (the layer's ``lp["hc"]``): x <- res x + post^T
+    F(Norm(sum_i pre[i] x[i])). That work is the part "hyper" inside
+    each branch's."""
     post = cfg.norm_order == "post"
+    hc = lp["hc"] if cfg.hc_mult else None
     with jax.named_scope("mixer"):
+        streams = x
+        if hc is not None:
+            with jax.named_scope("hyper"):
+                pre, spread, res = hyper_coeffs(cfg, streams, hc, 0)
+                x = hyper_in(pre, streams)
         h = x if post else norm(cfg, x, lp["attn_norm"])
         if recur is not None:
             mixed, kv = recur(kv, h, lp)
@@ -960,8 +1146,16 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
             mixed = _attn_out(cfg, attn, lp)
         if post:
             mixed = norm(cfg, mixed, lp["attn_norm"])
-        x = x + mixed
+        if hc is not None:
+            with jax.named_scope("hyper"):
+                streams = hyper_out(res, spread, streams, mixed)
+        else:
+            x = x + mixed
     with jax.named_scope("mlp"):
+        if hc is not None:
+            with jax.named_scope("hyper"):
+                pre, spread, res = hyper_coeffs(cfg, streams, hc, 1)
+                x = hyper_in(pre, streams)
         if cfg.norm_order == "parallel":
             mlp_out, aux = _mlp(cfg, h, lp, expert_kernel)
         elif post:
@@ -970,6 +1164,9 @@ def decoder_layer(cfg: TransformerConfig, x, positions, lp, attend, kv=None,
         else:
             mlp_out, aux = _mlp(cfg, norm(cfg, x, lp["mlp_norm"]), lp,
                                 expert_kernel)
+        if hc is not None:
+            with jax.named_scope("hyper"):
+                return hyper_out(res, spread, streams, mlp_out), aux, kv
         return x + mlp_out, aux, kv
 
 
@@ -1008,7 +1205,7 @@ def _hidden(params, tokens, cfg: TransformerConfig, mesh=None):
     dt = cfg.dtype
     b, l = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(l), (b, l))
-    x = params["embed"].astype(dt)[tokens]
+    x = hyper_expand(cfg, params["embed"].astype(dt)[tokens])
 
     layer_fns = {kind: functools.partial(_layer, cfg, mesh, kind=kind)
                  for kind in LAYER_KINDS}
@@ -1047,7 +1244,7 @@ def _hidden(params, tokens, cfg: TransformerConfig, mesh=None):
         for i in range(cfg.n_layers):
             kind, _, lp = layer_at(cfg, params["layers"], i)
             x, _ = layer_fns[kind](x, positions, lp)
-    return x, auxes
+    return hyper_collapse(cfg, x), auxes
 
 
 def apply_hidden(

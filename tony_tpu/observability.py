@@ -783,12 +783,14 @@ def phase(name: str, **counts: int):
 # its residual add), ``router`` and ``experts`` (parallel/routed_experts.py),
 # ``unembed`` (the final norm, the unembedding and the sample:
 # models/generate.py, the decode block of models/serving.py), ``loss``
-# (models/transformer.py ``loss_fn``), ``optimizer`` (train/step.py) and
-# ``counts`` (the count columns the decode block computes for the host).
+# (models/transformer.py ``loss_fn``), ``optimizer`` (train/step.py),
+# ``counts`` (the count columns the decode block computes for the host) and
+# ``hyper`` (the hyper-connections' coefficients, stream mixes, expansion
+# and collapse: models/transformer.py, inside both branches).
 # An instruction's part is the innermost of these on its scope path, and
 # STEP_OTHER under none; a fusion carries its root's path.
 STEP_PARTS = ("mixer", "mlp", "router", "experts", "unembed", "loss",
-              "optimizer", "counts")
+              "optimizer", "counts", "hyper")
 STEP_OTHER = "other"
 
 _HLO_NAME = re.compile(r"\s*(?:ROOT\s+)?%([^\s=]+) = ")

@@ -77,6 +77,11 @@ def create_train_step(
     snapshots to host synchronously before overlapping the write, never
     by handing live device arrays to a background saver.
     """
+    if cfg.hc_mult:
+        raise ValueError(
+            "hyper-connections (hc_mult > 0) cannot be trained here: the "
+            "backward through the Sinkhorn projection is not built or "
+            "tested; they are served only")
     if cfg.n_latent_layers:
         raise ValueError(
             "'latent' layers cannot be trained here: a head's q and k are "
